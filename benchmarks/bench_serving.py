@@ -304,6 +304,8 @@ def main(argv):
     """CLI: ``[--smoke] [--stateful-backend ref,xla,pallas]
     [--state-residency auto,host,device] [--cell lstm,gru,rglru]
     [--fault-rate F] [--chaos] [--replicas 1,2,4] [out.json]``."""
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     smoke = "--smoke" in argv
     chaos = "--chaos" in argv
     stateful_backends = None

@@ -86,6 +86,8 @@ def sweep(argv) -> None:
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     argv = sys.argv[1:]
     if argv and argv[0] == "--smoke":
         smoke(*argv[1:2])

@@ -14,7 +14,10 @@ Run:  PYTHONPATH=src python examples/explore_design_space.py
 """
 from repro import explore
 from repro.analysis.report import pareto_table
+from repro.compile_cache import use_compile_cache
 from repro.core.fixed_point import FXP_4_8, FXP_8_16
+
+use_compile_cache()
 
 # The Table-4 axes.  hs_method stays at the paper's 'step' (Table 1 showed
 # the three methods are accuracy-equivalent; 'step' is the cheapest) and

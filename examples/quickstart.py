@@ -14,9 +14,12 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 import jax.numpy as jnp
 
 import repro
+from repro.compile_cache import use_compile_cache
 from repro.core.accelerator import PAPER_DEFAULT
 from repro.core.qlstm import QLSTMConfig
 from repro.data.timeseries import pems_like_dataset
+
+use_compile_cache()
 
 cfg = QLSTMConfig()  # the paper's model
 data = pems_like_dataset(seq_len=cfg.seq_len)

@@ -5,5 +5,7 @@ Run:  PYTHONPATH=src python examples/serve_lm.py
       PYTHONPATH=src python examples/serve_lm.py --arch rwkv6-7b
       PYTHONPATH=src python examples/serve_lm.py --quant w8 --kv-int8
 """
+from repro.compile_cache import use_compile_cache
 from repro.launch.serve import main
+use_compile_cache()
 main()

@@ -13,13 +13,17 @@ Run:  PYTHONPATH=src python examples/serve_lstm_realtime.py
 """
 import time
 
+import jax
 import numpy as np
 
 import repro
+from repro.compile_cache import use_compile_cache
 from repro.core.accelerator import PAPER_DEFAULT, PAPER_NO_MXU
 from repro.core.qlstm import QLSTMConfig
 from repro.data.timeseries import pems_like_dataset
 from repro.serving import StreamServer
+
+use_compile_cache()
 
 cfg = QLSTMConfig()
 data = pems_like_dataset(seq_len=cfg.seq_len)
@@ -39,8 +43,10 @@ preds = list(acc.serve(iter(x[:N]), batch=BATCH))
 dt = time.perf_counter() - t0
 sps = len(preds) / dt
 ops = acc.report()["ops_per_inference"]
+dev = jax.devices()[0]
 print(f"[serve] {len(preds)} samples in {dt:.2f}s = {sps:,.0f} samples/s "
-      f"(CPU interpret mode; paper: 32,873 samples/s on FPGA)")
+      f"on {dev.platform} ({dev.device_kind}; paper: 32,873 samples/s on "
+      f"FPGA)")
 print(f"[serve] equivalent GOP/s at this rate: {sps*ops/1e9:.3f}")
 print(f"[serve] stream MSE vs targets: "
       f"{float(np.mean((np.stack(preds) - y[:N]) ** 2)):.5f}")

@@ -11,5 +11,7 @@ Run:  PYTHONPATH=src python examples/train_lstm_pems.py [--steps 400]
 import sys
 sys.argv = [sys.argv[0], "--arch", "lstm-pems",
             "--ckpt-dir", "/tmp/repro_lstm_ckpt"] + sys.argv[1:]
+from repro.compile_cache import use_compile_cache
 from repro.launch.train import main
+use_compile_cache()
 main()

@@ -43,7 +43,7 @@ from repro import backends, cells
 from repro.core import fixed_point as fxp
 from repro.core.accelerator import (AcceleratorConfig, plan as resolve_plan,
                                     resolve_model, sync_accelerator)
-from repro.core.energy import power_report
+from repro.core.energy import check_modelled_device, power_report
 from repro.core.qlstm import QLSTMConfig
 
 Array = jax.Array
@@ -256,18 +256,22 @@ class Accelerator:
 
     def init_state_table(self, max_slots: int) -> Array:
         """The reset DEVICE-RESIDENT state table for
-        ``compiled_stateful_slots``: a zero ``(max_slots + 2, L, S, H)``
-        int32 array where ``(L, S, H)`` is the cell's
-        ``plan()['state_shape']`` (axis 2 is the carry arity — (h, c) for
-        the LSTM, a single h row for GRU/rGLRU), committed to this
-        session's device when the session is pinned (``replicate``).
-        Rows ``max_slots`` and ``max_slots + 1`` are the conventions of
-        the slot kernel: the always-zero RESET row fresh/evicted streams
-        gather from, and the write-only TRASH row retired/padding rows
-        scatter to (``kernels/qlstm_cell.qlstm_seq_slot_pallas``)."""
+        ``compiled_stateful_slots``: a zero int32 array of
+        ``kernels.qlstm_cell.state_table_shape(max_slots + 2, L, S, H)``,
+        where ``(L, S, H)`` is the cell's ``plan()['state_shape']`` (S is
+        the carry arity — (h, c) for the LSTM, a single h for GRU/rGLRU),
+        committed to this session's device when the session is pinned
+        (``replicate``).  Slots ``max_slots`` and ``max_slots + 1`` are
+        the conventions of the slot kernel: the always-zero RESET slot
+        fresh/evicted streams gather from, and the write-only TRASH slot
+        retired/padding rows scatter to
+        (``kernels/qlstm_cell.qlstm_seq_slot_pallas``)."""
+        from repro.kernels.qlstm_cell import state_table_shape
         if max_slots < 1:
             raise ValueError(f"max_slots must be >= 1, got {max_slots}")
-        tbl = jnp.zeros((max_slots + 2, *self.plan["state_shape"]), jnp.int32)
+        tbl = jnp.zeros(state_table_shape(max_slots + 2,
+                                          *self.plan["state_shape"]),
+                        jnp.int32)
         return jax.device_put(tbl, self.device) if self.device is not None \
             else tbl
 
@@ -437,7 +441,9 @@ class Accelerator:
     def report(self, latency_s: float = PAPER_LATENCY_S,
                batch: int = 1) -> Dict[str, Any]:
         """Resolved plan + op/footprint accounting + the Table-4-style
-        energy report at the given operating point."""
+        energy report at the given operating point.  Raises on a TPU the
+        energy model has no constants for (``core.energy``)."""
+        check_modelled_device(self.device or jax.devices()[0])
         ops = self.cell.ops_per_inference(self.model)
         energy = power_report(
             flops=ops * batch, hbm_bytes=self.plan["weight_bytes"],

@@ -52,8 +52,9 @@ engine may additionally expose
                      table, gather_slots, scatter_slots)
       -> (y_int, new_table)
 
-where ``table`` is the persistent ``(n_slots + 2, L, S, H)`` int32 state
-table and the slot vectors are per-batch-row table-row ids (the contract
+where ``table`` is the persistent int32 state table of
+``kernels.qlstm_cell.state_table_shape(n_slots + 2, L, S, H)`` and the
+slot vectors are per-batch-row slot ids (the contract
 of ``kernels/qlstm_cell.qlstm_seq_slot_pallas``).  The ``pallas`` engine
 gathers/scatters inside the fused kernel; ``ref`` and ``xla`` use the
 XLA-level adapter (``common.run_slots_via_state`` — still device-side,

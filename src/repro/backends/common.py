@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.core import fixed_point as fxp
 from repro.core.accelerator import AcceleratorConfig
 from repro.core.qlstm import QLSTMConfig, check_int_state
+from repro.kernels.qlstm_cell import gather_carry, scatter_carry
 
 Array = jax.Array
 
@@ -64,23 +65,17 @@ def run_slots_via_state(run_stateful: Callable, qparams, x_int: Array,
     itself only understands dense state.  This keeps every rung of the
     serving degradation ladder device-resident: falling back from the
     fused pallas kernel to ``xla``/``ref`` changes latency, never where
-    the state lives.  The carry arity is read off the table itself
-    (``table.shape == (slots + 2, L, S, H)``), so the adapter serves every
-    registered cell — LSTM's ``S == 2`` (h, c) and the single-array GRU /
-    rGLRU carries alike.
+    the state lives.  The carry layout comes from the cell spec
+    (``cells.state_shape``), so the adapter serves every registered cell
+    — LSTM's (h, c) and the single-array GRU / rGLRU carries alike.
 
     Same table contract as ``kernels/qlstm_cell.qlstm_seq_slot_pallas``
-    (rows ``n_slots``/``n_slots + 1`` are the ZERO/TRASH slots); returns
+    (slots ``n_slots``/``n_slots + 1`` are the ZERO/TRASH slots); returns
     ``(y_int, new_table)``."""
-    nl, arity = table.shape[1], table.shape[2]
-    state = tuple(tuple(jnp.take(table[:, li, s, :], gather_slots, axis=0)
-                        for s in range(arity))
-                  for li in range(nl))
+    from repro import cells  # lazy: avoids the cells -> kernels -> backends cycle
+    state = gather_carry(table, gather_slots, *cells.state_shape(model))
     y_int, new_state = run_stateful(qparams, x_int, model, accel, state)
-    for li, layer_carry in enumerate(new_state):
-        for s, arr in enumerate(layer_carry):
-            table = table.at[scatter_slots, li, s, :].set(arr)
-    return y_int, table
+    return y_int, scatter_carry(table, scatter_slots, new_state)
 
 
 def run_layered_stateful(layer_fn: Callable, qparams, x_int: Array,

@@ -24,6 +24,22 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional
 
+#: ``device_kind`` strings of the one chip the constants below describe.
+#: Off the TPU (CPU runs) the model is a projection onto this chip.
+MODELLED_TPU_KINDS = ("TPU v5 lite", "TPU v5e")
+
+
+def check_modelled_device(device) -> None:
+    """Raise unless the energy model describes ``device``: any TPU other
+    than a v5e would get v5e numbers printed under its own name."""
+    if device.platform == "tpu" and device.device_kind not in \
+            MODELLED_TPU_KINDS:
+        raise RuntimeError(
+            f"the energy model's constants describe a TPU v5e; this device "
+            f"is {device.device_kind!r} — add its constants before "
+            f"reporting energy on it")
+
+
 # --- Roofline peaks (task-specified) ---------------------------------------
 PEAK_BF16_FLOPS = 197e12          # per chip
 PEAK_INT8_OPS = 394e12            # MXU int8 = 2x bf16

@@ -40,15 +40,16 @@ Three public entry points share one cell-step implementation:
     removes from the serving hot path).
   * :func:`qlstm_seq_slot_pallas` — the multi-layer kernel with
     DEVICE-RESIDENT stream state: instead of shipping ``(h0, c0)`` batch
-    arrays from the host, the call carries a persistent state TABLE of
-    shape ``(n_slots + 2, L, 2, H)`` plus two per-row int32 slot-id
-    vectors.  At t == 0 each batch row gathers its carry from
-    ``table[gather_slots[i]]``; at t == T-1 each row scatters its final
-    (h, c) into ``table[scatter_slots[i]]`` — all inside the kernel, so
-    the host ships only integer inputs and slot ids per wave.  Row
-    ``n_slots`` is the ZERO slot (always the reset carry, gathered by
-    fresh/reset streams, never written); row ``n_slots + 1`` is the TRASH
-    slot (the scatter target for padding/retired rows, never read).
+    arrays from the host, the call carries a persistent HBM state TABLE
+    (:func:`state_table_shape`: ``n_slots + 2`` slots, each stream's
+    per-layer (h, c) in 128-lane rows) plus two per-row int32 slot-id
+    vectors.  At t == 0 each batch row DMAs its carry from slot
+    ``gather_slots[i]``; at t == T-1 each row DMAs its final (h, c) into
+    slot ``scatter_slots[i]`` — all inside the kernel, so the host ships
+    only integer inputs and slot ids per wave.  Slot ``n_slots`` is the
+    ZERO slot (always the reset carry, gathered by fresh/reset streams,
+    never written); slot ``n_slots + 1`` is the TRASH slot (the scatter
+    target for padding/retired rows, never read).
     Because every gather happens at t == 0 and every scatter at t == T-1,
     a slot freed and reassigned within one wave is still read before it
     is overwritten.
@@ -192,36 +193,116 @@ def _make_kernel(cfg: FixedPointConfig, hdim: int, hs_method: str,
     return kernel
 
 
+#: Lane width of the state table's minor axis.  Mosaic moves one table
+#: row per DMA only when the row is exactly one 128-lane tile wide, so
+#: wider carries are split into 128-lane chunks along the leading axis.
+TABLE_LANES = 128
+
+
+def table_chunks(hidden: int) -> int:
+    """128-lane chunks one (hidden,) carry component occupies."""
+    return -(-hidden // TABLE_LANES)
+
+
+def state_table_shape(n_rows: int, num_layers: int, arity: int,
+                      hidden: int) -> Tuple[int, int, int]:
+    """Shape of the device state table: ``(K, n_rows, 128)`` int32 with
+    ``K = num_layers * arity * table_chunks(hidden)``.
+
+    Component-major: chunk ``j`` of carry component ``s`` of layer ``li``
+    is the ``(n_rows, 128)`` slab ``k = (li * arity + s) * chunks + j``,
+    one stream per sublane row, its codes in the low lanes and zeros in
+    the pad lanes.  One stream's whole carry is the strided column
+    ``table[:, slot, :]`` — a single DMA — and the slabs tile HBM with no
+    sublane padding."""
+    return (num_layers * arity * table_chunks(hidden), n_rows, TABLE_LANES)
+
+
+def _components(rows: Array, hidden: int):
+    """``(K, B, 128)`` staged rows -> the K/chunks ``(B, hidden)`` carry
+    components in table order."""
+    nch = table_chunks(hidden)
+    return [jnp.concatenate([rows[k + j] for j in range(nch)],
+                            axis=1)[:, :hidden] if nch > 1
+            else rows[k][:, :hidden]
+            for k in range(0, rows.shape[0], nch)]
+
+
+def gather_carry(table: Array, slots: Array, num_layers: int, arity: int,
+                 hidden: int):
+    """The per-layer carry of table rows ``slots``: ``num_layers`` tuples
+    of ``arity`` (B, hidden) int32 arrays — the inverse of
+    :func:`scatter_carry`, in jnp for the XLA engines and the host."""
+    comps = _components(jnp.take(table, slots, axis=1), hidden)
+    return tuple(tuple(comps[li * arity:(li + 1) * arity])
+                 for li in range(num_layers))
+
+
+def scatter_carry(table: Array, slots: Array, state) -> Array:
+    """``table`` with rows ``slots`` set to the per-layer carry ``state``
+    (tuples of (B, hidden) arrays); pad lanes are written as zeros."""
+    comps = [jnp.asarray(a, jnp.int32) for layer in state for a in layer]
+    bsz, hidden = comps[0].shape
+    nch = table_chunks(hidden)
+    pad = nch * TABLE_LANES - hidden
+    rows = jnp.stack([jnp.pad(a, ((0, 0), (0, pad)))
+                      .reshape(bsz, nch, TABLE_LANES).transpose(1, 0, 2)
+                      for a in comps]).reshape(-1, bsz, TABLE_LANES)
+    return table.at[:, slots, :].set(rows)
+
+
 def _make_slot_kernel(cfg: FixedPointConfig, hdim: int, hs_method: str,
                       hs_slope_shift: int, hs_bound: float,
                       ht_min: float, ht_max: float, compute_unit: str,
-                      t_len: int, num_layers: int):
+                      t_len: int, num_layers: int, bsz: int):
     requant, hs, ht = _cell_math(cfg, hs_method, hs_slope_shift, hs_bound,
                                  ht_min, ht_max)
+    nch = table_chunks(hdim)
 
     def kernel(*refs):
-        # Ref layout (L = num_layers): x, gather_slots, scatter_slots,
-        # table | L*w_x, L*w_h, L*b | out, table_out | L*h_s, L*c_s.
+        # Ref layout (L = num_layers): gather_slots, scatter_slots (SMEM,
+        # scalar-prefetched) | x, table (HBM) | L*w_x, L*w_h, L*b | out,
+        # table_out (HBM, aliases table) | rows, L*h_s, L*c_s, dma_sem.
         n = num_layers
-        x_ref, g_ref, s_ref, tbl_ref = refs[:4]
+        g_ref, s_ref, x_ref, tbl_in = refs[:4]
         wx = refs[4:4 + n]
         wh = refs[4 + n:4 + 2 * n]
         b = refs[4 + 2 * n:4 + 3 * n]
         out_ref = refs[4 + 3 * n]
         tbl_out = refs[5 + 3 * n]
-        h_s = refs[6 + 3 * n:6 + 4 * n]
-        c_s = refs[6 + 4 * n:6 + 5 * n]
+        rows = refs[6 + 3 * n]
+        h_s = refs[7 + 3 * n:7 + 4 * n]
+        c_s = refs[7 + 4 * n:7 + 5 * n]
+        sem = refs[7 + 5 * n]
         t = pl.program_id(0)
+        carries = [ref for li in range(n) for ref in (h_s[li], c_s[li])]
+
+        def copy_rows(src, src_row, dst, dst_row):
+            # One strided DMA per batch row moves that stream's whole
+            # carry (every layer, h and c); start all B, then wait all B.
+            def row(ref, r):
+                return ref.at[:, pl.ds(r, 1), :]
+
+            def start(i, c):
+                pltpu.make_async_copy(row(src, src_row(i)),
+                                      row(dst, dst_row(i)), sem).start()
+                return c
+
+            def wait(i, c):
+                pltpu.make_async_copy(row(src, 0), row(dst, 0), sem).wait()
+                return c
+
+            jax.lax.fori_loop(0, bsz, start, 0)
+            jax.lax.fori_loop(0, bsz, wait, 0)
 
         @pl.when(t == 0)
         def _():
-            # In-kernel GATHER: row i's carry comes from table row
-            # gather_slots[i] — the ZERO row for fresh/reset streams.
-            g = g_ref[0]
-            tbl = tbl_ref[...]
-            for li in range(n):
-                h_s[li][...] = jnp.take(tbl[:, li, 0, :], g, axis=0)
-                c_s[li][...] = jnp.take(tbl[:, li, 1, :], g, axis=0)
+            # GATHER: row i's carry comes from table row gather_slots[i] —
+            # the ZERO row for fresh/reset streams.  Every gather completes
+            # before any scatter starts.
+            copy_rows(tbl_in, lambda i: g_ref[i], rows, lambda i: i)
+            for ref, comp in zip(carries, _components(rows, hdim)):
+                ref[...] = comp
 
         out_ref[0] = _stack_step(
             x_ref[0], wx, wh, b, h_s, c_s, hdim=hdim,
@@ -230,16 +311,17 @@ def _make_slot_kernel(cfg: FixedPointConfig, hdim: int, hs_method: str,
 
         @pl.when(t == t_len - 1)
         def _():
-            # In-kernel SCATTER: row i's final (h, c) lands in table row
+            # SCATTER: row i's final (h, c) lands in table row
             # scatter_slots[i] — the TRASH row for retired/padding rows.
             # Duplicate targets only ever occur at TRASH (the allocator
             # hands out unique live slots), whose content is never read.
-            s = s_ref[0]
-            tbl = tbl_ref[...]
-            for li in range(n):
-                tbl = tbl.at[s, li, 0, :].set(h_s[li][...])
-                tbl = tbl.at[s, li, 1, :].set(c_s[li][...])
-            tbl_out[...] = tbl
+            # Pad lanes keep the gathered row's zeros.
+            for k, ref in enumerate(carries):
+                for j in range(nch):
+                    w = min(TABLE_LANES, hdim - j * TABLE_LANES)
+                    rows[k * nch + j, :, :w] = \
+                        ref[:, j * TABLE_LANES:j * TABLE_LANES + w]
+            copy_rows(rows, lambda i: i, tbl_out, lambda i: s_ref[i])
 
     return kernel
 
@@ -410,21 +492,22 @@ def qlstm_seq_slot_pallas(x_int: Array, gather_slots: Array,
                           interpret: bool = True):
     """The fused multi-layer stack with DEVICE-RESIDENT stream state.
 
-    x_int: (T, B, M) integer codes; ``table``: the persistent
-    ``(n_slots + 2, L, 2, H)`` int32 state table (axis 2 is (h, c); row
-    ``n_slots`` is the always-zero RESET slot, row ``n_slots + 1`` the
-    write-only TRASH slot); ``gather_slots``/``scatter_slots``: (B,) int32
-    table-row ids, one per batch row.  Weight tuples as in
-    :func:`qlstm_seq_multilayer_pallas`.
+    x_int: (T, B, M) integer codes; ``table``: the persistent int32 state
+    table, shaped ``state_table_shape(n_slots + 2, L, 2, H)`` — axis 1
+    indexes slots, slot ``n_slots`` is the always-zero RESET slot and
+    slot ``n_slots + 1`` the write-only TRASH slot; ``gather_slots``/
+    ``scatter_slots``: (B,) int32 slot ids, one per batch row.  Weight
+    tuples as in :func:`qlstm_seq_multilayer_pallas`.
 
-    At t == 0 the kernel gathers row i's per-layer carry from
-    ``table[gather_slots[i]]`` into VMEM scratch; at t == T-1 it scatters
-    the final per-layer (h, c) into ``table[scatter_slots[i]]`` and emits
-    the updated table.  The host therefore ships only the integer inputs
-    and two (B,) slot vectors per wave — no (h, c) batch arrays cross the
-    host/device boundary on the hot path.  Because all gathers precede all
-    scatters inside one call, a slot evicted and reassigned within the
-    same wave still sources its old owner's carry correctly.
+    The table stays in HBM and the slot ids are scalar-prefetched into
+    SMEM.  At t == 0 the kernel DMAs row i's per-layer carry from slot
+    ``gather_slots[i]`` into VMEM; at t == T-1 it DMAs the final
+    per-layer (h, c) into slot ``scatter_slots[i]`` of the output table,
+    which aliases the input — only the B scattered rows are written.  The
+    host therefore ships only the integer inputs and two (B,) slot
+    vectors per wave.  Because all gathers complete before any scatter
+    starts, a slot evicted and reassigned within the same wave still
+    sources its old owner's carry correctly.
 
     The whole batch runs as ONE grid block (grid is over time only): every
     row scatters into one shared table, so the grid must not parallelise
@@ -440,40 +523,40 @@ def qlstm_seq_slot_pallas(x_int: Array, gather_slots: Array,
             f"w_xs={len(w_xs)}, w_hs={n}, b_wides={len(b_wides)}")
     t_len, bsz, m = x_int.shape
     hdim = w_hs[0].shape[0]
-    if table.ndim != 4 or table.shape[0] < 3 or table.shape[1:] != (n, 2,
-                                                                    hdim):
+    n_rows = table.shape[1] if table.ndim == 3 else 0
+    if n_rows < 3 or table.shape != state_table_shape(n_rows, n, 2, hdim):
         raise ValueError(
-            f"state table must be (n_slots + 2, {n}, 2, {hdim}) with "
-            f"n_slots >= 1, got {table.shape}")
+            f"state table must be state_table_shape(n_slots + 2, {n}, 2, "
+            f"{hdim}) with n_slots >= 1, got {table.shape}")
     sd = x_int.dtype
-    gather_slots = gather_slots.reshape(1, bsz).astype(jnp.int32)
-    scatter_slots = scatter_slots.reshape(1, bsz).astype(jnp.int32)
-    table = table.astype(jnp.int32)
 
     kernel = _make_slot_kernel(cfg, hdim, hs_method, hs_slope_shift,
                                hs_bound, ht_min, ht_max, compute_unit,
-                               t_len, n)
-    res2 = lambda t: (0, 0)                             # resident across t
-    res4 = lambda t: (0, 0, 0, 0)
-    in_specs = [pl.BlockSpec((1, bsz, m), lambda t: (t, 0, 0)),
-                pl.BlockSpec((1, bsz), res2),
-                pl.BlockSpec((1, bsz), res2),
-                pl.BlockSpec(table.shape, res4)]
+                               t_len, n, bsz)
+    res2 = lambda t, g, s: (0, 0)                       # resident across t
+    per_t = lambda t, g, s: (t, 0, 0)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, bsz, m), per_t), hbm]
     in_specs += [pl.BlockSpec(w.shape, res2) for w in w_xs]
     in_specs += [pl.BlockSpec(w.shape, res2) for w in w_hs]
     in_specs += [pl.BlockSpec((1, 4 * hdim), res2)] * n
-    out_specs = [pl.BlockSpec((1, bsz, hdim), lambda t: (t, 0, 0)),
-                 pl.BlockSpec(table.shape, res4)]
-    out_shape = [jax.ShapeDtypeStruct((t_len, bsz, hdim), sd),
-                 jax.ShapeDtypeStruct(table.shape, jnp.int32)]
+    scratch = [pltpu.VMEM((table.shape[0], bsz, TABLE_LANES), jnp.int32)]
+    scratch += [pltpu.VMEM((bsz, hdim), jnp.int32)] * (2 * n)
+    scratch += [pltpu.SemaphoreType.DMA(())]
     outs = pl.pallas_call(
         kernel,
-        grid=(t_len,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((bsz, hdim), jnp.int32)] * (2 * n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(t_len,), in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, bsz, hdim), per_t), hbm],
+            scratch_shapes=scratch),
+        out_shape=[jax.ShapeDtypeStruct((t_len, bsz, hdim), sd),
+                   jax.ShapeDtypeStruct(table.shape, jnp.int32)],
+        input_output_aliases={3: 1},            # table -> new table
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(x_int, gather_slots, scatter_slots, table, *w_xs, *w_hs,
+    )(gather_slots.reshape(bsz).astype(jnp.int32),
+      scatter_slots.reshape(bsz).astype(jnp.int32), x_int,
+      table.astype(jnp.int32), *w_xs, *w_hs,
       *(b.reshape(1, -1).astype(jnp.int32) for b in b_wides))
     return outs[0], outs[1]

@@ -186,21 +186,16 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     # accounting re-lowers (no backend compile) with every model loop
     # unrolled.  The UNCOMPILED module is the GLOBAL program (SPMD
     # partitioning happens at compile), so per-device = global / chips.
-    def _ca(obj):
-        # jax < 0.5 returns a per-device list of dicts; newer jax a dict.
-        out = obj.cost_analysis() or {}
-        return out[0] if isinstance(out, (list, tuple)) else out
-
     if skip_cost_pass:
-        ca = _ca(compiled)
+        ca = compiled.cost_analysis() or {}
         rec["flops_per_device"] = float(ca.get("flops", 0.0))
     else:
         t1 = time.time()
         with rules_context(mesh, cfg.sharding_overrides), unroll_mode():
             lowered_cost, _ = _lower_step(cfg, shape, mesh, quant_serve)
-        ca = _ca(lowered_cost)
+        ca = lowered_cost.cost_analysis() or {}
         rec["cost_lower_s"] = round(time.time() - t1, 1)
-        ca_scan = _ca(compiled)
+        ca_scan = compiled.cost_analysis() or {}
         rec["flops_per_device_scanned_hlo"] = float(ca_scan.get("flops", 0.0))
         rec["flops_global"] = float(ca.get("flops", 0.0))
         rec["flops_per_device"] = rec["flops_global"] / chips

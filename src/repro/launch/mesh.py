@@ -8,17 +8,10 @@ XLA_FLAGS before any jax initialisation.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: make_mesh has no axis_types kwarg
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes, devices=None):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes, devices=devices)
     return jax.make_mesh(shape, axes, devices=devices,
                          axis_types=(AxisType.Auto,) * len(axes))
 
@@ -59,6 +52,8 @@ def serving_devices(n: int, devices=None, *, oversubscribe: bool = True):
     before XLA_FLAGS forces extra host devices) reuses devices cyclically,
     while ``oversubscribe=False`` raises — the production posture, where a
     "replica" that silently shares a device is a capacity-planning bug.
+    On a TPU it always raises: replicas sharing a chip would report one
+    chip's throughput as several.
     """
     if n < 1:
         raise ValueError(f"need n >= 1 replicas, got {n}")
@@ -70,6 +65,10 @@ def serving_devices(n: int, devices=None, *, oversubscribe: bool = True):
                 f"explicit devices")
         return devices[:n]
     avail = jax.devices()
+    if len(avail) < n and avail[0].platform == "tpu":
+        raise RuntimeError(
+            f"need {n} TPU chips for {n} replicas, have {len(avail)}; "
+            "replicas never share a chip")
     if len(avail) < n and not oversubscribe:
         raise RuntimeError(
             f"need {n} devices for {n} replicas, have {len(avail)} — set "

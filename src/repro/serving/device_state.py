@@ -2,10 +2,11 @@
 
 The host-side :class:`~repro.serving.state.StateStore` ships every
 stream's carry codes to the device and back on EVERY wave.  This module
-is ROADMAP item 1's answer: the carries live in one persistent
-``(max_slots + 2, L, S, H)`` int32 table ON the accelerator (``(L, S,
-H)`` is the cell's ``plan()['state_shape']`` — ``S == 2`` (h, c) rows
-for the LSTM, ``S == 1`` for GRU/rGLRU)
+is ROADMAP item 1's answer: the carries live in one persistent int32
+table ON the accelerator, ``max_slots + 2`` slots of the cell's
+``plan()['state_shape']`` ``(L, S, H)`` carry — ``S == 2`` (h, c) for
+the LSTM, ``S == 1`` for GRU/rGLRU — laid out by
+``kernels.qlstm_cell.state_table_shape``
 (``Accelerator.init_state_table``), and the host keeps only a
 :class:`SlotAllocator` — an LRU map ``stream_id -> table row`` with
 exactly the hit/miss/eviction accounting of the ``StateStore`` it
@@ -16,16 +17,16 @@ carry array crosses the host/device boundary on the hot path — the
 paper's state-next-to-compute residency argument, and ELSA's throughput
 lever, applied to serving.
 
-Table row conventions (shared with the kernel and the XLA-level adapter
+Table slot conventions (shared with the kernel and the XLA-level adapter
 ``backends.common.run_slots_via_state``):
 
-  * rows ``0 .. max_slots-1`` — live stream carries, owned by the
+  * slots ``0 .. max_slots-1`` — live stream carries, owned by the
     allocator;
-  * row ``max_slots`` (:attr:`DeviceStateStore.zero_slot`) — the RESET
-    row: always all-zero, gathered by fresh/evicted/ended streams, never
+  * slot ``max_slots`` (:attr:`DeviceStateStore.zero_slot`) — the RESET
+    slot: always all-zero, gathered by fresh/evicted/ended streams, never
     written;
-  * row ``max_slots + 1`` (:attr:`DeviceStateStore.trash_slot`) — the
-    TRASH row: the scatter target for padding rows, tombstoned windows,
+  * slot ``max_slots + 1`` (:attr:`DeviceStateStore.trash_slot`) — the
+    TRASH slot: the scatter target for padding rows, tombstoned windows,
     and same-wave eviction victims; never read.
 
 Eviction/reset semantics are IDENTICAL to the host store: an evicted or
@@ -51,6 +52,7 @@ from typing import Dict, Hashable, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.qlstm_cell import gather_carry, scatter_carry
 from repro.serving.state import StreamState
 
 
@@ -165,8 +167,8 @@ class DeviceStateStore:
         serving knob)."""
         self.capacity = capacity
         self._alloc = SlotAllocator(capacity)
-        self._model = session.model
-        #: The persistent (capacity + 2, L, S, H) int32 carry table.  The
+        self._shape = session.plan["state_shape"]      # (L, S, H)
+        #: The persistent int32 carry table (``capacity + 2`` slots).  The
         #: serving hot path replaces this reference wholesale after each
         #: wave (:meth:`commit`) — the array itself never visits the host.
         self.table = session.init_state_table(capacity)
@@ -230,9 +232,8 @@ class DeviceStateStore:
             table = self.table
         if slot is None:
             return None
-        row = np.asarray(table[slot])              # (L, S, H) — one stream
-        return [tuple(row[li, s].copy() for s in range(row.shape[1]))
-                for li in range(row.shape[0])]
+        state = gather_carry(table, jnp.asarray([slot]), *self._shape)
+        return [tuple(np.asarray(a)[0] for a in layer) for layer in state]
 
     def seed_state(self, stream_id: Hashable,
                    state: StreamState) -> List[Hashable]:
@@ -241,10 +242,10 @@ class DeviceStateStore:
         ids the assignment evicted."""
         with self._lock:
             slot, evicted = self._alloc.assign(stream_id)
-            row = jnp.asarray(
-                np.stack([np.stack([np.asarray(a) for a in layer])
-                          for layer in state]).astype(np.int32))
-            self.table = self.table.at[slot].set(row)
+            self.table = scatter_carry(
+                self.table, jnp.asarray([slot]),
+                [tuple(np.asarray(a)[None] for a in layer)
+                 for layer in state])
         return evicted
 
     # -- fault-injection surface ---------------------------------------------
@@ -258,8 +259,12 @@ class DeviceStateStore:
             slot = self._alloc.slot_of(stream_id)
             if slot is None:
                 return False
-            self.table = self.table.at[slot].set(
-                jnp.bitwise_xor(self.table[slot], 1))
+            slots = jnp.asarray([slot])
+            state = gather_carry(self.table, slots, *self._shape)
+            self.table = scatter_carry(
+                self.table, slots,
+                [tuple(jnp.bitwise_xor(a, 1) for a in layer)
+                 for layer in state])
             return True
 
     # -- StateStore-compatible reporting ------------------------------------

@@ -233,11 +233,12 @@ class StreamServer:
         self.config = cfg
         self._sessions = sessions
         self.fault_injector = fault_injector
-        # Resolve the degradation ladder and compile/validate NOW: a bad
+        # Resolve the degradation ladder and validate NOW: a bad
         # path/backend or an unquantised session fails at construction,
-        # not in the compute thread.  jit closures are lazy, so non-
-        # preferred ladder levels cost nothing until a degradation
-        # actually runs them.
+        # not in the compute thread.  The preferred engine is compiled at
+        # the first submit, once the window shape is known
+        # (_compile_preferred); jit closures are lazy, so non-preferred
+        # ladder levels cost nothing until a degradation runs them.
         from repro import backends as _backends
         #: Resolved carry placement: "device" | "host" on a stateful
         #: server, None on a stateless one (ServingConfig.state_residency
@@ -279,6 +280,7 @@ class StreamServer:
             ladder = (cfg.path,)
             self._fns = [[(cfg.path, s.compiled(cfg.path, cfg.backend))]
                          for s in sessions]
+        self._preferred = [per_session[0][1] for per_session in self._fns]
         if fault_injector is not None:
             self._fns = [[(n, fault_injector.wrap_fn(fn, label=n))
                           for n, fn in per_session]
@@ -353,6 +355,7 @@ class StreamServer:
                 "rejected at submit")
         with self._seq_lock:
             if self._window_shape is None:
+                self._compile_preferred(w.shape)
                 self._window_shape = w.shape
             elif w.shape != self._window_shape:
                 raise ValueError(f"window shape {w.shape} != first window's "
@@ -373,6 +376,31 @@ class StreamServer:
 
         self.metrics.note_submit(time.perf_counter())
         return self._sched.submit(stream_id, w, alloc_seq)
+
+    def _compile_preferred(self, window_shape: Tuple[int, ...]) -> None:
+        """Compile every session's preferred engine for waves of
+        ``(batch, *window_shape)``, outside the ``ExecutionGuard``.  A
+        kernel the compiler refuses raises here, to the first submitter:
+        the guard degrades past runtime faults, and a program that cannot
+        compile is not one.  Waves then hit jit's cache for the same
+        program."""
+        b = self.config.batch
+        x = jnp.zeros((b, *window_shape), jnp.float32)
+        for sess, fn in zip(self._sessions, self._preferred):
+            if self.state_residency == "device":
+                slots = jnp.zeros((b,), jnp.int32)
+                args = (x, self.states.table, slots, slots)
+            elif self.config.stateful:
+                args = (x, sess.init_state(b))
+            else:
+                args = (x,)
+            try:
+                fn.lower(*args).compile()
+            except Exception as e:
+                raise RuntimeError(
+                    f"the preferred engine {self.guard.ladder[0]!r} failed "
+                    f"to compile for waves of shape {x.shape}: "
+                    f"{type(e).__name__}: {e}") from e
 
     def poll(self, timeout: float = 0.0) -> List[StreamResult]:
         """Completed predictions, in wave order (per-stream order is always

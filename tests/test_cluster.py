@@ -216,6 +216,19 @@ def test_serving_devices_contract():
         serving_devices(2, devices=jax.devices()[:1])
 
 
+def test_serving_devices_never_shares_a_tpu(monkeypatch):
+    """On a TPU, replicas never share a chip, even with the CPU-test
+    posture's ``oversubscribe=True``; enough chips are dealt out one each."""
+    import types
+
+    from repro.launch import mesh
+    chips = [types.SimpleNamespace(platform="tpu", id=i) for i in range(2)]
+    monkeypatch.setattr(mesh.jax, "devices", lambda: chips)
+    with pytest.raises(RuntimeError, match="never share a chip"):
+        mesh.serving_devices(4)
+    assert mesh.serving_devices(2) == chips
+
+
 # ---------------------------------------------------------------------------
 # ClusterServer — the routing invariant, end to end
 # ---------------------------------------------------------------------------

@@ -78,6 +78,22 @@ def test_roofline_terms_and_bound():
     assert t2.bound == "memory"
 
 
+def test_energy_model_refuses_unmodelled_tpus():
+    """The constants describe a v5e: another TPU raises instead of being
+    reported with v5e numbers; the CPU keeps the v5e projection, and so
+    does ``Accelerator.report()`` here."""
+    import types
+
+    import repro
+    dev = lambda platform, kind: types.SimpleNamespace(platform=platform,
+                                                       device_kind=kind)
+    energy.check_modelled_device(dev("tpu", "TPU v5 lite"))
+    energy.check_modelled_device(dev("cpu", "cpu"))
+    with pytest.raises(RuntimeError, match="TPU v4"):
+        energy.check_modelled_device(dev("tpu", "TPU v4"))
+    assert repro.build().report()["energy"]["total_w"] > 0
+
+
 def test_power_report_static_dynamic_split():
     rep = energy.power_report(flops=1e12, hbm_bytes=1e9, ici_bytes=0,
                               latency_s=0.01, dtype="int8")

@@ -130,10 +130,12 @@ def test_dryrun_single_cell_small_mesh():
         import repro.launch.mesh as M
 
         def small_mesh(*, multi_pod=False):
+            # Auto axes, as the production mesh has: jax.make_mesh's own
+            # default is Explicit, whose type-level shardings the dry-run
+            # step does not annotate.
             shape = (2, 2, 2) if multi_pod else (2, 4)
             axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-            return jax.make_mesh(shape, axes,
-                                 devices=jax.devices()[:8 if multi_pod else 8])
+            return M._make_mesh(shape, axes, devices=jax.devices()[:8])
         M.make_production_mesh = small_mesh
         D.make_production_mesh = small_mesh
         rec = D.run_cell("qwen1.5-0.5b", "train_4k", False)

@@ -252,8 +252,10 @@ def test_autotune_reuses_payload_without_resweeping():
 
     space = explore.SearchSpace(fxp=(FXP_4_8, FXP_8_16), backend="ref",
                                 batch=8)
-    payload = explore.sweep(space, iters=2, seed=3)
-    assert payload["seed"] == 3
+    # Seed 1: under JAX's partitionable threefry (the default since 0.5)
+    # seed 3's (4,8) weights happen to quantise with the lower error.
+    payload = explore.sweep(space, iters=2, seed=1)
+    assert payload["seed"] == 1
     calls = []
     session = explore.autotune(payload=payload, objective="int_float_mse",
                                log=calls.append)
@@ -264,7 +266,7 @@ def test_autotune_reuses_payload_without_resweeping():
     # rebuilt with the PAYLOAD's seed: the deployed weights are the ones
     # the stored metrics were measured on
     cfgs = point_from_config(session.autotune_summary["best"]["config"])
-    want = repro.build(*cfgs.configs(), seed=3).params
+    want = repro.build(*cfgs.configs(), seed=1).params
     assert all(bool((a == b).all()) for a, b in
                zip(jax.tree.leaves(session.params), jax.tree.leaves(want)))
 

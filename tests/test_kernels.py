@@ -128,37 +128,44 @@ def _slot_battery_weights(num_layers, cfg, T, B, M, H):
     return x, tuple(wxs), tuple(whs), tuple(bs)
 
 
-@pytest.mark.parametrize("cfg", [FXP_4_8, FixedPointConfig(6, 8), FXP_8_16])
-@pytest.mark.parametrize("method", ["arithmetic", "step"])
-@pytest.mark.parametrize("num_layers", [1, 2, 3])
-def test_qlstm_slot_kernel_matches_gathered_multilayer(cfg, method,
-                                                       num_layers):
-    """The slot-battery acceptance sweep: the in-kernel gather/scatter
-    entry, over random slot PERMUTATIONS of a pre-filled state table, is
-    bit-exact with the multilayer kernel handed the same carries as
-    explicit (h, c) arrays — outputs AND the updated table — across fxp
-    widths, HardSigmoid methods, and 1-3 layers.  Also pins the table
-    conventions: ZERO-row gathers start the recurrence from the reset
-    carry, TRASH-row scatters drop a row's final state, and table rows
-    the wave never scattered to are byte-identical before/after."""
-    from repro.kernels.qlstm_cell import (qlstm_seq_multilayer_pallas,
-                                          qlstm_seq_slot_pallas)
-    T, B, M, H = 4, 5, 2, 8
+def _check_slot_kernel(cfg, method, num_layers, H):
+    """One slot-battery case: random slot permutations of a pre-filled
+    table through the slot kernel vs the multilayer kernel handed the
+    host-gathered carries, outputs AND updated table."""
+    from repro.kernels.qlstm_cell import (TABLE_LANES, gather_carry,
+                                          qlstm_seq_multilayer_pallas,
+                                          qlstm_seq_slot_pallas,
+                                          scatter_carry, state_table_shape,
+                                          table_chunks)
+    T, B, M = 4, 5, 2
     n_data = 6                      # slots 0..5; ZERO = 6, TRASH = 7
     zero_slot, trash_slot = n_data, n_data + 1
     x, wxs, whs, bs = _slot_battery_weights(num_layers, cfg, T, B, M, H)
     rng = np.random.default_rng(7 * num_layers + len(method))
+    all_slots = jnp.arange(n_data + 2)
+    shape = state_table_shape(n_data + 2, num_layers, 2, H)
     for trial in range(3):
         table = rng.integers(-100, 100,
                              (n_data + 2, num_layers, 2, H)).astype(np.int32)
         table[zero_slot] = 0
+        packed = scatter_carry(
+            jnp.zeros(shape, jnp.int32), all_slots,
+            [(table[:, li, 0], table[:, li, 1]) for li in range(num_layers)])
         gather = rng.permutation(n_data)[:B].astype(np.int32)
         scatter = rng.permutation(n_data)[:B].astype(np.int32)
         gather[0] = zero_slot       # a fresh/evicted stream's row
         scatter[1] = trash_slot     # a padding/tombstoned row
-        got, new_table = qlstm_seq_slot_pallas(
-            x, jnp.asarray(gather), jnp.asarray(scatter), jnp.asarray(table),
+        got, new_packed = qlstm_seq_slot_pallas(
+            x, jnp.asarray(gather), jnp.asarray(scatter), packed,
             wxs, whs, bs, cfg=cfg, hs_method=method)
+        # Pad lanes stay zero; unpack to the logical (slots, L, 2, H) view.
+        lanes = np.asarray(new_packed).reshape(
+            num_layers * 2, table_chunks(H), n_data + 2, TABLE_LANES
+        ).transpose(0, 2, 1, 3).reshape(num_layers * 2, n_data + 2, -1)
+        assert not lanes[..., H:].any()
+        new_table = np.stack(
+            [np.stack(layer, axis=1) for layer in gather_carry(
+                new_packed, all_slots, num_layers, 2, H)], axis=1)
         # Oracle: gather the same carries host-side, run the plain
         # multilayer kernel, scatter host-side.
         h0s = tuple(jnp.asarray(table[gather, li, 0])
@@ -175,26 +182,49 @@ def test_qlstm_slot_kernel_matches_gathered_multilayer(cfg, method,
             for li in range(num_layers):
                 expect[scatter[i], li, 0] = np.asarray(state[li][0][i])
                 expect[scatter[i], li, 1] = np.asarray(state[li][1][i])
-        expect[trash_slot] = np.asarray(new_table)[trash_slot]  # don't-care
-        np.testing.assert_array_equal(np.asarray(new_table), expect)
+        expect[trash_slot] = new_table[trash_slot]  # don't-care
+        np.testing.assert_array_equal(new_table, expect)
         # The ZERO row survives every wave unwritten.
-        assert not np.asarray(new_table)[zero_slot].any()
+        assert not new_table[zero_slot].any()
+
+
+@pytest.mark.parametrize("cfg", [FXP_4_8, FixedPointConfig(6, 8), FXP_8_16])
+@pytest.mark.parametrize("method", ["arithmetic", "step"])
+@pytest.mark.parametrize("num_layers", [1, 2, 3])
+def test_qlstm_slot_kernel_matches_gathered_multilayer(cfg, method,
+                                                       num_layers):
+    """The slot-battery acceptance sweep: the in-kernel gather/scatter
+    entry, over random slot PERMUTATIONS of a pre-filled state table, is
+    bit-exact with the multilayer kernel handed the same carries as
+    explicit (h, c) arrays — outputs AND the updated table — across fxp
+    widths, HardSigmoid methods, and 1-3 layers.  Also pins the table
+    conventions: ZERO-row gathers start the recurrence from the reset
+    carry, TRASH-row scatters drop a row's final state, and table rows
+    the wave never scattered to are byte-identical before/after."""
+    _check_slot_kernel(cfg, method, num_layers, H=8)
+
+
+def test_qlstm_slot_kernel_multi_chunk_rows():
+    """A carry wider than one 128-lane table row (H=136 spans two row
+    chunks per component) through the same battery."""
+    _check_slot_kernel(FXP_4_8, "arithmetic", 2, H=136)
 
 
 def test_qlstm_slot_kernel_validates_inputs():
     """Layer-count mismatches and undersized tables fail loudly."""
-    from repro.kernels.qlstm_cell import qlstm_seq_slot_pallas
+    from repro.kernels.qlstm_cell import (qlstm_seq_slot_pallas,
+                                          state_table_shape)
     cfg = FXP_4_8
     x, wx, wh, b = _rand_lstm(3, 2, 1, 4, cfg)
     slots = jnp.zeros((2,), jnp.int32)
-    table = jnp.zeros((5, 1, 2, 4), jnp.int32)
+    table = jnp.zeros(state_table_shape(5, 1, 2, 4), jnp.int32)
     with pytest.raises(ValueError, match="layer count"):
         qlstm_seq_slot_pallas(x, slots, slots, table, (wx, wx), (wh,), (b,),
                               cfg=cfg)
-    with pytest.raises(ValueError, match="table"):
-        qlstm_seq_slot_pallas(x, slots, slots, jnp.zeros((2, 1, 2, 4),
-                                                         jnp.int32),
-                              (wx,), (wh,), (b,), cfg=cfg)
+    for bad in (state_table_shape(2, 1, 2, 4), (5, 1, 2, 4)):
+        with pytest.raises(ValueError, match="table"):
+            qlstm_seq_slot_pallas(x, slots, slots, jnp.zeros(bad, jnp.int32),
+                                  (wx,), (wh,), (b,), cfg=cfg)
 
 
 def test_qlstm_kernel_int16_datapath():

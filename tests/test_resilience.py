@@ -261,6 +261,33 @@ def test_degradation_and_promotion_round_trip_server(sess):
         srv.close()
 
 
+@pytest.mark.parametrize("residency", ["device", "host"])
+def test_preferred_engine_compile_refusal_raises_at_first_submit(
+        sess, monkeypatch, residency):
+    """A preferred engine the compiler refuses is an error, not a fault to
+    degrade past: the first submit raises, no wave runs, and the guard
+    never counts a retry, wave failure or degradation.  The stand-in
+    refusal is the fused pallas engine asked to compile its kernels for
+    the chip (interpret=False) on a CPU."""
+    import repro.backends.pallas as pallas_backend
+    # A fresh session: the compiled-closure cache must not hold the
+    # interpret-mode program already.
+    fresh = repro.build(MODEL, params=sess.params, seed=0).quantize()
+    monkeypatch.setattr(pallas_backend, "_interpret", lambda: False)
+    srv = StreamServer(fresh, batch=2, deadline_s=0.005,
+                       state_residency=residency)
+    try:
+        assert srv.guard.ladder[0] == "pallas"
+        with pytest.raises(RuntimeError, match="'pallas' failed to compile"):
+            srv.submit("s", _windows(1)[0])
+        g = srv.guard.stats()
+        assert (g["waves"], g["retries"], g["wave_failures"],
+                g["degradations"]) == (0, 0, 0, 0)
+        assert srv.health()["status"] == "ok" and not srv.poll()
+    finally:
+        srv.close(abandon=True)
+
+
 # ---------------------------------------------------------------------------
 # ExecutionGuard unit tests (plain callables, no server)
 # ---------------------------------------------------------------------------

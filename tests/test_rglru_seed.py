@@ -29,6 +29,8 @@ def cfg():
 
 @pytest.fixture(scope="module")
 def params(cfg):
+    # The pins below are the random bits jax.random.key(3) draws under
+    # the partitionable threefry that JAX >= 0.5 uses by default.
     p, _ = unbox(RG.init_rglru_block(jax.random.key(3), cfg))
     return p
 
@@ -59,10 +61,10 @@ def test_rglru_scan_shape_finite_and_pinned(cfg, params):
     h = RG.rglru_scan(params, x, cfg)
     assert h.shape == (2, 7, w)
     assert bool(jnp.all(jnp.isfinite(h)))
-    np.testing.assert_allclose(float(jnp.sum(h)), -21.403288, atol=1e-3)
+    np.testing.assert_allclose(float(jnp.sum(h)), -18.600780, atol=1e-3)
     np.testing.assert_allclose(
         np.asarray(h)[0, -1, :4],
-        [-1.177827, -0.383444, 0.061266, 0.279287], atol=1e-4)
+        [-0.694003, -0.418674, 0.379087, 0.140698], atol=1e-4)
 
 
 def test_rec_block_apply_train_shape_finite_and_pinned(cfg, params):
@@ -75,7 +77,7 @@ def test_rec_block_apply_train_shape_finite_and_pinned(cfg, params):
     assert bool(jnp.all(jnp.isfinite(y)))
     np.testing.assert_allclose(
         np.asarray(y)[1, -1, :4],
-        [0.187749, -1.501872, -0.00121, 0.372229], atol=1e-4)
+        [-1.313005, 1.646791, 0.959831, 0.029209], atol=1e-4)
 
 
 def test_rec_block_decode_equals_train(cfg, params):

@@ -18,6 +18,7 @@ import repro
 from repro import backends
 from repro.core.accelerator import AcceleratorConfig
 from repro.core.qlstm import QLSTMConfig, init_int_state
+from repro.kernels.qlstm_cell import state_table_shape
 from repro.serving import (ServingConfig, StateStore, StreamServer,
                            serve_windows)
 
@@ -745,7 +746,8 @@ def test_device_store_rejects_host_only_surfaces(sess):
     with pytest.raises(AttributeError, match="state_residency='host'"):
         store.put("s", _DUMMY_STATE)
     assert store.zero_slot == 4 and store.trash_slot == 5
-    assert store.table.shape == (6, MODEL.num_layers, 2, MODEL.hidden_size)
+    assert store.table.shape == state_table_shape(
+        6, MODEL.num_layers, 2, MODEL.hidden_size)
 
 
 def test_device_vs_host_bit_exact_under_eviction_churn(sess):
