@@ -10,32 +10,74 @@ headline is exactly this quantity at 32 873 samples/s).
 
 Latency definitions (the metrics glossary in docs/SERVING.md):
 
-  * ``compute_s``  — device time for the wave (dispatch to results ready).
+  * ``compute_s``  — host wall time of the server's execute for the wave,
+    from its first stage (slot gather) up to the emit loop: carries, the
+    batch's upload, the guarded device call, the wait for the results,
+    and the carry commit.  It includes the device time the host waits
+    out, but it is not a device clock.
   * ``latency_s``  — end-to-end for the wave's OLDEST window: submit ->
     results ready.  Queueing + assembly + compute; the quantity the
     deadline bounds, and what p50/p95/p99 are computed over.
+
+Stage times (``summary()["stages"]``), per wave and per window, split
+where a window's time goes.  A window waits in the scheduler's pending
+list from submit to ``t_built`` (its wave assembled), then in the queue of
+assembled waves until ``t_start`` (the compute thread starts the wave's
+execute), then executes until ``t_done``.  The execute runs the stages of
+:data:`STAGES` in order — ``slots`` (carry lookup), ``h2d`` (the batch's
+and slot vectors' upload), ``call`` (the guarded jitted call), ``ready``
+(waiting for the results and reading them back), ``commit`` (carry
+commit and in-flight bookkeeping), ``emit`` (recording and the results'
+delivery) — each also a ``serve.<stage>`` span on the profiler's trace
+when one is recording.  ``stage_cpu_s`` holds the compute thread's CPU
+time in every stage but ``ready``: against those stages' wall time it says
+whether the thread was working or waiting (for the interpreter lock, a
+lock, or a transfer).
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
+import math
 import threading
 from typing import Deque, Dict, List, Optional
 
 import numpy as np
 
+#: The server's execute stages, in the order they run (module docstring).
+STAGES = ("slots", "h2d", "call", "ready", "commit", "emit")
+
 
 @dataclasses.dataclass(frozen=True)
 class WaveRecord:
-    """One computed wave, as recorded by the scheduler's compute thread."""
+    """One computed wave, as recorded by the scheduler's compute thread.
+
+    The fields after ``deadline_flush`` are the stage times (module
+    docstring); a record without them (a failed wave, or one built by
+    hand) keeps their defaults and stays out of ``summary()["stages"]``."""
 
     t_done: float           # perf_counter when results were ready
-    compute_s: float        # device compute time for the wave
+    compute_s: float        # host wall time of execute up to the emit loop
     latency_s: float        # oldest-window end-to-end latency
     occupancy: int          # real (non-padding) windows in the wave
     batch: int              # static wave size the datapath saw
     deadline_flush: bool    # True when the deadline forced a partial wave
+    wave: int = -1          # the wave's id (``Wave.id``)
+    t_built: float = math.nan   # perf_counter when the wave was assembled
+    t_start: float = math.nan   # perf_counter when its execute started
+    # Wall seconds of each of STAGES that ran, and the compute thread's CPU
+    # seconds in each but "ready".  The server fills both as the stages
+    # end, "emit" after the record is taken (the results are delivered
+    # after it), so a record counts in summary()["stages"] once "emit" is
+    # in stage_s.
+    stage_s: Dict[str, float] = dataclasses.field(
+        default_factory=dict, compare=False)
+    stage_cpu_s: Dict[str, float] = dataclasses.field(
+        default_factory=dict, compare=False)
+    # float32, per window: t_built - its submit time (the pending wait).
+    pending_s: Optional[np.ndarray] = dataclasses.field(
+        default=None, compare=False)
 
 
 class MetricsSink:
@@ -134,7 +176,9 @@ class MetricsSink:
         :class:`WaveRecord` rows ordered by completion time and truncated
         to ``window`` (default: the largest input window), so the merged
         p50/p95/p99 describe *current* cluster-wide wave latency exactly
-        as a single server's sink would.  ``merge([])`` is the empty sink;
+        as a single server's sink would; the records keep their stage
+        times, so the merged ``stages`` block covers the same waves.
+        ``merge([])`` is the empty sink;
         empty inputs contribute nothing."""
         sinks = list(sinks)
         if window is None:
@@ -194,4 +238,42 @@ class MetricsSink:
             "batch": recent[-1].batch,
             "deadline_flushes": n_flushes,
             "padded_slots": int(n_padded),
+            "stages": _stage_summary(recent),
         }
+
+
+def _pcts(values: np.ndarray, qs) -> Dict[str, float]:
+    return {f"p{q}": float(v * 1e3)
+            for q, v in zip(qs, np.percentile(values, qs))}
+
+
+def _stage_summary(records: List[WaveRecord]) -> Dict:
+    """The ``stages`` block of :meth:`MetricsSink.summary` over the waves
+    of ``records`` that carry stage times (see the module docstring):
+    ``stage_ms``, the mean wall ms of each stage; per-window percentiles
+    of the pending wait (submit to built), the queue wait (built to
+    execute start) and submit to done, in ms; and ``host_wall_s`` /
+    ``host_cpu_s``, the wall and CPU seconds of every stage but
+    ``ready``, summed."""
+    done = [r for r in records if "emit" in r.stage_s]
+    if not done:
+        return {"waves": 0, "windows": 0}
+    pending = np.concatenate([r.pending_s for r in done]).astype(np.float64)
+    occ = [len(r.pending_s) for r in done]
+    queue = np.repeat([r.t_start - r.t_built for r in done], occ)
+    to_done = pending + np.repeat([r.t_done - r.t_built for r in done], occ)
+    host = [name for name in STAGES if name != "ready"]
+    return {
+        "waves": len(done),
+        "windows": int(len(pending)),
+        "stage_ms": {name: 1e3 * float(np.mean([r.stage_s.get(name, 0.0)
+                                                 for r in done]))
+                     for name in STAGES},
+        "pending_wait_ms": _pcts(pending, (50, 99)),
+        "queue_wait_ms": _pcts(queue, (50, 99)),
+        "submit_to_done_ms": _pcts(to_done, (50,)),
+        "host_wall_s": float(sum(r.stage_s.get(name, 0.0) for r in done
+                                 for name in host)),
+        "host_cpu_s": float(sum(sum(r.stage_cpu_s.values())
+                                for r in done)),
+    }

@@ -37,6 +37,13 @@ exceeds ``shed_after_s`` (their deadline is hopeless; computing them
 would only delay windows that can still make theirs) through the
 ``on_shed`` callback instead of computing them.  Both are accounted:
 ``stats()`` feeds the serving health snapshot.
+
+Every wave gets an id when it is built.  While a profiler trace records,
+the threads mark their per-wave steps as spans tagged ``wave=<id>``: the
+assembler ``serve.assemble`` (stacking and padding the windows its select
+chose) and ``serve.put`` (handing it to the queue, blocked while the
+queue is full); the compute thread ``serve.wait`` (waiting on the queue
+for the wave) and ``serve.execute`` (the ``execute`` hook).
 """
 
 from __future__ import annotations
@@ -50,6 +57,7 @@ from typing import (Callable, Deque, Dict, Hashable, List, Optional,
                     Tuple)
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 _SENTINEL = object()
 
@@ -109,6 +117,7 @@ class Slot:
     sub_idx: int      # global submission index — strictly increasing across
                       # the scheduler's lifetime, orders windows ACROSS
                       # streams (end_stream tombstones compare against it)
+    t_submit: float = float("nan")   # perf_counter when it was submitted
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,6 +128,8 @@ class Wave:
     slots: Tuple[Slot, ...]                   # one per real row
     t_oldest: float                           # submit time of oldest window
     deadline_flush: bool                      # partial wave forced by deadline
+    id: int = -1                              # per-scheduler build counter
+    t_built: float = float("nan")             # perf_counter when assembled
 
     @property
     def occupancy(self) -> int:
@@ -176,6 +187,7 @@ class WaveScheduler:
         self._waveq: "queue.Queue" = queue.Queue(maxsize=queue_depth)
         self._submitted = 0
         self._completed = 0
+        self._built = 0             # waves built (the assembler's counter)
         self._draining = 0          # active flush() calls
         self._closing = False       # drain everything, then stop
         self._stop = False          # stop ASAP, abandon pending work
@@ -371,7 +383,8 @@ class WaveScheduler:
             if shed:
                 for p in shed:
                     if self._on_shed is not None:
-                        self._on_shed(Slot(p.stream_id, p.seq, p.sub_idx))
+                        self._on_shed(Slot(p.stream_id, p.seq, p.sub_idx,
+                                           p.t_submit))
                 with self._cond:
                     # A shed window is accounted as completed (flush must
                     # not wait forever for work that was dropped) only
@@ -408,10 +421,12 @@ class WaveScheduler:
                     continue
                 self._pending = rest
                 self._cond.notify_all()   # wake submitters (backpressure)
-            wave = self._build_wave(chosen, deadline_flush=not full
-                                    and deadline_hit and not force)
-            if not self._put_wave(wave):
-                break
+            with TraceAnnotation("serve.assemble", wave=self._built):
+                wave = self._build_wave(chosen, deadline_flush=not full
+                                        and deadline_hit and not force)
+            with TraceAnnotation("serve.put", wave=wave.id):
+                if not self._put_wave(wave):
+                    break
         self._put_wave(_SENTINEL)
 
     def _shed_expired(self) -> List[_Pending]:
@@ -439,11 +454,14 @@ class WaveScheduler:
         # real window; padded rows are computed and DROPPED — they are
         # never emitted as results and never touch the state store.
         rows.extend([rows[-1]] * (self.batch - len(rows)))
-        return Wave(x=np.stack(rows, axis=0),
-                    slots=tuple(Slot(p.stream_id, p.seq, p.sub_idx)
-                                for p in chosen),
+        wave = Wave(x=np.stack(rows, axis=0),
+                    slots=tuple(Slot(p.stream_id, p.seq, p.sub_idx,
+                                     p.t_submit) for p in chosen),
                     t_oldest=min(p.t_submit for p in chosen),
-                    deadline_flush=deadline_flush)
+                    deadline_flush=deadline_flush, id=self._built,
+                    t_built=time.perf_counter())
+        self._built += 1
+        return wave
 
     def _put_wave(self, item) -> bool:
         # On abandon (_stop) give up rather than block: the compute loop
@@ -458,21 +476,30 @@ class WaveScheduler:
 
     # -- compute thread -----------------------------------------------------
 
-    def _compute_loop(self):
+    def _next_wave(self):
+        """The next item of the wave queue (a wave or the sentinel); None
+        once abandoned."""
         while True:
             try:
-                item = self._waveq.get(timeout=0.1)
+                return self._waveq.get(timeout=0.1)
             except queue.Empty:
                 if self._stop:
-                    return
-                continue
-            if item is _SENTINEL:
+                    return None
+
+    def _compute_loop(self):
+        while True:
+            with TraceAnnotation("serve.wait") as span:
+                item = self._next_wave()
+                if isinstance(item, Wave):
+                    span.set_metadata(wave=item.id)
+            if item is None or item is _SENTINEL:
                 return
             if not self._stop:
                 # Waves keep executing even while _error is set: one
                 # failed wave must not condemn every later one unseen.
                 try:
-                    self._execute(item)
+                    with TraceAnnotation("serve.execute", wave=item.id):
+                        self._execute(item)
                     with self._cond:
                         if self._error is not None:
                             # A later wave completed cleanly: the failure

@@ -59,6 +59,7 @@ from typing import (Dict, Hashable, Iterable, Iterator, List, Optional,
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.serving.faults import FaultInjector
 from repro.serving.metrics import MetricsSink, WaveRecord
@@ -178,7 +179,11 @@ class StreamResult:
     stream's windows may carry DIFFERENT indices, the wave-level
     round-robin documented in the module docstring.  Through
     ``ClusterServer`` it is the replica NAME, and the routing invariant
-    guarantees one stream always reports one replica."""
+    guarantees one stream always reports one replica.
+
+    ``wave`` is the id of the wave that computed the window (None for
+    shed windows): the ``wave=`` tag of that wave's ``serve.*`` spans on
+    a profiler trace and ``WaveRecord.wave`` in the metrics sink."""
 
     stream_id: Hashable
     seq: int
@@ -187,11 +192,42 @@ class StreamResult:
     state_reset: bool = False
     backend: Optional[str] = None
     routed_replica: Optional[Hashable] = None
+    wave: Optional[int] = None
 
     @property
     def ok(self) -> bool:
         """True for a real prediction, False for a shed/failed window."""
         return self.error is None
+
+
+class _StageClock:
+    """Times the execute stages of one wave (``metrics.STAGES``), one at a
+    time (``with clock("h2d"): ...``): the wall seconds of each into
+    ``wall`` and the thread's CPU seconds of each but ``ready`` (the wait
+    for the device) into ``cpu``.  Each stage is also a ``serve.<stage>``
+    span tagged with the wave's id."""
+
+    def __init__(self, wave_id: int):
+        self.wave = wave_id
+        self.wall: Dict[str, float] = {}
+        self.cpu: Dict[str, float] = {}
+
+    def __call__(self, stage: str) -> "_StageClock":
+        self._stage = stage
+        self._span = TraceAnnotation("serve." + stage, wave=self.wave)
+        return self
+
+    def __enter__(self) -> None:
+        self._c0 = time.thread_time() if self._stage != "ready" else 0.0
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter()
+        self._span.__exit__(*exc)
+        if self._stage != "ready":
+            self.cpu[self._stage] = time.thread_time() - self._c0
+        self.wall[self._stage] = t1 - self._t0   # last: marks it complete
 
 
 class StreamServer:
@@ -649,29 +685,48 @@ class StreamServer:
         The guard absorbs engine failures (retry, backoff, degradation
         down the bit-identical ladder); only a wave that fails on EVERY
         engine is converted into per-stream error results — the compute
-        thread survives either way."""
+        thread survives either way.
+
+        The work runs as the stages of ``metrics.STAGES``, timed into the
+        wave's :class:`WaveRecord` (``slots`` and ``commit`` only on a
+        stateful server); ``compute_s`` spans every stage before
+        ``emit``."""
         sess_idx = self._rr % len(self._fns)
         fns = self._fns[sess_idx]
         self._rr += 1
+        clock = _StageClock(wave.id)
         t0 = time.perf_counter()
-        x = jnp.asarray(wave.x)
         device_state = self.state_residency == "device"
-        if device_state:
-            # Slot path: the carries never leave the table — only two (B,)
-            # int32 slot-id vectors cross to the device.  The allocator
-            # transaction (lookup + assign + tombstone checks) happens
-            # BEFORE compute, so faults can only strand slots, never
-            # corrupt the allocator<->table correspondence.
-            g, s, reset, rows, evicted = self._gather_slots(wave)
-            self.metrics.count("slot_id_bytes", int(g.nbytes + s.nbytes))
-            outcome = self.guard.run(fns, x, self.states.table,
-                                     jnp.asarray(g), jnp.asarray(s))
-        elif self.config.stateful:
-            gathered, reset = self._gather(wave)
-            outcome = self.guard.run(fns, x, gathered)
+        if self.config.stateful:
+            with clock("slots"):
+                if device_state:
+                    # Slot path: the carries never leave the table — only
+                    # two (B,) int32 slot-id vectors cross to the device.
+                    # The allocator transaction (lookup + assign +
+                    # tombstone checks) happens BEFORE compute, so faults
+                    # can only strand slots, never corrupt the
+                    # allocator<->table correspondence.
+                    g, s, reset, rows, evicted = self._gather_slots(wave)
+                    self.metrics.count("slot_id_bytes",
+                                       int(g.nbytes + s.nbytes))
+                else:
+                    gathered, reset = self._gather(wave)
         else:
             reset = [False] * len(wave.slots)
-            outcome = self.guard.run(fns, x)
+        with clock("h2d"):
+            x = jnp.asarray(wave.x)
+            if device_state:
+                slot_ids = (jnp.asarray(g), jnp.asarray(s))
+        with clock("call"):
+            if device_state:
+                # The table is read here, not held in a local: the old
+                # table is freed when commit replaces it.
+                outcome = self.guard.run(fns, x, self.states.table,
+                                         *slot_ids)
+            elif self.config.stateful:
+                outcome = self.guard.run(fns, x, gathered)
+            else:
+                outcome = self.guard.run(fns, x)
         if not outcome.ok:
             self._fail_wave(wave, outcome, t0, sess_idx)
             if device_state:
@@ -680,33 +735,41 @@ class StreamServer:
                 # wave's table update was discarded.
                 self._reconcile_evictions(evicted)
             return
-        if device_state:
-            y, new_table = outcome.value
+        with clock("ready"):
+            y, new_state = (outcome.value if self.config.stateful
+                            else (outcome.value, None))
             y = np.asarray(y)
-            self.states.commit(new_table, rows)
-            self._retire(wave)
-            self._reconcile_evictions(evicted)
-        elif self.config.stateful:
-            y, new_state = outcome.value
-            y = np.asarray(y)
-            evicted = self._scatter(wave, new_state)
-            self._retire(wave)
-            self._reconcile_evictions(evicted)
-        else:
-            y = np.asarray(outcome.value)
-        n_reset = sum(reset)
-        if n_reset:
-            self.metrics.count("state_resets", n_reset)
+        if self.config.stateful:
+            with clock("commit"):
+                if device_state:
+                    self.states.commit(new_state, rows)
+                else:
+                    evicted = self._scatter(wave, new_state)
+                self._retire(wave)
+                self._reconcile_evictions(evicted)
+                n_reset = sum(reset)
+                if n_reset:
+                    self.metrics.count("state_resets", n_reset)
         t1 = time.perf_counter()
-        self.metrics.record_wave(WaveRecord(
-            t_done=t1, compute_s=t1 - t0, latency_s=t1 - wave.t_oldest,
-            occupancy=wave.occupancy, batch=self.config.batch,
-            deadline_flush=wave.deadline_flush))
-        for i, slot in enumerate(wave.slots):
-            self._emit(StreamResult(slot.stream_id, slot.seq, y[i],
-                                    state_reset=reset[i],
-                                    backend=outcome.backend,
-                                    routed_replica=sess_idx))
+        with clock("emit"):
+            t_submit = np.fromiter((sl.t_submit for sl in wave.slots),
+                                   np.float64, len(wave.slots))
+            # The record shares the clock's dicts, so the emit stage's
+            # times land in it when this block ends, after the results are
+            # out, without taking the sink's lock again.
+            self.metrics.record_wave(WaveRecord(
+                t_done=t1, compute_s=t1 - t0, latency_s=t1 - wave.t_oldest,
+                occupancy=wave.occupancy, batch=self.config.batch,
+                deadline_flush=wave.deadline_flush, wave=wave.id,
+                t_built=wave.t_built, t_start=t0, stage_s=clock.wall,
+                stage_cpu_s=clock.cpu,
+                pending_s=(wave.t_built - t_submit).astype(np.float32)))
+            for i, slot in enumerate(wave.slots):
+                self._emit(StreamResult(slot.stream_id, slot.seq, y[i],
+                                        state_reset=reset[i],
+                                        backend=outcome.backend,
+                                        routed_replica=sess_idx,
+                                        wave=wave.id))
 
     def _fail_wave(self, wave: Wave, outcome, t0: float,
                    sess_idx: int) -> None:
@@ -726,10 +789,12 @@ class StreamServer:
         self.metrics.record_wave(WaveRecord(
             t_done=t1, compute_s=t1 - t0, latency_s=t1 - wave.t_oldest,
             occupancy=wave.occupancy, batch=self.config.batch,
-            deadline_flush=wave.deadline_flush))
+            deadline_flush=wave.deadline_flush, wave=wave.id,
+            t_built=wave.t_built, t_start=t0))
         for slot in wave.slots:
             self._emit(StreamResult(slot.stream_id, slot.seq, None,
-                                    error=err, routed_replica=sess_idx))
+                                    error=err, routed_replica=sess_idx,
+                                    wave=wave.id))
 
     def _shed(self, slot: Slot) -> None:
         """Scheduler shed callback (assembler thread): the window was
