@@ -8,7 +8,8 @@ layer, H=20, T=6, (4,8) codes).
 
 Checks results, not speed: every row must be bit-exact with the ``ref``
 engine run over the stream's concatenated windows, on the preferred
-engine, with no retry, wave failure or degradation.  Exits non-zero when
+engine, with no retry, wave failure or degradation, and every wave must
+write the state table in place.  Exits non-zero when
 no TPU is found.  The last line of a passing run is one JSON object:
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 """
@@ -148,6 +149,16 @@ def check_faults(faults, what: str) -> None:
     print(f"[{what}] retries=0 wave_failures=0 degradations=0", flush=True)
 
 
+def check_in_place(summaries, what: str) -> None:
+    """Every wave wrote its rows into the state table's own buffer."""
+    n = {k: sum(s["state_transfer"][k] for s in summaries)
+         for k in ("table_in_place", "table_copied", "table_losses")}
+    if n["table_copied"] or n["table_losses"] or not n["table_in_place"]:
+        fail(f"{what}: the state table was not updated in place: {n}")
+    print(f"[{what}] table_in_place={n['table_in_place']} table_copied=0",
+          flush=True)
+
+
 def one_chip(acc, data) -> None:
     from repro.serving import StreamServer
 
@@ -166,6 +177,7 @@ def one_chip(acc, data) -> None:
         summary = server.metrics_summary()
     check_rows(rows, oracle, "server")
     check_faults(summary["faults"], "server")
+    check_in_place([summary], "server")
 
 
 def four_chips(acc, data) -> None:
@@ -195,6 +207,7 @@ def four_chips(acc, data) -> None:
         summary = cluster.metrics_summary()
     check_rows(rows, oracle, "cluster")
     check_faults(summary["faults"], "cluster")
+    check_in_place(summary["replicas"].values(), "cluster")
 
 
 def main() -> None:

@@ -287,7 +287,11 @@ class Accelerator:
         The fused pallas engine gathers/scatters inside the kernel;
         ``ref``/``xla`` run the XLA-level adapter, so every rung of the
         degradation ladder accepts the same arguments.  Bit-identical to
-        ``compiled_stateful`` fed the host-gathered carries."""
+        ``compiled_stateful`` fed the host-gathered carries.
+
+        The call DONATES ``table``: the new table is the old one's buffer
+        with the scattered rows written in place, and the array passed in
+        is deleted once the call is dispatched."""
         self._require_quantized()
         bk = backends.select_stateful(self.model, self.accel,
                                       override=backend)
@@ -306,7 +310,7 @@ class Accelerator:
                                     gather_slots, scatter_slots)
             return fxp.dequantize(y_int, model.fxp), new_table
 
-        fn = jax.jit(slot_path)
+        fn = jax.jit(slot_path, donate_argnums=1)
         self._jitted[key] = fn
         return fn
 

@@ -205,17 +205,27 @@ def table_chunks(hidden: int) -> int:
 
 
 def state_table_shape(n_rows: int, num_layers: int, arity: int,
-                      hidden: int) -> Tuple[int, int, int]:
-    """Shape of the device state table: ``(K, n_rows, 128)`` int32 with
+                      hidden: int) -> Tuple[int, int]:
+    """Shape of the device state table: ``(K * n_rows, 128)`` int32 with
     ``K = num_layers * arity * table_chunks(hidden)``.
 
     Component-major: chunk ``j`` of carry component ``s`` of layer ``li``
-    is the ``(n_rows, 128)`` slab ``k = (li * arity + s) * chunks + j``,
-    one stream per sublane row, its codes in the low lanes and zeros in
-    the pad lanes.  One stream's whole carry is the strided column
-    ``table[:, slot, :]`` — a single DMA — and the slabs tile HBM with no
-    sublane padding."""
-    return (num_layers * arity * table_chunks(hidden), n_rows, TABLE_LANES)
+    is the ``n_rows``-row slab ``k = (li * arity + s) * chunks + j``, one
+    stream per row, its codes in the low lanes and zeros in the pad lanes;
+    stream ``slot``'s carry is rows ``k * n_rows + slot``.  The table is
+    two-dimensional so that the TPU's default layout for it, row-major in
+    (8, 128) tiles, is the layout the slot kernel reads its HBM operand
+    in: a table kept in any other layout is copied into the kernel's and
+    back on every wave."""
+    return (num_layers * arity * table_chunks(hidden) * n_rows, TABLE_LANES)
+
+
+def _row_ids(slots: Array, n_comp: int, n_rows: int) -> Array:
+    """Table rows of ``slots``' carries, component-major: ``(n_comp *
+    B,)``."""
+    slots = jnp.asarray(slots, jnp.int32).reshape(1, -1)
+    comp = jnp.arange(n_comp, dtype=jnp.int32).reshape(-1, 1)
+    return (comp * n_rows + slots).reshape(-1)
 
 
 def _components(rows: Array, hidden: int):
@@ -233,7 +243,10 @@ def gather_carry(table: Array, slots: Array, num_layers: int, arity: int,
     """The per-layer carry of table rows ``slots``: ``num_layers`` tuples
     of ``arity`` (B, hidden) int32 arrays — the inverse of
     :func:`scatter_carry`, in jnp for the XLA engines and the host."""
-    comps = _components(jnp.take(table, slots, axis=1), hidden)
+    n_comp = num_layers * arity * table_chunks(hidden)
+    ids = _row_ids(slots, n_comp, table.shape[0] // n_comp)
+    rows = jnp.take(table, ids, axis=0).reshape(n_comp, -1, TABLE_LANES)
+    comps = _components(rows, hidden)
     return tuple(tuple(comps[li * arity:(li + 1) * arity])
                  for li in range(num_layers))
 
@@ -247,17 +260,20 @@ def scatter_carry(table: Array, slots: Array, state) -> Array:
     pad = nch * TABLE_LANES - hidden
     rows = jnp.stack([jnp.pad(a, ((0, 0), (0, pad)))
                       .reshape(bsz, nch, TABLE_LANES).transpose(1, 0, 2)
-                      for a in comps]).reshape(-1, bsz, TABLE_LANES)
-    return table.at[:, slots, :].set(rows)
+                      for a in comps]).reshape(-1, TABLE_LANES)
+    n_comp = len(comps) * nch
+    ids = _row_ids(slots, n_comp, table.shape[0] // n_comp)
+    return table.at[ids].set(rows.astype(table.dtype))
 
 
 def _make_slot_kernel(cfg: FixedPointConfig, hdim: int, hs_method: str,
                       hs_slope_shift: int, hs_bound: float,
                       ht_min: float, ht_max: float, compute_unit: str,
-                      t_len: int, num_layers: int, bsz: int):
+                      t_len: int, num_layers: int, bsz: int, n_rows: int):
     requant, hs, ht = _cell_math(cfg, hs_method, hs_slope_shift, hs_bound,
                                  ht_min, ht_max)
     nch = table_chunks(hdim)
+    n_comp = num_layers * 2 * nch
 
     def kernel(*refs):
         # Ref layout (L = num_layers): gather_slots, scatter_slots (SMEM,
@@ -277,19 +293,25 @@ def _make_slot_kernel(cfg: FixedPointConfig, hdim: int, hs_method: str,
         t = pl.program_id(0)
         carries = [ref for li in range(n) for ref in (h_s[li], c_s[li])]
 
-        def copy_rows(src, src_row, dst, dst_row):
-            # One strided DMA per batch row moves that stream's whole
-            # carry (every layer, h and c); start all B, then wait all B.
-            def row(ref, r):
-                return ref.at[:, pl.ds(r, 1), :]
+        def copy_rows(tbl, slot, to_table):
+            # One DMA per carry component and batch row moves table row
+            # ``k * n_rows + slot(i)`` to or from the staging row
+            # ``rows[k, i]``; start all of them, then wait for all.
+            def dma(i, k, r):
+                src = tbl.at[pl.ds(k * n_rows + r, 1), :]
+                dst = rows.at[k, pl.ds(i, 1), :]
+                if to_table:
+                    src, dst = dst, src
+                return pltpu.make_async_copy(src, dst, sem)
 
             def start(i, c):
-                pltpu.make_async_copy(row(src, src_row(i)),
-                                      row(dst, dst_row(i)), sem).start()
+                for k in range(n_comp):
+                    dma(i, k, slot(i)).start()
                 return c
 
             def wait(i, c):
-                pltpu.make_async_copy(row(src, 0), row(dst, 0), sem).wait()
+                for k in range(n_comp):
+                    dma(0, k, 0).wait()
                 return c
 
             jax.lax.fori_loop(0, bsz, start, 0)
@@ -300,7 +322,7 @@ def _make_slot_kernel(cfg: FixedPointConfig, hdim: int, hs_method: str,
             # GATHER: row i's carry comes from table row gather_slots[i] —
             # the ZERO row for fresh/reset streams.  Every gather completes
             # before any scatter starts.
-            copy_rows(tbl_in, lambda i: g_ref[i], rows, lambda i: i)
+            copy_rows(tbl_in, lambda i: g_ref[i], to_table=False)
             for ref, comp in zip(carries, _components(rows, hdim)):
                 ref[...] = comp
 
@@ -321,7 +343,7 @@ def _make_slot_kernel(cfg: FixedPointConfig, hdim: int, hs_method: str,
                     w = min(TABLE_LANES, hdim - j * TABLE_LANES)
                     rows[k * nch + j, :, :w] = \
                         ref[:, j * TABLE_LANES:j * TABLE_LANES + w]
-            copy_rows(rows, lambda i: i, tbl_out, lambda i: s_ref[i])
+            copy_rows(tbl_out, lambda i: s_ref[i], to_table=True)
 
     return kernel
 
@@ -493,8 +515,9 @@ def qlstm_seq_slot_pallas(x_int: Array, gather_slots: Array,
     """The fused multi-layer stack with DEVICE-RESIDENT stream state.
 
     x_int: (T, B, M) integer codes; ``table``: the persistent int32 state
-    table, shaped ``state_table_shape(n_slots + 2, L, 2, H)`` — axis 1
-    indexes slots, slot ``n_slots`` is the always-zero RESET slot and
+    table, shaped ``state_table_shape(n_slots + 2, L, 2, H)`` — slot
+    ``s`` owns row ``s`` of each component's slab, slot ``n_slots`` is
+    the always-zero RESET slot and
     slot ``n_slots + 1`` the write-only TRASH slot; ``gather_slots``/
     ``scatter_slots``: (B,) int32 slot ids, one per batch row.  Weight
     tuples as in :func:`qlstm_seq_multilayer_pallas`.
@@ -523,7 +546,8 @@ def qlstm_seq_slot_pallas(x_int: Array, gather_slots: Array,
             f"w_xs={len(w_xs)}, w_hs={n}, b_wides={len(b_wides)}")
     t_len, bsz, m = x_int.shape
     hdim = w_hs[0].shape[0]
-    n_rows = table.shape[1] if table.ndim == 3 else 0
+    n_comp = n * 2 * table_chunks(hdim)
+    n_rows = table.shape[0] // n_comp if table.ndim == 2 else 0
     if n_rows < 3 or table.shape != state_table_shape(n_rows, n, 2, hdim):
         raise ValueError(
             f"state table must be state_table_shape(n_slots + 2, {n}, 2, "
@@ -532,7 +556,7 @@ def qlstm_seq_slot_pallas(x_int: Array, gather_slots: Array,
 
     kernel = _make_slot_kernel(cfg, hdim, hs_method, hs_slope_shift,
                                hs_bound, ht_min, ht_max, compute_unit,
-                               t_len, n, bsz)
+                               t_len, n, bsz, n_rows)
     res2 = lambda t, g, s: (0, 0)                       # resident across t
     per_t = lambda t, g, s: (t, 0, 0)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -540,7 +564,7 @@ def qlstm_seq_slot_pallas(x_int: Array, gather_slots: Array,
     in_specs += [pl.BlockSpec(w.shape, res2) for w in w_xs]
     in_specs += [pl.BlockSpec(w.shape, res2) for w in w_hs]
     in_specs += [pl.BlockSpec((1, 4 * hdim), res2)] * n
-    scratch = [pltpu.VMEM((table.shape[0], bsz, TABLE_LANES), jnp.int32)]
+    scratch = [pltpu.VMEM((n_comp, bsz, TABLE_LANES), jnp.int32)]
     scratch += [pltpu.VMEM((bsz, hdim), jnp.int32)] * (2 * n)
     scratch += [pltpu.SemaphoreType.DMA(())]
     outs = pl.pallas_call(

@@ -41,6 +41,14 @@ movement: :meth:`DeviceStateStore.read_state` /
 :meth:`DeviceStateStore.seed_state`, used by
 ``ClusterServer.remove_replica`` to hand a draining replica's streams to
 their new ring homes warm (docs/SERVING.md §Scaling out).
+
+The table is updated IN PLACE: every program that writes it — the wave
+programs and the planned writes here — donates it, so a write lands in
+the table's own buffer and the array handed in is deleted.  A wave
+therefore borrows the table (:meth:`DeviceStateStore.take`) and gives a
+table back (:meth:`DeviceStateStore.commit` on success,
+:meth:`DeviceStateStore.restore` on failure); meanwhile the planned
+surfaces wait, so none of them reads a donated array.
 """
 
 from __future__ import annotations
@@ -49,6 +57,7 @@ import threading
 from collections import OrderedDict
 from typing import Dict, Hashable, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -168,11 +177,16 @@ class DeviceStateStore:
         self.capacity = capacity
         self._alloc = SlotAllocator(capacity)
         self._shape = session.plan["state_shape"]      # (L, S, H)
+        self._fresh = lambda: session.init_state_table(capacity)
         #: The persistent int32 carry table (``capacity + 2`` slots).  The
         #: serving hot path replaces this reference wholesale after each
         #: wave (:meth:`commit`) — the array itself never visits the host.
-        self.table = session.init_state_table(capacity)
+        self.table = self._fresh()
+        self._write = jax.jit(scatter_carry, donate_argnums=0)
         self._lock = threading.Lock()
+        # Set while a wave holds the table (take .. commit / restore).
+        self._returned = threading.Condition(self._lock)
+        self._in_flight = False
 
     @property
     def zero_slot(self) -> int:
@@ -196,6 +210,14 @@ class DeviceStateStore:
         with self._lock:
             return self._alloc.assign(stream_id)
 
+    def take(self):
+        """Lend the table to a wave program, which donates it: until
+        :meth:`commit` or :meth:`restore` gives a table back, the planned
+        surfaces wait."""
+        with self._lock:
+            self._in_flight = True
+            return self.table
+
     def commit(self, new_table, rows: List[Tuple[int, Hashable]]) -> None:
         """Adopt the kernel's updated table after a successful wave.
         ``rows`` lists the wave's real scatters as ``(batch_row,
@@ -203,7 +225,37 @@ class DeviceStateStore:
         its per-put schedule from them (``faults.FaultyDeviceStateStore``),
         keeping the injected schedule identical to the host store's."""
         with self._lock:
-            self.table = new_table
+            self._give_back(new_table)
+
+    def restore(self, table) -> bool:
+        """Give back the table a failed wave borrowed.  A table that is
+        still alive (the failure came before the program was dispatched)
+        is kept as it was.  A donated one is gone with every carry in it:
+        a zero table takes its place and every live slot is released, so
+        each stream's next window starts from the zero carry, flagged
+        ``state_reset``.  Returns True when the table was lost."""
+        lost = table.is_deleted()
+        if lost:
+            table = self._fresh()
+        with self._lock:
+            if lost:
+                for sid in list(self._alloc.live()):
+                    self._alloc.release(sid)
+            self._give_back(table)
+        return lost
+
+    def _give_back(self, table) -> None:
+        """Install ``table`` and wake the planned surfaces.  Caller holds
+        the lock."""
+        self.table = table
+        self._in_flight = False
+        self._returned.notify_all()
+
+    def _table_at_rest(self):
+        """The table, once no wave holds it.  Caller holds the lock."""
+        while self._in_flight:
+            self._returned.wait()
+        return self.table
 
     def pop(self, stream_id: Hashable) -> Optional[int]:
         """Release a stream's slot (end-of-stream, failed wave, shed,
@@ -226,24 +278,28 @@ class DeviceStateStore:
         host/device state transfer, used only on planned stream movement
         (``ClusterServer.remove_replica``).  Returns per layer a tuple of
         the cell's ``state_arity`` int32 rows (``[(h, c), ...]`` for the
-        LSTM), or ``None`` for an unknown stream."""
+        LSTM), or ``None`` for an unknown stream.  Waits while a wave
+        holds the table."""
         with self._lock:
             slot = self._alloc.slot_of(stream_id)
-            table = self.table
-        if slot is None:
-            return None
-        state = gather_carry(table, jnp.asarray([slot]), *self._shape)
-        return [tuple(np.asarray(a)[0] for a in layer) for layer in state]
+            if slot is None:
+                return None
+            state = gather_carry(self._table_at_rest(),
+                                 jnp.asarray([slot]), *self._shape)
+            return [tuple(np.asarray(a)[0] for a in layer)
+                    for layer in state]
 
     def seed_state(self, stream_id: Hashable,
                    state: StreamState) -> List[Hashable]:
         """Plant a host-side carry into the table (the destination half of
-        a warm handoff): assigns a slot and writes the row.  Returns any
-        ids the assignment evicted."""
+        a warm handoff): assigns a slot and writes the row in place.
+        Returns any ids the assignment evicted.  Waits while a wave holds
+        the table."""
         with self._lock:
+            table = self._table_at_rest()
             slot, evicted = self._alloc.assign(stream_id)
-            self.table = scatter_carry(
-                self.table, jnp.asarray([slot]),
+            self.table = self._write(
+                table, jnp.asarray([slot]),
                 [tuple(np.asarray(a)[None] for a in layer)
                  for layer in state])
         return evicted
@@ -254,15 +310,17 @@ class DeviceStateStore:
         """XOR the low bit of every code in the stream's table row — the
         device form of the host store's put-corruption (same perturbation
         as ``FaultInjector._mutate_put``).  Returns False for an unknown
-        stream (nothing to corrupt)."""
+        stream (nothing to corrupt).  Waits while a wave holds the
+        table."""
         with self._lock:
             slot = self._alloc.slot_of(stream_id)
             if slot is None:
                 return False
+            table = self._table_at_rest()
             slots = jnp.asarray([slot])
-            state = gather_carry(self.table, slots, *self._shape)
-            self.table = scatter_carry(
-                self.table, slots,
+            state = gather_carry(table, slots, *self._shape)
+            self.table = self._write(
+                table, slots,
                 [tuple(jnp.bitwise_xor(a, 1) for a in layer)
                  for layer in state])
             return True

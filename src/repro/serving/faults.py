@@ -292,8 +292,9 @@ class FaultyDeviceStateStore:
                 self._store.corrupt_slot(sid)
 
     def __getattr__(self, name):
-        # lookup/assign/pop/read_state/seed_state/corrupt_slot/stats/
-        # table/capacity/zero_slot/trash_slot delegate verbatim.
+        # lookup/assign/take/restore/pop/read_state/seed_state/
+        # corrupt_slot/stats/table/capacity/zero_slot/trash_slot delegate
+        # verbatim.
         return getattr(self._store, name)
 
     def __len__(self) -> int:

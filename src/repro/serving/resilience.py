@@ -158,15 +158,20 @@ class ExecutionGuard:
 
     # -- execution -----------------------------------------------------------
 
-    def run(self, fns: Sequence[Tuple[str, Callable]], *args) -> GuardOutcome:
+    def run(self, fns: Sequence[Tuple[str, Callable]], *args,
+            alive: Optional[Callable[[], bool]] = None) -> GuardOutcome:
         """Execute one wave through the ladder.
 
         ``fns``: ordered ``(name, callable)`` pairs matching the ladder
         this guard was built with (the caller may pass a prefix-compatible
         ladder, e.g. per-session callables; names are matched by the
         guard's current level name, falling back to positional order).
-        ``*args`` are passed to the chosen callable.  Never raises for an
-        attempt failure — inspect the returned :class:`GuardOutcome`."""
+        ``*args`` are passed to the chosen callable.  ``alive``, when
+        given, is asked before every attempt whether ``args`` can still be
+        used (a failed attempt may have consumed a donated argument); once
+        it says no, no further attempt is made and the wave fails.  Never
+        raises for an attempt failure — inspect the returned
+        :class:`GuardOutcome`."""
         by_name = dict(fns)
         order = [n for n, _ in fns]
         with self._lock:
@@ -185,7 +190,7 @@ class ExecutionGuard:
         for idx in range(start, len(order)):
             name = order[idx]
             ok, value, att_r, att_t, errs = self._attempt_level(
-                by_name[name], name, args)
+                by_name[name], name, args, alive)
             retries += att_r
             timeouts += att_t
             errors.extend(errs)
@@ -197,12 +202,17 @@ class ExecutionGuard:
                 preferred_failed = True
         return self._note_total_failure(level, retries, timeouts, errors)
 
-    def _attempt_level(self, fn: Callable, name: str, args):
-        """Up to ``1 + max_retries`` attempts of ``fn`` with backoff;
-        returns (ok, value, retries, timeouts, error strings)."""
+    def _attempt_level(self, fn: Callable, name: str, args, alive=None):
+        """Up to ``1 + max_retries`` attempts of ``fn`` with backoff, while
+        ``alive`` allows; returns (ok, value, retries, timeouts, error
+        strings)."""
         retries = timeouts = 0
         errors: List[str] = []
         for attempt in range(1 + self.policy.max_retries):
+            if alive is not None and not alive():
+                errors.append(f"{name}: the wave's arguments were consumed "
+                              f"by a failed attempt")
+                break
             if attempt > 0:
                 retries += 1
                 time.sleep(self.policy.backoff_s(attempt))

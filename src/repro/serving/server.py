@@ -627,6 +627,11 @@ class StreamServer:
             "to_device_bytes": counters.get("state_bytes_to_device", 0),
             "from_device_bytes": counters.get("state_bytes_from_device", 0),
             "slot_id_bytes": counters.get("slot_id_bytes", 0),
+            # Device residency: waves whose new table is the old one's
+            # buffer, waves whose is not, and failed waves that lost it.
+            "table_in_place": counters.get("table_in_place", 0),
+            "table_copied": counters.get("table_copied", 0),
+            "table_losses": counters.get("table_losses", 0),
         }
         s["health"] = self.health()
         if s["waves"]:
@@ -719,15 +724,21 @@ class StreamServer:
                 slot_ids = (jnp.asarray(g), jnp.asarray(s))
         with clock("call"):
             if device_state:
-                # The table is read here, not held in a local: the old
-                # table is freed when commit replaces it.
-                outcome = self.guard.run(fns, x, self.states.table,
-                                         *slot_ids)
+                # The wave program donates the table: the store lends it
+                # here and gets a table back at commit, or at restore when
+                # the wave fails.  No attempt runs on a donated table.
+                table = self.states.take()
+                before = table.unsafe_buffer_pointer()
+                outcome = self.guard.run(
+                    fns, x, table, *slot_ids,
+                    alive=lambda: not table.is_deleted())
             elif self.config.stateful:
                 outcome = self.guard.run(fns, x, gathered)
             else:
                 outcome = self.guard.run(fns, x)
         if not outcome.ok:
+            if device_state:
+                self._return_table(table)
             self._fail_wave(wave, outcome, t0, sess_idx)
             if device_state:
                 # Slot assignment (and any LRU evictions) happened before
@@ -738,11 +749,18 @@ class StreamServer:
         with clock("ready"):
             y, new_state = (outcome.value if self.config.stateful
                             else (outcome.value, None))
-            y = np.asarray(y)
+            try:
+                y = np.asarray(y)
+            except BaseException:
+                # A device error surfaces here, after the dispatch: the
+                # donated table went with the failed program.
+                if device_state:
+                    self._return_table(table)
+                raise
         if self.config.stateful:
             with clock("commit"):
                 if device_state:
-                    self.states.commit(new_state, rows)
+                    self._commit_table(table, before, new_state, rows)
                 else:
                     evicted = self._scatter(wave, new_state)
                 self._retire(wave)
@@ -770,6 +788,26 @@ class StreamServer:
                                         backend=outcome.backend,
                                         routed_replica=sess_idx,
                                         wave=wave.id))
+
+    def _commit_table(self, table, before: int, new_table,
+                      rows: List[Tuple[int, Hashable]]) -> None:
+        """Adopt a wave's new state table, counting whether it is the old
+        table's buffer (``before`` is that buffer's address).  A program
+        that handed back a deleted array, its own donated input, left no
+        table: that is a loss, as in a failed wave."""
+        if new_table.is_deleted():
+            self._return_table(table)
+            return
+        self.metrics.count("table_in_place"
+                           if new_table.unsafe_buffer_pointer() == before
+                           else "table_copied")
+        self.states.commit(new_table, rows)
+
+    def _return_table(self, table) -> None:
+        """Give the store back the table a wave borrowed and did not
+        replace; count a loss when the wave had donated it."""
+        if self.states.restore(table):
+            self.metrics.count("table_losses")
 
     def _fail_wave(self, wave: Wave, outcome, t0: float,
                    sess_idx: int) -> None:

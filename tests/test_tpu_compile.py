@@ -114,3 +114,37 @@ def test_slot_kernel_compiles_for_v5e_wide_deep(one_chip, no_compile_cache):
         spec((6, BATCH, 4), cfg.storage_dtype), slots, slots, table, w_xs,
         w_hs, bs, cfg=cfg, interpret=False).compile()
     _assert_kernel(compiled)
+
+
+PEMS_SENSORS = 2_160_000        # the benchmark's population (PERF.md)
+
+
+@pytest.mark.parametrize("engine", ["pallas", "xla", "ref"])
+def test_wave_program_updates_the_table_in_place(one_chip, no_compile_cache,
+                                                 monkeypatch, engine):
+    """The wave program as ``compiled_stateful_slots`` builds it, for a
+    2.2-GB table: the table is aliased to the new table, and no op copies
+    it.  A table that is not donated, or that XLA keeps in another layout
+    than the kernel reads, costs a whole-table copy per wave each way."""
+    import re
+
+    import repro
+    from repro.backends import pallas
+
+    monkeypatch.setattr(pallas, "_interpret", lambda: False)
+    sess = repro.build(PEMS).quantize()
+    shape = state_table_shape(PEMS_SENSORS + 2, *sess.plan["state_shape"])
+    table = jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((BATCH, PEMS.seq_len, PEMS.input_size),
+                             jnp.float32)
+    slots = jax.ShapeDtypeStruct((BATCH,), jnp.int32)
+    compiled = sess.compiled_stateful_slots(engine).lower(
+        x, table, slots, slots).compile()
+    table_bytes = 4 * shape[0] * shape[1]
+    assert compiled.memory_analysis().alias_size_in_bytes >= table_bytes
+    dims = ",".join(map(str, shape))
+    copies = re.findall(rf"s32\[{dims}\]\{{[^}}]*\}} copy(?:-start)?\(",
+                        compiled.as_text())
+    assert not copies
+    if engine == "pallas":
+        _assert_kernel(compiled)
