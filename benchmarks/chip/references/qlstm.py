@@ -25,6 +25,9 @@ test, which quantises them itself; the reference quantises them here.
 :func:`control_codes` is the control of the comparison: the same weights
 rounded to 4-bit codes (two fewer fractional bits, eight levels each way),
 the int4 step down from the configured int8 codes.
+
+The module keeps the contract of ``references/__init__.py``: a stream's
+carry is ``(h, c)`` of every layer, ``2 * num_layers * hidden_size`` codes.
 """
 
 from __future__ import annotations
@@ -34,6 +37,8 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from work import storage_bytes
 
 
 def _fmt(cfg) -> Tuple[int, int]:
@@ -202,21 +207,57 @@ def output_codes(cfg, y: np.ndarray) -> np.ndarray:
     return np.rint(np.asarray(y, np.float64) * (1 << frac)).astype(np.int64)
 
 
+def ops_per_window(cfg) -> int:
+    """The gate multiply-accumulates, 2 per MAC, of every timestep and
+    layer, ``2 * (in + H) * 4H`` per step, plus the dense head's
+    ``2 * H * P``."""
+    m = cfg["model"]
+    h, n_in = m["hidden_size"], m["input_size"]
+    per_step = sum(2 * ((n_in if li == 0 else h) + h) * 4 * h
+                   for li in range(m["num_layers"]))
+    return per_step * m["seq_len"] + 2 * h * m["out_features"]
+
+
+def weight_bytes(cfg) -> int:
+    """Gate weights (in + H, 4H) and the head's (H, P) at the code width,
+    biases (4H,) and (P,) at the product format's."""
+    m = cfg["model"]
+    h, n_in, p = m["hidden_size"], m["input_size"], m["out_features"]
+    w = storage_bytes(cfg["number_format"]["total_bits"])
+    b = storage_bytes(2 * cfg["number_format"]["total_bits"])
+    total = 0
+    for li in range(m["num_layers"]):
+        total += ((n_in if li == 0 else h) + h) * 4 * h * w + 4 * h * b
+    return total + h * p * w + p * b
+
+
+def carry_codes(cfg) -> int:
+    """h and c of every layer, H codes each."""
+    m = cfg["model"]
+    return m["num_layers"] * 2 * m["hidden_size"]
+
+
+def carry_vector(cfg, state) -> np.ndarray:
+    """``[(h, c), ...]`` per layer -> ``h0, c0, h1, c1, ...`` as one row."""
+    return np.concatenate([np.asarray(a, np.int64).ravel()
+                           for layer in state for a in layer])
+
+
 def run_chains(cfg, codes, x_of, ids, n_windows, block: int, keep=()):
     """Reference outputs of stateful streams: stream ``ids[r]`` runs its
     windows 0..n_windows[r]-1 in order from the zero carry.
     ``x_of(streams, k)`` gives window k of each listed stream as float32
     (len, T, M).  Returns the codes (len(ids), max_windows, P), windows
     past a stream's count left at 0, and the carries after the last
-    window of the streams at positions ``keep``, (len(keep), L, 2, H)."""
+    window of the streams at positions ``keep``, (len(keep),
+    carry_codes) in :func:`carry_vector`'s order."""
     fn = window_fn(cfg)
     m = cfg["model"]
     ids, n_windows = np.asarray(ids), np.asarray(n_windows)
     keep = np.asarray(keep, np.int64)
     out = np.zeros((len(ids), int(n_windows.max(initial=0)),
                     m["out_features"]), np.int64)
-    final = np.zeros((len(keep), m["num_layers"], 2, m["hidden_size"]),
-                     np.int64)
+    final = np.zeros((len(keep), carry_codes(cfg)), np.int64)
     for b0 in range(0, len(ids), block):
         rows = np.arange(b0, min(b0 + block, len(ids)))
         pad = block - len(rows)
@@ -232,7 +273,7 @@ def run_chains(cfg, codes, x_of, ids, n_windows, block: int, keep=()):
             last = kept[n_windows[keep[kept]] == k + 1]
             if len(last):
                 local = keep[last] - b0
-                final[last] = np.stack(
-                    [np.stack([np.asarray(h)[local], np.asarray(c)[local]], 1)
-                     for h, c in carry], 1)
+                final[last] = np.concatenate(
+                    [np.asarray(a)[local] for layer in carry for a in layer],
+                    1)
     return out, final

@@ -9,7 +9,11 @@ on one chip.  The run builds the weights from the seed, serves the mix
 open loop through ``StreamServer``, warms up on the same traffic, measures
 for ``--seconds`` seconds, then checks every answered window, and the
 carries the server holds for a sample of the streams, against the plain
-reference (``references/<reference>.py``).  With ``--trace 0`` it reports the
+reference (``references/<reference>.py``, which alone knows the
+architecture).  A stateful cell chains each stream's windows through the
+reference and compares the carries read back; a stateless one
+(``serving.stateful`` false) runs each window alone from the zero carry
+and holds that the server keeps no carry.  With ``--trace 0`` it reports the
 cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics from a
 profiler trace of the first seconds of the window.  Each metric is a
 reader ``metrics/<name>.py``.
@@ -66,7 +70,8 @@ def load_json(path: str) -> Dict:
 
 
 def cell_spec(workload: str):
-    """(benchmark, cell, configuration, traffic mix) of ``workload``."""
+    """(benchmark, cell, configuration, traffic mix, reference module) of
+    ``workload``; a reference that breaks its contract fails here."""
     bench = load_json(os.path.join(ROOT, "BENCHMARK.json"))
     cells = {c["name"]: c for c in bench["workloads"]}
     if workload not in cells:
@@ -76,7 +81,8 @@ def cell_spec(workload: str):
     entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = load_json(os.path.join(ROOT, entry["file"]))
     mix = load_json(os.path.join(HERE, "traffic", f"{cell['traffic']}.json"))
-    return bench, cell, cfg, mix
+    import references
+    return bench, cell, cfg, mix, references.load(cfg["reference"])
 
 
 def metric_specs(bench: Dict, cell: str, kind: str) -> List[Dict]:
@@ -160,7 +166,8 @@ class Record:
         self.setup_s = math.nan
         self.sink: Dict = {}
         self.carry_pos = np.zeros(0, np.int64)   # schedule positions read back
-        self.carry_read = None                   # their carries, (K, L, 2, H)
+        self.carry_read = None                   # their carries, (K, codes)
+        self.carry_held = np.zeros(0, bool)      # whether the server had one
         self.serving: Dict = {}
         self.trace: Optional[Dict] = None
         self.work: Dict = {}
@@ -309,10 +316,17 @@ def _sleep_until(t: float) -> None:
 def expected(cfg: Dict, ref, codes, rec: Record):
     """The reference's output codes of every submitted window, in schedule
     order, and its carries of the streams read back (``rec.carry_pos``).
-    Stream ``order[r]`` sends windows ``r, r + streams, ...``, each
-    continuing the carry of the one before, from the zero carry."""
+    Stream ``order[r]`` sends windows ``r, r + streams, ...``; on a
+    stateful server each continues the carry of the one before, from the
+    zero carry, on a stateless one each starts from the zero carry."""
     tr, n = rec.traffic, rec.n_sub
     n_str = tr.streams
+    if not cfg["serving"]["stateful"]:
+        out, carries = ref.run_chains(
+            cfg, codes, lambda i, k: tr.pool[tr.rows(tr.order[i % n_str],
+                                                     i // n_str)],
+            np.arange(n), np.ones(n, np.int64), block=16384)
+        return out[:, 0], carries
     touched = min(n, n_str)
     per = n // n_str + (np.arange(touched) < n % n_str)
     out, carries = ref.run_chains(
@@ -322,19 +336,18 @@ def expected(cfg: Dict, ref, codes, rec: Record):
     return out[i % n_str, i // n_str], carries
 
 
-def compare(rec: Record, served, carries, expect, expect_carries) -> Dict:
+def compare(rec: Record, served, expect, carry: Dict) -> Dict:
     """The numbers compared with their limits: ``served`` output codes of
-    every submitted window and ``carries`` of the streams read back,
-    against the reference's."""
+    every submitted window against the reference's ``expect``, the
+    ``carry`` check (:func:`carry_gap` or :func:`carries_held`), and the
+    run's own counts."""
     n = rec.n_sub
     ok = rec.status[:n] == 1
     gap = int(np.abs(served[ok] - expect[ok]).max()) if ok.any() else None
-    cgap = (int(np.abs(carries - expect_carries).max())
-            if len(expect_carries) else None)
     return {
         "answered_ok": {"value": int(ok.sum()), "limit": 1, "op": ">="},
         "max_gap_lsb": {"value": gap, "limit": 0, "op": "<="},
-        "carry_gap_lsb": {"value": cgap, "limit": 0, "op": "<="},
+        **carry,
         "unanswered": {"value": int((rec.status[:n] == 0).sum()),
                        "limit": 0, "op": "<="},
         "state_resets": {"value": int(rec.reset[:n].sum()), "limit": 0,
@@ -342,20 +355,41 @@ def compare(rec: Record, served, carries, expect, expect_carries) -> Dict:
     }
 
 
+def carry_gap(carries, expect_carries) -> Dict:
+    """A stateful cell's carry check: the widest gap between the carries
+    read back and the reference's, None when none was read."""
+    value = (int(np.abs(carries - expect_carries).max())
+             if len(expect_carries) else None)
+    return {"carry_gap_lsb": {"value": value, "limit": 0, "op": "<="}}
+
+
+def carries_held(held) -> Dict:
+    """A stateless cell's carry check: the sampled streams the server still
+    holds a carry for, none."""
+    return {"carries_held": {"value": int(np.sum(held)), "limit": 0,
+                             "op": "<="}}
+
+
 def check(cfg: Dict, ref, params, rec: Record, control: bool = False):
     """Compare what the run served with the reference.
 
     Returns the checks of the program and, with ``control``, those of the
     control: the reference at 4-bit weights put in the program's place
-    and held to the same checks, else None."""
+    (holding no carry on a stateless cell) and held to the same checks,
+    else None."""
+    stateful = cfg["serving"]["stateful"]
     expect, expect_carries = expected(cfg, ref, ref.weight_codes(cfg, params),
                                       rec)
     served = ref.output_codes(cfg, rec.y[:rec.n_sub])
-    checks = compare(rec, served, rec.carry_read, expect, expect_carries)
+    checks = compare(rec, served, expect,
+                     carry_gap(rec.carry_read, expect_carries) if stateful
+                     else carries_held(rec.carry_held))
     if not control:
         return checks, None
     ctl, ctl_carries = expected(cfg, ref, ref.control_codes(cfg, params), rec)
-    return checks, compare(rec, ctl, ctl_carries, expect, expect_carries)
+    return checks, compare(rec, ctl, expect,
+                           carry_gap(ctl_carries, expect_carries) if stateful
+                           else carries_held(()))
 
 
 def holds(c: Dict) -> bool:
@@ -369,12 +403,14 @@ def correct(rec: Record, checks: Dict) -> bool:
     return bool(not rec.errors and all(holds(c) for c in checks.values()))
 
 
-def read_carries(server, rec: Record, seed: int, m: Dict) -> None:
+def read_carries(server, rec: Record, seed: int, cfg: Dict, ref) -> None:
     """Read back the carries the server holds for a sample of the streams,
     drawn from the seed among those whose last window was answered, into
-    ``rec.carry_pos`` (schedule positions) and ``rec.carry_read``; ``m`` is
-    the configuration's ``model``.  A carry the server no longer holds
-    reads as ``MISSING``."""
+    ``rec.carry_pos`` (schedule positions), ``rec.carry_held`` and
+    ``rec.carry_read``: one row of ``ref.carry_codes(cfg)`` codes a stream,
+    as ``ref.carry_vector`` lays it out.  A carry the server does not hold
+    reads as a row of ``MISSING``; one of another length is an error of
+    the run."""
     tr, n = rec.traffic, rec.n_sub
     touched = np.arange(min(n, tr.streams))
     last = touched + tr.streams * ((n - 1 - touched) // tr.streams)
@@ -382,15 +418,21 @@ def read_carries(server, rec: Record, seed: int, m: Dict) -> None:
     rng = np.random.default_rng(np.random.SeedSequence([seed % 2 ** 64, 17]))
     rec.carry_pos = np.sort(rng.choice(
         eligible, min(CARRY_SAMPLE, len(eligible)), replace=False))
-    rows = []
-    for r in rec.carry_pos:
+    codes = ref.carry_codes(cfg)
+    rec.carry_read = np.full((len(rec.carry_pos), codes), MISSING, np.int64)
+    rec.carry_held = np.zeros(len(rec.carry_pos), bool)
+    for j, r in enumerate(rec.carry_pos):
         st = server.read_stream_state(int(tr.order[r]))
-        rows.append(np.full((m["num_layers"], 2, m["hidden_size"]), MISSING)
-                    if st is None else
-                    np.stack([np.stack([np.asarray(h), np.asarray(c)])
-                              for h, c in st]))
-    rec.carry_read = np.asarray(rows, np.int64).reshape(
-        len(rows), m["num_layers"], 2, m["hidden_size"])
+        if st is None:
+            continue
+        rec.carry_held[j] = True
+        row = ref.carry_vector(cfg, st)
+        if row.shape != (codes,):
+            rec.errors.append(f"carry of stream {int(tr.order[r])}: "
+                              f"{row.shape} codes, the reference holds "
+                              f"{codes}")
+            continue
+        rec.carry_read[j] = row
 
 
 def serve_cell(workload: str, seed: int, seconds: float, trace: bool,
@@ -415,7 +457,7 @@ def serve_cell(workload: str, seed: int, seconds: float, trace: bool,
     import work
 
     phases = {"imports": time.perf_counter() - T_START}
-    bench, cell, cfg, mix = cell_spec(workload)
+    bench, cell, cfg, mix, ref = cell_spec(workload)
     chips = int(cell["chips"])
     devices = jax.devices() if rehearse else check_devices(chips)
     phases["devices"] = time.perf_counter() - T_START
@@ -424,7 +466,6 @@ def serve_cell(workload: str, seed: int, seconds: float, trace: bool,
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     counter = CompileCounter()
 
-    ref = importlib.import_module(f"references.{cfg['reference']}")
     params = ref.make_params(cfg, jax.random.key(
         int(np.random.SeedSequence(seed % 2 ** 64).generate_state(1)[0])))
     traffic = loadgen.make_traffic({**mix, **(mix_overrides or {})}, cfg,
@@ -450,7 +491,7 @@ def serve_cell(workload: str, seed: int, seconds: float, trace: bool,
         rec.phases = phases
         peaks_mem = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                      for d in devices[:chips]]
-        read_carries(server, rec, seed, cfg["model"])
+        read_carries(server, rec, seed, cfg, ref)
     finally:
         server.close(abandon=True, timeout=30)
     if counter.count:
@@ -463,9 +504,9 @@ def serve_cell(workload: str, seed: int, seconds: float, trace: bool,
                    "deadline_s": server.config.deadline_s,
                    "max_streams": server.config.max_streams,
                    "stateful": cfg["serving"]["stateful"]}
-    rec.work = {"ops_per_window": work.ops_per_window(cfg),
-                "bytes_per_wave": work.bytes_per_wave(cfg, batch or 0),
-                "ops_per_wave": work.ops_per_window(cfg) * (batch or 0)}
+    rec.work = {"ops_per_window": ref.ops_per_window(cfg),
+                "bytes_per_wave": work.bytes_per_wave(ref, cfg, batch or 0),
+                "ops_per_wave": ref.ops_per_window(cfg) * (batch or 0)}
     if dev0.platform == "tpu":         # a CPU rehearsal has no peaks
         rec.work["peaks"] = work.peaks(dev0.device_kind)
     if trace:

@@ -1,15 +1,21 @@
 """Self-check of the yardstick: the operation and byte counts, the peak
-table, the plain reference against a scalar loop, and the trace reduction
-on a small recorded trace and on a hand-made one."""
+table, the reference contract, the plain reference against a scalar loop,
+and the trace reduction on a small recorded trace and on a hand-made
+one."""
 
 import json
 import os
+import sys
+import types
 
 import numpy as np
 import pytest
 
+import references
+import run as harness
 import trace_reduce
 import work
+from references import qlstm
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -29,8 +35,8 @@ def config(name):
 ])
 def test_ops_per_window(model, ops):
     cfg = config("lstm_pems")
-    assert work.ops_per_window({**cfg, "model": {**cfg["model"],
-                                                 **model}}) == ops
+    assert qlstm.ops_per_window({**cfg, "model": {**cfg["model"],
+                                                  **model}}) == ops
 
 
 def test_bytes_per_wave_pems():
@@ -39,7 +45,29 @@ def test_bytes_per_wave_pems():
     w = (1 + 20) * 80 + 80 * 2 + 20 * 1 + 1 * 2   # int8 weights, int16 biases
     carry = 2 * 256 * 1 * 2 * 20              # h and c, read and written
     y = 256 * 1 * 4
-    assert work.bytes_per_wave(cfg, 256) == x + w + carry + y
+    assert qlstm.weight_bytes(cfg) == w == 1_862
+    assert work.bytes_per_wave(qlstm, cfg, 256) == x + w + carry + y == 29_510
+
+
+def test_reference_without_carry_codes_fails_at_cell_spec(tmp_path,
+                                                          monkeypatch):
+    broken = types.ModuleType("references.nocarry")
+    for name in references.REQUIRED:
+        if name != "carry_codes":
+            setattr(broken, name, getattr(qlstm, name))
+    monkeypatch.setitem(sys.modules, "references.nocarry", broken)
+    cfg = {**config("lstm_pems"), "name": "nocarry", "reference": "nocarry"}
+    (tmp_path / "nocarry.json").write_text(json.dumps(cfg))
+    (tmp_path / "traffic").mkdir()
+    (tmp_path / "traffic" / "mix.json").write_text("{}")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps({
+        "configs": [{"name": "nocarry", "file": "nocarry.json"}],
+        "workloads": [{"name": "cell", "config": "nocarry",
+                       "traffic": "mix", "chips": 1}]}))
+    monkeypatch.setattr(harness, "ROOT", str(tmp_path))
+    monkeypatch.setattr(harness, "HERE", str(tmp_path))
+    with pytest.raises(AttributeError, match="carry_codes"):
+        harness.cell_spec("cell")
 
 
 def test_peaks_table():
