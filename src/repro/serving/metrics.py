@@ -3,10 +3,11 @@
 Every computed wave appends one :class:`WaveRecord`; :meth:`MetricsSink.
 summary` reduces them to the numbers the paper reports for its real-time
 deployment (§6): achieved samples/s, per-wave latency percentiles
-(p50/p95/p99), wave occupancy, and how often the deadline forced a partial
-flush.  ``StreamServer.metrics_summary`` extends this with the energy
-model's GOP/s/W *at the measured throughput* (the paper's 11.89 GOP/s/W
-headline is exactly this quantity at 32 873 samples/s).
+(p50/p95/p99), wave occupancy, and how often a partial wave was cut: by
+the deadline, or at once for an idle compute thread.
+``StreamServer.metrics_summary`` extends this with the energy model's
+GOP/s/W *at the measured throughput* (the paper's 11.89 GOP/s/W headline
+is exactly this quantity at 32 873 samples/s).
 
 Latency definitions (the metrics glossary in docs/SERVING.md):
 
@@ -53,9 +54,9 @@ STAGES = ("slots", "h2d", "call", "ready", "commit", "emit")
 class WaveRecord:
     """One computed wave, as recorded by the scheduler's compute thread.
 
-    The fields after ``deadline_flush`` are the stage times (module
-    docstring); a record without them (a failed wave, or one built by
-    hand) keeps their defaults and stays out of ``summary()["stages"]``."""
+    The fields after ``idle_cut`` are the wave's id and stage times
+    (module docstring); a record without them (a failed wave, or one built
+    by hand) keeps their defaults and stays out of ``summary()["stages"]``."""
 
     t_done: float           # perf_counter when results were ready
     compute_s: float        # host wall time of execute up to the emit loop
@@ -63,6 +64,8 @@ class WaveRecord:
     occupancy: int          # real (non-padding) windows in the wave
     batch: int              # static wave size the datapath saw
     deadline_flush: bool    # True when the deadline forced a partial wave
+    idle_cut: bool = False  # True when cut part-full for an idle compute
+                            # thread (``Wave.idle_cut``)
     wave: int = -1          # the wave's id (``Wave.id``)
     t_built: float = math.nan   # perf_counter when the wave was assembled
     t_start: float = math.nan   # perf_counter when its execute started
@@ -90,8 +93,8 @@ class MetricsSink:
     only the most recent ``window`` records are retained for the
     percentile/mean reductions (latency p50/p95/p99 then read as *current*
     behaviour, not lifetime history), while counts — waves, samples,
-    deadline flushes, padded slots — and the samples/s wall interval are
-    lifetime totals kept as O(1) counters."""
+    deadline flushes, idle cuts, padded slots — and the samples/s wall
+    interval are lifetime totals kept as O(1) counters."""
 
     def __init__(self, window: int = 4096):
         """Create an empty sink retaining the last ``window`` wave records;
@@ -103,6 +106,7 @@ class MetricsSink:
         self._n_waves = 0
         self._n_samples = 0
         self._n_deadline_flushes = 0
+        self._n_idle_cuts = 0
         self._n_padded_slots = 0
         self._compute_s_total = 0.0
         self._counters: Dict[str, int] = collections.defaultdict(int)
@@ -135,6 +139,7 @@ class MetricsSink:
             self._n_waves += 1
             self._n_samples += record.occupancy
             self._n_deadline_flushes += bool(record.deadline_flush)
+            self._n_idle_cuts += bool(record.idle_cut)
             self._n_padded_slots += record.batch - record.occupancy
             self._compute_s_total += record.compute_s
 
@@ -157,6 +162,7 @@ class MetricsSink:
                 "n_waves": self._n_waves,
                 "n_samples": self._n_samples,
                 "n_deadline_flushes": self._n_deadline_flushes,
+                "n_idle_cuts": self._n_idle_cuts,
                 "n_padded_slots": self._n_padded_slots,
                 "compute_s_total": self._compute_s_total,
                 "counters": dict(self._counters),
@@ -167,10 +173,10 @@ class MetricsSink:
               window: Optional[int] = None) -> "MetricsSink":
         """Cluster aggregation: one sink summarising many replicas' sinks.
 
-        Lifetime counters (waves, samples, deadline flushes, padded slots,
-        named event counters) are SUMMED; the wall interval spans the
-        earliest first-submit to the latest last-done across all replicas,
-        so the merged ``samples_per_s`` is the cluster's aggregate
+        Lifetime counters (waves, samples, deadline flushes, idle cuts,
+        padded slots, named event counters) are SUMMED; the wall interval
+        spans the earliest first-submit to the latest last-done across all
+        replicas, so the merged ``samples_per_s`` is the cluster's aggregate
         throughput over the common measurement window.  The rolling
         percentile window is the union of the replicas' retained
         :class:`WaveRecord` rows ordered by completion time and truncated
@@ -199,6 +205,7 @@ class MetricsSink:
         out._n_samples = sum(sn["n_samples"] for sn in snaps)
         out._n_deadline_flushes = sum(sn["n_deadline_flushes"]
                                       for sn in snaps)
+        out._n_idle_cuts = sum(sn["n_idle_cuts"] for sn in snaps)
         out._n_padded_slots = sum(sn["n_padded_slots"] for sn in snaps)
         out._compute_s_total = sum(sn["compute_s_total"] for sn in snaps)
         for sn in snaps:
@@ -217,6 +224,7 @@ class MetricsSink:
             n_waves = self._n_waves
             n_samples = self._n_samples
             n_flushes = self._n_deadline_flushes
+            n_idle_cuts = self._n_idle_cuts
             n_padded = self._n_padded_slots
             compute_total = self._compute_s_total
         if not recent:
@@ -237,6 +245,7 @@ class MetricsSink:
             "mean_occupancy": n_samples / n_waves,
             "batch": recent[-1].batch,
             "deadline_flushes": n_flushes,
+            "idle_cuts": n_idle_cuts,
             "padded_slots": int(n_padded),
             "stages": _stage_summary(recent),
         }

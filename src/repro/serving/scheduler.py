@@ -16,11 +16,14 @@ submitted-but-unassembled windows (``submit`` blocks), ``queue_depth``
 bounds assembled-but-uncomputed waves (default 2 — classic double
 buffering).
 
-Tail latency is bounded by the DEADLINE: a wave normally waits until
-``batch`` windows are available (maximum device efficiency), but once the
-oldest pending window has waited ``deadline_s`` the scheduler flushes a
-partial wave — padded to the static shape, padding dropped — instead of
-stalling a slow stream behind a full-wave quorum.  ``deadline_s=None``
+A wave is cut when ``batch`` windows are available (maximum device
+efficiency).  With a deadline set, a partial wave — padded to the static
+shape, padding dropped — is cut earlier in two cases: at once when the
+compute thread is waiting for work and no wave is queued for it (an IDLE
+cut: waiting longer would only leave the device idle, so the wave holds
+what arrived during the previous execute), and, while the compute thread
+is busy, once the oldest pending window has waited ``deadline_s`` (the
+DEADLINE, the upper limit of the pending wait).  ``deadline_s=None``
 waits for full waves (the strict ``Accelerator.serve`` semantics; the
 final partial wave still flushes on drain/close).  Independent of the
 deadline, a SATURATION flush fires when pending hits ``max_pending`` and
@@ -130,6 +133,8 @@ class Wave:
     deadline_flush: bool                      # partial wave forced by deadline
     id: int = -1                              # per-scheduler build counter
     t_built: float = float("nan")             # perf_counter when assembled
+    idle_cut: bool = False                    # partial wave cut for an idle
+                                              # compute thread
 
     @property
     def occupancy(self) -> int:
@@ -189,6 +194,10 @@ class WaveScheduler:
         self._completed = 0
         self._built = 0             # waves built (the assembler's counter)
         self._draining = 0          # active flush() calls
+        # The idle cut's inputs: the compute thread is blocked waiting for
+        # a wave, and how many waves were cut that it has not taken yet.
+        self._compute_waiting = False
+        self._untaken = 0
         self._closing = False       # drain everything, then stop
         self._stop = False          # stop ASAP, abandon pending work
         self._error: Optional[BaseException] = None
@@ -247,7 +256,16 @@ class WaveScheduler:
             self._pending.append(_Pending(stream_id, seq, self._submitted,
                                           window, time.perf_counter()))
             self._submitted += 1
-            self._cond.notify_all()
+            # Wake the assembler only where this window can change its
+            # decision: it sets the deadline of a pending list that was
+            # empty, it may complete a wave or saturate pending, or the
+            # compute thread waits for it.  Any other window waits for the
+            # oldest one's deadline, which the assembler already sleeps
+            # toward.
+            n = len(self._pending)
+            if (n == 1 or n >= self.batch or n >= self.max_pending
+                    or self._idle_locked()):
+                self._cond.notify_all()
             return seq
 
     def submission_watermark(self) -> int:
@@ -360,17 +378,25 @@ class WaveScheduler:
         if self._closing:
             raise RuntimeError("scheduler is closed")
 
+    def _idle_locked(self) -> bool:
+        """Whether an idle cut is due, with a deadline set: the compute
+        thread waits with no wave cut for it, so holding pending windows
+        back would only idle the device.  Caller holds ``_cond``."""
+        return (self.deadline_s is not None and self._compute_waiting
+                and not self._untaken)
+
     # -- assembler thread ---------------------------------------------------
 
     def _select(self):
         """Pick up to ``batch`` pending windows, oldest first, at most one
         per stream when the carry demands it.  Returns (chosen, rest)."""
+        if not self._one_per_stream:
+            return self._pending[:self.batch], self._pending[self.batch:]
         chosen: List[_Pending] = []
         rest: List[_Pending] = []
         seen = set()
         for p in self._pending:
-            if len(chosen) < self.batch and \
-                    (not self._one_per_stream or p.stream_id not in seen):
+            if len(chosen) < self.batch and p.stream_id not in seen:
                 chosen.append(p)
                 seen.add(p.stream_id)
             else:
@@ -409,8 +435,9 @@ class WaveScheduler:
                 # max_pending < batch), waiting for quorum would deadlock —
                 # ship what is eligible and free pending slots.
                 saturated = len(self._pending) >= self.max_pending
+                idle = self._idle_locked()
                 if not chosen or not (full or force or deadline_hit
-                                      or saturated):
+                                      or saturated or idle):
                     if self._closing and not self._pending:
                         break
                     wait = None
@@ -420,10 +447,14 @@ class WaveScheduler:
                     self._cond.wait(timeout=wait if wait is not None else 0.5)
                     continue
                 self._pending = rest
+                self._untaken += 1
                 self._cond.notify_all()   # wake submitters (backpressure)
+            partial = not (full or force)
             with TraceAnnotation("serve.assemble", wave=self._built):
-                wave = self._build_wave(chosen, deadline_flush=not full
-                                        and deadline_hit and not force)
+                wave = self._build_wave(
+                    chosen, deadline_flush=partial and deadline_hit,
+                    idle_cut=partial and idle and not deadline_hit
+                    and not saturated)
             with TraceAnnotation("serve.put", wave=wave.id):
                 if not self._put_wave(wave):
                     break
@@ -447,19 +478,22 @@ class WaveScheduler:
                 self._cond.notify_all()   # wake blocked submitters
             return shed
 
-    def _build_wave(self, chosen: List[_Pending],
-                    deadline_flush: bool) -> Wave:
-        rows = [p.window for p in chosen]
+    def _build_wave(self, chosen: List[_Pending], deadline_flush: bool,
+                    idle_cut: bool) -> Wave:
+        n = len(chosen)
+        first = chosen[0].window
+        x = np.empty((self.batch,) + first.shape, first.dtype)
+        np.stack([p.window for p in chosen], out=x[:n])
         # Pad the partial wave to the static shape by repeating the last
         # real window; padded rows are computed and DROPPED — they are
         # never emitted as results and never touch the state store.
-        rows.extend([rows[-1]] * (self.batch - len(rows)))
-        wave = Wave(x=np.stack(rows, axis=0),
+        x[n:] = x[n - 1]
+        wave = Wave(x=x,
                     slots=tuple(Slot(p.stream_id, p.seq, p.sub_idx,
                                      p.t_submit) for p in chosen),
                     t_oldest=min(p.t_submit for p in chosen),
                     deadline_flush=deadline_flush, id=self._built,
-                    t_built=time.perf_counter())
+                    t_built=time.perf_counter(), idle_cut=idle_cut)
         self._built += 1
         return wave
 
@@ -488,10 +522,18 @@ class WaveScheduler:
 
     def _compute_loop(self):
         while True:
+            with self._cond:
+                self._compute_waiting = True
+                if self._idle_locked():
+                    self._cond.notify_all()   # an idle cut is due
             with TraceAnnotation("serve.wait") as span:
                 item = self._next_wave()
                 if isinstance(item, Wave):
                     span.set_metadata(wave=item.id)
+            with self._cond:
+                self._compute_waiting = False
+                if isinstance(item, Wave):
+                    self._untaken -= 1
             if item is None or item is _SENTINEL:
                 return
             if not self._stop:

@@ -85,9 +85,12 @@ class ServingConfig:
     guide).
 
     ``batch``: static wave size the jitted datapath sees.  ``deadline_s``:
-    flush a padded partial wave once the oldest pending window has waited
-    this long (None = wait for full waves).  ``queue_depth``: assembled
-    waves the compute thread may fall behind by (2 = double buffering).
+    the upper limit of a window's pending wait — a padded partial wave is
+    cut at once when the compute thread waits for work with no wave queued
+    for it, and otherwise once the oldest pending window has waited this
+    long (None = wait for full waves; no early cut).  ``queue_depth``:
+    assembled waves the compute thread may fall behind by (2 = double
+    buffering).
     ``max_pending``: submitted-but-unassembled window bound — ``submit``
     blocks past it (None = 4 * batch); when pending saturates and no full
     wave can form (one window per stream), a partial wave is flushed
@@ -778,7 +781,8 @@ class StreamServer:
             self.metrics.record_wave(WaveRecord(
                 t_done=t1, compute_s=t1 - t0, latency_s=t1 - wave.t_oldest,
                 occupancy=wave.occupancy, batch=self.config.batch,
-                deadline_flush=wave.deadline_flush, wave=wave.id,
+                deadline_flush=wave.deadline_flush,
+                idle_cut=wave.idle_cut, wave=wave.id,
                 t_built=wave.t_built, t_start=t0, stage_s=clock.wall,
                 stage_cpu_s=clock.cpu,
                 pending_s=(wave.t_built - t_submit).astype(np.float32)))
@@ -827,7 +831,8 @@ class StreamServer:
         self.metrics.record_wave(WaveRecord(
             t_done=t1, compute_s=t1 - t0, latency_s=t1 - wave.t_oldest,
             occupancy=wave.occupancy, batch=self.config.batch,
-            deadline_flush=wave.deadline_flush, wave=wave.id,
+            deadline_flush=wave.deadline_flush,
+            idle_cut=wave.idle_cut, wave=wave.id,
             t_built=wave.t_built, t_start=t0))
         for slot in wave.slots:
             self._emit(StreamResult(slot.stream_id, slot.seq, None,

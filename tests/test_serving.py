@@ -186,8 +186,10 @@ def test_end_stream_with_window_in_flight(sess):
 
 def test_deadline_flushes_partial_wave(sess):
     """A slow stream is not stuck behind a full-wave quorum: with 3 windows
-    pending against batch=8, the deadline flushes a padded partial wave and
-    exactly 3 predictions come back (padding dropped)."""
+    against batch=8, a padded partial wave is cut (at once for the idle
+    compute thread, else by the deadline) and exactly 3 predictions come
+    back (padding dropped).  The deadline cut itself, with the compute
+    thread busy, is pinned in test_idle_cut.py."""
     x = _windows(3, seed=4)
     srv = StreamServer(sess, ServingConfig(batch=8, stateful=False,
                                            deadline_s=0.05))
@@ -200,7 +202,7 @@ def test_deadline_flushes_partial_wave(sess):
             results.extend(srv.poll(timeout=1.0))
         assert len(results) == 3
         m = srv.metrics_summary()
-        assert m["deadline_flushes"] >= 1
+        assert m["deadline_flushes"] + m["idle_cuts"] >= 1
         assert m["samples"] == 3 and m["padded_slots"] >= 5
         want = np.asarray(sess.infer(jnp.asarray(x), path="int"))
         got = np.stack([r.y for r in sorted(results, key=lambda r: r.seq)])
