@@ -41,13 +41,11 @@ def _smoke_space(serving: bool):
     from repro import explore
     if not serving:
         return explore.smoke_space(cell=("lstm", "gru", "rglru"))
-    # The serving smoke walks the cell zoo AND the new serving axes: a
+    # The serving smoke walks the cell zoo AND the serving axis: a
     # 2-replica point (feasible under forced-host device counts >= 2,
-    # pruned as infeasible on a single-device runner) and pinned host
-    # residency alongside the auto default.
+    # pruned as infeasible on a single-device runner).
     return explore.smoke_space(cell=("lstm", "gru", "rglru"),
-                               replicas=(1, 2),
-                               state_residency=("auto",))
+                               replicas=(1, 2))
 
 
 def sweep_payload(smoke: bool = False, iters: int = 20, seed: int = 0,

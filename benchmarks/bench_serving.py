@@ -2,7 +2,7 @@
 headline (32 873 samples/s at 11.89 GOP/s/W on the XC7S15).
 
   PYTHONPATH=src python -m benchmarks.bench_serving [--smoke]
-      [--stateful-backend ref,xla,pallas] [--state-residency host,device]
+      [--stateful-backend ref,xla,pallas]
       [--fault-rate F] [--chaos] [--replicas 1,2,4] [out.json]
 
 Three scenario families through `repro.serving`:
@@ -28,16 +28,9 @@ Three scenario families through `repro.serving`:
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` set before jax
     initialises (how CI runs the ``--replicas 1,2`` smoke).
 
-``--state-residency`` (comma list of ``auto`` | ``host`` | ``device``,
-default ``auto``) runs each stateful scenario once per requested carry
-placement: ``host`` ships every wave's (h, c) batch across the
-host/device boundary (the legacy ``StateStore``), ``device`` keeps the
-carries in the accelerator-resident slot table and ships only (B,)
-slot-id vectors (``ServingConfig.state_residency``; docs/SERVING.md
-§State residency).  Each scenario's summary carries the resolved
-``state_residency`` and the ``state_transfer`` byte counters — on the
-device point ``to_device_bytes == from_device_bytes == 0`` is the
-artifact's proof that the per-wave state traffic is gone.
+Every stateful scenario keeps its carries in the accelerator-resident
+slot table and ships only (B,) slot-id vectors per wave (docs/SERVING.md
+§The carry table); its summary's ``state_transfer`` block counts them.
 
 Chaos axes (the PR-6 reliability layer, ``repro.serving.faults``):
 ``--fault-rate F`` runs the stateful scenarios under a seeded
@@ -83,10 +76,13 @@ PAPER_GOPS_PER_WATT = 11.89       # Table 4
 # rglru resolve to xla@host — no fused kernel); "lstm" in the list is a
 # no-op because the default scenarios ARE the lstm points, so every
 # pre-v6 key (and its numbers) is byte-identical for a given config.
-SCHEMA_VERSION = 6
+# 7: one carry placement (the device slot table): --state-residency and
+# the "stateful[<backend>@<residency>]" keys are gone, the per-cell keys
+# are "stateful[<cell>@<backend>]", and "state_transfer" no longer carries
+# the to_device/from_device byte counters (they could only read 0).
+SCHEMA_VERSION = 7
 
 STATEFUL_BACKENDS = ("ref", "xla", "pallas")
-STATE_RESIDENCIES = ("auto", "host", "device")
 
 
 def _scenario_stateless(sess, n_windows, batch):
@@ -127,11 +123,9 @@ def _injector(fault_rate, chaos, seed=42):
 
 
 def _scenario_stateful(sess, n_streams, windows_per_stream, batch,
-                       backend=None, fault_rate=0.0, chaos=False,
-                       residency="auto"):
+                       backend=None, fault_rate=0.0, chaos=False):
     """Multiplexed named streams with cross-window carry on ``backend``
-    (None = the plan's ``stateful_backend``); ``residency`` places the
-    carries (``ServingConfig.state_residency``); ``fault_rate``/``chaos``
+    (None = the plan's ``stateful_backend``); ``fault_rate``/``chaos``
     run the scenario under the seeded FaultInjector."""
     import numpy as np
     rng = np.random.default_rng(1)
@@ -141,7 +135,6 @@ def _scenario_stateful(sess, n_streams, windows_per_stream, batch,
     from repro.serving import ResiliencePolicy, ServingConfig, StreamServer
     cfg = ServingConfig(batch=batch, deadline_s=0.05, backend=backend,
                         max_streams=max(16, n_streams),
-                        state_residency=residency,
                         resilience=ResiliencePolicy(
                             max_retries=3, backoff_base_s=0.0005))
     with StreamServer(sess, cfg,
@@ -193,10 +186,9 @@ def _row(name, summary):
 
 def run(smoke: bool = False, out_path: str = "BENCH_serving.json",
         stateful_backends=None, fault_rate: float = 0.0,
-        chaos: bool = False, replicas=None, state_residencies=None,
-        cell_axis=None):
+        chaos: bool = False, replicas=None, cell_axis=None):
     """Measure the stateless scenario plus one stateful scenario per
-    requested engine x state residency (under the seeded chaos axes when
+    requested engine (under the seeded chaos axes when
     requested), one cluster scenario per requested replica count, and one
     plan-default stateful point per requested non-lstm cell; write the
     JSON payload and return the CSV-ish rows the benchmark harness
@@ -216,27 +208,14 @@ def run(smoke: bool = False, out_path: str = "BENCH_serving.json",
         if b not in STATEFUL_BACKENDS:
             raise SystemExit(f"unknown stateful backend {b!r}; "
                              f"choose from {STATEFUL_BACKENDS}")
-    residencies = tuple(state_residencies) if state_residencies else ("auto",)
-    for r in residencies:
-        if r not in STATE_RESIDENCIES:
-            raise SystemExit(f"unknown state residency {r!r}; "
-                             f"choose from {STATE_RESIDENCIES}")
-
-    def _skey(b, r):
-        # The bare pre-v5 key for the default placement, an explicit
-        # "@<residency>" suffix for requested host-vs-device points.
-        return f"stateful[{b}]" if r == "auto" else f"stateful[{b}@{r}]"
-
     scenarios = {}
     if smoke:
         scenarios["stateless"] = _scenario_stateless(sess, n_windows=64,
                                                      batch=16)
         for b in backends:
-            for r in residencies:
-                scenarios[_skey(b, r)] = _scenario_stateful(
-                    sess, n_streams=8, windows_per_stream=4, batch=8,
-                    backend=b, fault_rate=fault_rate, chaos=chaos,
-                    residency=r)
+            scenarios[f"stateful[{b}]"] = _scenario_stateful(
+                sess, n_streams=8, windows_per_stream=4, batch=8,
+                backend=b, fault_rate=fault_rate, chaos=chaos)
         for n in (replicas or ()):
             # Enough streams that every replica still fills waves at the
             # largest requested fan-out — the scaling trend needs the
@@ -250,19 +229,17 @@ def run(smoke: bool = False, out_path: str = "BENCH_serving.json",
         scenarios["stateless"] = _scenario_stateless(sess, n_windows=4096,
                                                      batch=256)
         for b in backends:
-            for r in residencies:
-                scenarios[_skey(b, r)] = _scenario_stateful(
-                    sess, n_streams=128, windows_per_stream=16, batch=64,
-                    backend=b, fault_rate=fault_rate, chaos=chaos,
-                    residency=r)
+            scenarios[f"stateful[{b}]"] = _scenario_stateful(
+                sess, n_streams=128, windows_per_stream=16, batch=64,
+                backend=b, fault_rate=fault_rate, chaos=chaos)
         for n in (replicas or ()):
             scenarios[f"cluster[r{n}]"] = _scenario_cluster(
                 sess, n_replicas=n, n_streams=128, windows_per_stream=16,
                 batch=32)
 
     # The cell axis: one stateful point per non-lstm cell at its OWN plan
-    # defaults (backend/residency resolved by the registry — gru/rglru
-    # have no fused kernel, so they land on xla@host).  "lstm" is skipped:
+    # defaults (backend resolved by the registry — gru/rglru have no fused
+    # kernel, so they land on xla).  "lstm" is skipped:
     # the scenarios above already measure it under the pre-v6 keys, which
     # must stay byte-identical.
     for c in (cell_axis or ()):
@@ -270,8 +247,7 @@ def run(smoke: bool = False, out_path: str = "BENCH_serving.json",
             continue
         sess_c = repro.build(
             dataclasses.replace(sess.model, cell=c)).quantize()
-        key = (f"stateful[{c}@{sess_c.plan['stateful_backend']}"
-               f"@{sess_c.plan['state_residency']}]")
+        key = f"stateful[{c}@{sess_c.plan['stateful_backend']}]"
         if smoke:
             scenarios[key] = _scenario_stateful(
                 sess_c, n_streams=8, windows_per_stream=4, batch=8,
@@ -302,14 +278,13 @@ def run(smoke: bool = False, out_path: str = "BENCH_serving.json",
 
 def main(argv):
     """CLI: ``[--smoke] [--stateful-backend ref,xla,pallas]
-    [--state-residency auto,host,device] [--cell lstm,gru,rglru]
+    [--cell lstm,gru,rglru]
     [--fault-rate F] [--chaos] [--replicas 1,2,4] [out.json]``."""
     from repro.compile_cache import use_compile_cache
     use_compile_cache()
     smoke = "--smoke" in argv
     chaos = "--chaos" in argv
     stateful_backends = None
-    state_residencies = None
     cell_axis = None
     fault_rate = 0.0
     replicas = None
@@ -323,13 +298,6 @@ def main(argv):
                 raise SystemExit(
                     "--stateful-backend needs a comma list of "
                     f"{','.join(STATEFUL_BACKENDS)}")
-        elif a == "--state-residency" or a.startswith("--state-residency="):
-            val = a.split("=", 1)[1] if "=" in a else next(it, "")
-            state_residencies = [r for r in val.split(",") if r]
-            if not state_residencies:
-                raise SystemExit(
-                    "--state-residency needs a comma list of "
-                    f"{','.join(STATE_RESIDENCIES)}")
         elif a == "--cell" or a.startswith("--cell="):
             val = a.split("=", 1)[1] if "=" in a else next(it, "")
             cell_axis = [c for c in val.split(",") if c]
@@ -362,7 +330,7 @@ def main(argv):
     rows = run(smoke=smoke, out_path=paths[0] if paths
                else "BENCH_serving.json", stateful_backends=stateful_backends,
                fault_rate=fault_rate, chaos=chaos, replicas=replicas,
-               state_residencies=state_residencies, cell_axis=cell_axis)
+               cell_axis=cell_axis)
     print("name,us_per_call,derived")
     for n, us, d in rows:
         print(f"{n},{us:.2f},{d}")

@@ -63,8 +63,7 @@ def build_session():
     print(f"[build] lstm_pems H={CONFIG.hidden_size} "
           f"L={CONFIG.num_layers} T={CONFIG.seq_len}, {TRAIN_STEPS} QAT "
           f"steps (last logged loss {loss}), "
-          f"quantised; plan backend={acc.plan['backend']}, "
-          f"state_residency={acc.plan['state_residency']}", flush=True)
+          f"quantised; plan backend={acc.plan['backend']}", flush=True)
     return acc, data
 
 
@@ -167,11 +166,7 @@ def one_chip(acc, data) -> None:
     oracle = ref_oracle(acc, wins)
     with StreamServer(acc) as server:            # batch=256, max_streams=1024
         cfg = server.config
-        if server.state_residency != "device":
-            fail(f"state_residency resolved to {server.state_residency!r}, "
-                 f"not 'device'")
         print(f"[server] batch={cfg.batch} max_streams={cfg.max_streams} "
-              f"state_residency={server.state_residency} "
               f"ladder={server.guard.ladder}", flush=True)
         rows = run_load(server, wins)
         summary = server.metrics_summary()
@@ -193,12 +188,12 @@ def four_chips(acc, data) -> None:
     with repro.build_cluster(acc, 4, devices=devices[:4]) as cluster:
         placed = {}
         for name, server in cluster._servers.items():
-            ids = {d.id for leaf in jax.tree.leaves(server._sessions[0].qparams)
+            ids = {d.id for leaf in jax.tree.leaves(server._session.qparams)
                    for d in leaf.devices()}
             ids |= {d.id for d in server.states.table.devices()}
-            if len(ids) != 1 or server.state_residency != "device":
+            if len(ids) != 1:
                 fail(f"replica {name}: qparams and state table span devices "
-                     f"{sorted(ids)}, residency {server.state_residency!r}")
+                     f"{sorted(ids)}")
             placed[name] = ids.pop()
         if len(set(placed.values())) != 4:
             fail(f"replicas share devices: {placed}")

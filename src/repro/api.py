@@ -282,9 +282,8 @@ class Accelerator:
         per-stream carry table (``init_state_table``) and the slot vectors
         are (B,) int32 table-row ids.  Per wave the host ships only the
         float window batch and the two slot vectors — no (h, c) arrays
-        cross the host/device boundary, which is what
-        ``plan()['state_residency'] == 'device'`` buys the serving tier.
-        The fused pallas engine gathers/scatters inside the kernel;
+        cross the host/device boundary; every stateful ``StreamServer``
+        serves through it.  The fused pallas engine gathers/scatters inside the kernel;
         ``ref``/``xla`` run the XLA-level adapter, so every rung of the
         degradation ladder accepts the same arguments.  Bit-identical to
         ``compiled_stateful`` fed the host-gathered carries.
@@ -425,8 +424,7 @@ class Accelerator:
                              backend=backend)
 
     def measure_scenario(self, scenario, *, batch: Optional[int] = None,
-                         replicas: int = 1,
-                         state_residency: str = "auto") -> Dict[str, Any]:
+                         replicas: int = 1) -> Dict[str, Any]:
         """Measure THIS session at a serving operating point.
 
         ``scenario`` is a ``repro.explore.ServingScenario``; a short real
@@ -437,8 +435,7 @@ class Accelerator:
         operating point: after ``explore.autotune(..., scenario=...)``,
         ``session.measure_scenario(scenario)`` verifies the deployed
         session still meets the SLO it was selected under."""
-        return scenario.run(self, batch=batch, replicas=replicas,
-                            state_residency=state_residency)
+        return scenario.run(self, batch=batch, replicas=replicas)
 
     # -- reporting ----------------------------------------------------------
 

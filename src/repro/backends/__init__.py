@@ -45,7 +45,7 @@ so stateful selection (``select_stateful``, following the plan's
 ``stateful_backend``) resolves exactly like the stateless path
 (docs/API.md §Backends documents the selection order).
 
-For DEVICE-RESIDENT serving state (``plan()['state_residency']``) an
+For the DEVICE-RESIDENT carry table every stateful server keeps, an
 engine may additionally expose
 
   run_stateful_slots(qparams, x_int, model, accel,

@@ -30,7 +30,7 @@ The per-layer integer carry is a tuple of ``state_arity`` int32 arrays of
 shape ``(batch, hidden)`` — the LSTM's classic ``(h, c)`` is simply the
 ``arity == 2`` instance — and the whole-model state is a tuple of those
 over layers (:func:`init_state` / :func:`state_shape`).  Serving keys its
-host store rows and its device slot table
+device slot table
 (``kernels.qlstm_cell.state_table_shape(slots + 2, L, S, H)``) on
 :func:`state_shape`, never on a hardcoded ``(L, 2, H)``.
 """

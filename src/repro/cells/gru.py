@@ -27,7 +27,8 @@ is lifted to wide by the exact ``1.0`` code and the sum rounds once.
 module's general datapath must match bit-for-bit
 (``tests/test_cells.py``).  No fused Pallas kernel yet — the spec's
 ``supports_fused`` is ``None``, so ``plan()`` resolves the xla engine and
-serving keeps host (or adapter-driven device) state residency.
+serving drives the device carry table through the XLA-level slot
+adapter.
 """
 
 from __future__ import annotations

@@ -23,7 +23,8 @@ contract of ``core.qlstm``); MACs switch by ALU mode through
 ``qlstm.int_mac``.  ``kernels/ref.qrglru_seq_ref`` is the independently
 written oracle the general datapath must match bit-for-bit.  No fused
 Pallas kernel — ``supports_fused`` is ``None`` so ``plan()`` resolves the
-xla engine and host state residency.
+xla engine, and serving drives the device carry table through the
+XLA-level slot adapter.
 """
 
 from __future__ import annotations

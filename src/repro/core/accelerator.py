@@ -251,24 +251,6 @@ def resolve_stateful_backend(model: QLSTMConfig,
     return resolve_backend(model, acc)
 
 
-def resolve_state_residency(model: QLSTMConfig,
-                            acc: AcceleratorConfig) -> str:
-    """Where the serving tier keeps per-stream carries (the cell's
-    ``(state_arity, hidden)`` rows per layer): ``device`` | ``host``.
-
-    The fused Pallas kernel owns an in-kernel slot gather/scatter path
-    (``kernels/qlstm_cell.qlstm_seq_slot_pallas``), so when it is the
-    resolved stateful engine the carry table lives in device memory and
-    the host ships only slot ids per wave — the paper's state-next-to-
-    compute residency argument.  Everything else defaults to the host-side
-    LRU ``StateStore`` (``repro.serving.state``); an explicit
-    ``ServingConfig(state_residency='device')`` can still force the
-    device table onto ``ref``/``xla`` through their XLA-level slot
-    adapters."""
-    return ("device" if resolve_stateful_backend(model, acc) == "pallas"
-            else "host")
-
-
 def plan(model: QLSTMConfig, acc: AcceleratorConfig) -> Dict:
     """Resolve every implementation decision for (model, accelerator).
 
@@ -300,10 +282,6 @@ def plan(model: QLSTMConfig, acc: AcceleratorConfig) -> Dict:
         # see resolve_stateful_backend), kept as its own key so serving
         # code has one stable place to ask.
         "stateful_backend": resolve_stateful_backend(model, acc),
-        # Where serving keeps per-stream carries: "device" (slot table on
-        # the accelerator, in-kernel gather/scatter) when the fused pallas
-        # kernel serves the stateful path, else "host" (the LRU StateStore).
-        "state_residency": resolve_state_residency(model, acc),
         # MXU tiles are 128x128: tiny LSTMs under-fill them, exactly like
         # tiny models under-fill DSP columns.  Report the padding waste.
         "mxu_fill_fraction": _mxu_fill(model) if acc.compute_unit == "mxu" else None,

@@ -27,8 +27,8 @@ Layout:
   * ``space``       — :class:`SearchSpace` / :class:`Point` over the
                       Table-2 axes (fxp, hs_method, compute_unit,
                       alu_mode, layer width/depth, serve batch, backend,
-                      cell) plus the serving deployment axes (replicas,
-                      state_residency).
+                      cell) plus the serving deployment axis
+                      (replicas).
   * ``constraints`` — declarative, composable validity rules
                       (node-composition: ``&``/``|``/``~``) pruning
                       structurally infeasible points before measurement.
@@ -59,7 +59,6 @@ from repro.explore.constraints import (AllOf, AnyOf,  # noqa: F401
                                        ConstraintNode, InfeasiblePoint, Not,
                                        Rule, backend_supported,
                                        default_constraints,
-                                       device_residency_needs_fused,
                                        replicas_fit_devices)
 from repro.explore.halving import (rung_schedule,  # noqa: F401
                                    successive_halving)
@@ -82,7 +81,7 @@ __all__ = [
     "Rule", "SCHEMA_VERSION", "SERVING_METRIC_KEYS", "SERVING_MINIMISE",
     "SERVING_OBJECTIVES", "SLO", "SLOSet", "SearchSpace", "ServingScenario",
     "autotune", "backend_supported", "constrained_pareto_front",
-    "default_constraints", "device_residency_needs_fused", "dominates",
+    "default_constraints", "dominates",
     "evaluate_point", "evaluate_serving_point", "paper_space",
     "pareto_front", "pareto_indices", "parse_constraint",
     "point_from_config", "replicas_fit_devices", "rung_schedule",

@@ -1,10 +1,9 @@
 """Declarative, composable validity constraints over search-space points.
 
-The serving-aware search space (cells x backends x residency x replicas)
-contains points that are *structurally* infeasible — device-resident state
-on a cell with no fused kernel, more replicas than devices, an explicit
-backend that refuses the configuration.  Measuring them would waste a
-build + scenario run each, so the space prunes them up front.
+The serving-aware search space (cells x backends x replicas) contains
+points that are *structurally* infeasible — more replicas than devices,
+an explicit backend that refuses the configuration.  Measuring them would
+waste a build + scenario run each, so the space prunes them up front.
 
 The pruning rules are composed declaratively, node-style: every rule is a
 :class:`ConstraintNode`; ``&`` / ``|`` / ``~`` build composite trees out of
@@ -160,28 +159,6 @@ def backend_supported() -> Rule:
     return Rule("backend_supported", _backend_supported)
 
 
-def _device_residency_fused(point, base_model, base_accel) -> Optional[str]:
-    if point.state_residency != "device":
-        return None
-    from repro.core.accelerator import plan
-    model_cfg, accel_cfg = point.configs(base_model, base_accel)
-    pl = plan(model_cfg, accel_cfg)
-    if pl["state_residency"] != "device":
-        return (f"device-resident carry needs the fused stateful plan; "
-                f"cell={point.cell!r} on backend={point.backend!r} resolves "
-                f"to stateful_backend={pl['stateful_backend']!r} (host "
-                f"residency)")
-    return None
-
-
-def device_residency_needs_fused() -> Rule:
-    """``state_residency='device'`` is only a deployable operating point
-    where the plan itself resolves device residency (the fused pallas
-    stateful path); pinning it elsewhere measures an adapter degradation,
-    not a design point."""
-    return Rule("device_residency", _device_residency_fused)
-
-
 def _replicas_fit(point, base_model, base_accel) -> Optional[str]:
     if point.replicas <= 1:
         return None
@@ -203,7 +180,5 @@ def replicas_fit_devices() -> Rule:
 def default_constraints() -> ConstraintNode:
     """The composite every :class:`~repro.explore.space.SearchSpace`
     applies unless it carries its own tree: backend feasibility AND
-    fused-plan device residency AND replica/device fit."""
-    return (backend_supported()
-            & device_residency_needs_fused()
-            & replicas_fit_devices())
+    replica/device fit."""
+    return backend_supported() & replicas_fit_devices()

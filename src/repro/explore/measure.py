@@ -12,8 +12,8 @@ sweeps (``scenario=...``) instead stand up a short real
 ``metrics_summary()``-derived objectives: p50/p95/p99, achieved
 samples/s, deadline-miss rate, GOP/s/W.
 
-Structurally infeasible points (device residency without the fused plan,
-replicas > devices, a refusing explicit backend — see
+Structurally infeasible points (replicas > devices, a refusing explicit
+backend — see
 ``repro.explore.constraints``) are pruned BEFORE measurement and recorded
 with the violated rule's reason.  ``strategy="halving"`` replaces the
 full per-point scenario with seeded successive halving
@@ -163,7 +163,7 @@ def _prune(space: SearchSpace, points: List[Point], base_model,
            base_accel, log) -> Tuple[List[Point], Dict[str, Dict]]:
     """Split the candidate list on the space's constraint tree.  Pruned
     points become rows up front: backend refusals keep the historical
-    ``"unsupported"`` status, structural invalidity (residency, replicas)
+    ``"unsupported"`` status, structural invalidity (replicas)
     is ``"infeasible"`` — both carry the violated rule's reason."""
     survivors: List[Point] = []
     pruned: Dict[str, Dict] = {}

@@ -189,8 +189,7 @@ class ServingScenario:
                       if f.name in d})
 
     def run(self, session, *, batch: Optional[int] = None,
-            replicas: int = 1, state_residency: str = "auto",
-            devices=None) -> Dict[str, float]:
+            replicas: int = 1, devices=None) -> Dict[str, float]:
         """Measure ``session`` at this operating point.
 
         Stands up a real ``StreamServer`` (``replicas == 1``) or
@@ -209,7 +208,6 @@ class ServingScenario:
             (self.streams, self.windows_per_stream, t,
              session.model.input_size)) * 0.5).astype(np.float32)
         kw = dict(batch=b, deadline_s=self.deadline_ms / 1e3,
-                  state_residency=state_residency,
                   max_streams=max(16, 2 * self.streams))
         if replicas > 1:
             from repro.api import build_cluster
@@ -268,8 +266,8 @@ def serving_plan(point, base_model=None, base_accel=None) -> Dict:
 
     The checks are the imperative form of
     ``constraints.default_constraints()``: the (possibly explicit) backend
-    must carry state for the configuration, pinned device residency needs
-    the fused stateful plan, and ``replicas`` distinct devices must exist
+    must carry state for the configuration, and ``replicas`` distinct
+    devices must exist
     (production posture of ``launch.mesh.serving_devices``)."""
     from repro import backends
     from repro.core.accelerator import plan as _plan
@@ -279,25 +277,15 @@ def serving_plan(point, base_model=None, base_accel=None) -> Dict:
     except backends.BackendUnsupported as e:
         raise InfeasiblePoint(f"backend: {e}") from e
     pl = _plan(model_cfg, accel_cfg)
-    if point.state_residency == "device" \
-            and pl["state_residency"] != "device":
-        raise InfeasiblePoint(
-            f"state_residency: device-resident carry needs the fused "
-            f"stateful plan; cell={point.cell!r} on "
-            f"backend={point.backend!r} resolves to "
-            f"stateful_backend={pl['stateful_backend']!r}")
     if point.replicas > 1:
         from repro.launch.mesh import serving_devices
         try:
             serving_devices(point.replicas, oversubscribe=False)
         except (RuntimeError, ValueError) as e:
             raise InfeasiblePoint(f"replicas: {e}") from e
-    residency = (point.state_residency if point.state_residency != "auto"
-                 else pl["state_residency"])
     return {
         "backend": engine.name,
         "stateful_backend": pl["stateful_backend"],
-        "state_residency": residency,
         "replicas": point.replicas,
     }
 
@@ -315,8 +303,7 @@ def evaluate_serving_point(point, scenario: ServingScenario,
         model_cfg, accel_cfg = point.configs(base_model, base_accel)
         session = build(model_cfg, accel_cfg, seed=seed).quantize()
     metrics = scenario.run(session, batch=point.batch,
-                           replicas=point.replicas,
-                           state_residency=point.state_residency)
+                           replicas=point.replicas)
     return {
         "label": point.label,
         "config": point.asdict(),

@@ -32,9 +32,7 @@ from repro.core.qlstm import QLSTMConfig
 # runs so sweep artifacts diff cleanly.
 AXES = ("fxp", "hs_method", "compute_unit", "alu_mode",
         "hidden_size", "num_layers", "batch", "backend", "cell",
-        "replicas", "state_residency")
-
-STATE_RESIDENCIES = ("auto", "host", "device")
+        "replicas")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,12 +50,10 @@ class Point:
     # The recurrent cell id (default keeps pre-cell-axis records and
     # Point(...) call sites valid).
     cell: str = "lstm"
-    # Serving-side deployment axes (defaults keep pre-serving-axis records
-    # and positional Point(...) call sites valid): how many cluster
-    # replicas the point deploys as, and where the per-stream carry lives
-    # (auto | host | device — the ServingConfig knob).
+    # Serving-side deployment axis (the default keeps pre-serving-axis
+    # records and positional Point(...) call sites valid): how many
+    # cluster replicas the point deploys as.
     replicas: int = 1
-    state_residency: str = "auto"
 
     def configs(self, base_model: Optional[QLSTMConfig] = None,
                 base_accel: Optional[AcceleratorConfig] = None,
@@ -83,8 +79,8 @@ class Point:
     def label(self) -> str:
         """Stable human/machine-readable id, e.g.
         ``a4b8_step_mxu_pipelined_h20x1_b256_auto`` (non-LSTM cells get
-        a ``_gru``/``_rglru`` suffix; non-default serving axes append
-        ``_rN`` / ``_host``/``_device``.  Default-axis labels are
+        a ``_gru``/``_rglru`` suffix; more than one replica appends
+        ``_rN``.  Default-axis labels are
         unchanged from earlier eras so existing sweep artifacts still
         diff cleanly)."""
         base = (f"a{self.fxp.frac_bits}b{self.fxp.total_bits}_"
@@ -95,8 +91,6 @@ class Point:
             base += f"_{self.cell}"
         if self.replicas != 1:
             base += f"_r{self.replicas}"
-        if self.state_residency != "auto":
-            base += f"_{self.state_residency}"
         return base
 
     def asdict(self) -> dict:
@@ -132,7 +126,6 @@ class SearchSpace:
     backend: Sequence[str] = ("auto",)
     cell: Sequence[str] = ("lstm",)
     replicas: Sequence[int] = (1,)
-    state_residency: Sequence[str] = ("auto",)
     constraints: Optional[object] = None
 
     def __post_init__(self):
@@ -148,7 +141,6 @@ class SearchSpace:
         _check("compute_unit", self.compute_unit, ("mxu", "vpu"))
         _check("alu_mode", self.alu_mode, ALU_MODES)
         _check("backend", self.backend, BACKENDS)
-        _check("state_residency", self.state_residency, STATE_RESIDENCIES)
         from repro import cells as _cells
         _check("cell", self.cell, _cells.available())
         for axis in ("hidden_size", "num_layers", "batch", "replicas"):
@@ -215,10 +207,9 @@ def point_from_config(config: dict) -> Point:
     kw["fxp"] = FixedPointConfig(kw["fxp"]["frac_bits"],
                                  kw["fxp"]["total_bits"])
     # Records written before the cell / serving axes existed have no keys
-    # for them — they were single-replica LSTM points with auto residency.
+    # for them — they were single-replica LSTM points.
     kw.setdefault("cell", "lstm")
     kw.setdefault("replicas", 1)
-    kw.setdefault("state_residency", "auto")
     return Point(**{a: kw[a] for a in AXES})
 
 
@@ -240,13 +231,11 @@ def paper_space(batch: int = 256) -> SearchSpace:
 
 
 def smoke_space(batch: int = 32, cell: Sequence[str] = ("lstm",),
-                replicas: Sequence[int] = (1,),
-                state_residency: Sequence[str] = ("auto",)) -> SearchSpace:
+                replicas: Sequence[int] = (1,)) -> SearchSpace:
     """Four cheap CPU-safe points per cell (fixed-point format x ALU
     mode) — the deterministic sweep CI runs and tests assert on.  ``cell``
     widens the sweep across the registered cell zoo (``bench_pareto``
-    passes all three); ``replicas``/``state_residency`` open the serving
-    deployment axes for scenario sweeps."""
+    passes all three); ``replicas`` opens the serving deployment axis for
+    scenario sweeps."""
     return SearchSpace(fxp=(FXP_4_8, FXP_8_16), alu_mode=ALU_MODES,
-                       batch=(batch,), cell=cell, replicas=replicas,
-                       state_residency=state_residency)
+                       batch=(batch,), cell=cell, replicas=replicas)

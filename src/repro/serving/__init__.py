@@ -2,8 +2,9 @@
 
 The paper's deployment story is a single real-time sensor stream (§6:
 32 873 samples/s); this package is the production form of that story —
-many named client streams multiplexed onto one or more ``Accelerator``
-sessions, each stream's recurrent carry held across windows, waves
+many named client streams multiplexed onto an ``Accelerator`` session
+(or, behind a cluster, one per replica), each stream's recurrent carry
+held across windows in a device-resident slot table, waves
 double-buffered against device compute, tail latency bounded by a
 deadline, and the paper's metrics (samples/s, GOP/s/W, latency
 percentiles) measured where the server actually runs.
@@ -11,17 +12,15 @@ percentiles) measured where the server actually runs.
 Public surface (docs/SERVING.md is the deployment guide):
 
   * :class:`StreamServer` — submit/poll/flush/close over named streams.
-  * :class:`ServingConfig` — batch, deadline, backpressure, state-store
+  * :class:`ServingConfig` — batch, deadline, backpressure, carry-table
     capacity, resilience and overload policies.
   * :class:`StreamResult` — (stream_id, seq, prediction) rows, plus the
     structured error/``state_reset`` reliability flags.
-  * :class:`StateStore` — the bounded LRU carry store (exposed for tests
-    and capacity planning).
-  * :class:`DeviceStateStore` / :class:`SlotAllocator` — the
-    device-resident alternative (``repro.serving.device_state``): carries
-    live in an on-accelerator slot table, the host keeps only the LRU
+  * :class:`DeviceStateStore` / :class:`SlotAllocator` — the carry
+    store (``repro.serving.device_state``): carries live in an
+    on-accelerator slot table, the host keeps only the LRU
     ``stream_id -> slot`` map, and the hot path ships slot ids instead of
-    (h, c) arrays (``ServingConfig.state_residency``).
+    (h, c) arrays.
   * :class:`ResiliencePolicy` / :class:`ExecutionGuard` — guarded wave
     execution: retry, timeout, backend degradation pallas -> xla -> ref
     with recovery probes (``repro.serving.resilience``).
@@ -56,14 +55,13 @@ from repro.serving.scheduler import (OverloadPolicy,             # noqa: F401
                                      WaveScheduler)
 from repro.serving.server import (ServingConfig, StreamResult,   # noqa: F401
                                   StreamServer, serve_windows)
-from repro.serving.state import StateStore, StreamState          # noqa: F401
 
 __all__ = [
     "ClusterConfig", "ClusterServer", "DeviceStateStore", "ExecutionGuard",
     "FaultConfig", "FaultInjector", "GuardOutcome", "HashRing",
     "InjectedFault", "SlotAllocator",
     "MetricsSink", "OverloadPolicy", "ResiliencePolicy", "ServerOverloaded",
-    "ServingConfig", "StateStore", "StreamResult", "StreamServer",
-    "StreamState", "Wave", "WaveRecord", "WaveScheduler", "WaveTimeout",
+    "ServingConfig", "StreamResult", "StreamServer",
+    "Wave", "WaveRecord", "WaveScheduler", "WaveTimeout",
     "serve_windows",
 ]

@@ -5,7 +5,7 @@ One ``StreamServer`` is one device's worth of throughput (the paper's §6
 point: 32 873 samples/s on one FPGA).  The ROADMAP's millions-of-users
 scenario scales OUT: N replica servers, each pinned to its own device
 (``Accelerator.replicate`` / ``launch.mesh.serving_devices``), each owning
-its own scheduler threads, state store, and overload policy — and a
+its own scheduler threads, carry table, and overload policy — and a
 routing layer in front that keeps the one invariant scale-out must not
 break: **a stream's (h, c) carry never migrates across replicas on the
 hot path**.  ELSA's state-residency argument at cluster scale — recurrent
@@ -14,8 +14,8 @@ state stays next to the compute that consumes it.
 The invariant comes from :class:`~repro.serving.routing.HashRing`
 (consistent hashing with virtual nodes): every named stream hashes to
 exactly one replica, so all its windows execute there and its carry stays
-in that replica's ``StateStore``.  ``StreamResult.routed_replica`` carries
-the replica name out, so the invariant is testable per row.
+in that replica's device slot table.  ``StreamResult.routed_replica``
+carries the replica name out, so the invariant is testable per row.
 
 Deployment shape::
 
@@ -35,15 +35,15 @@ replica (``ExecutionGuard``), and the front door adds only what needs the
 global view — routing, failover off a replica whose ``health()`` reports
 ``failed``, aggregate metrics (``MetricsSink.merge``), and the
 drain/rebalance path whose ring-shrink moves only the leaving replica's
-~K/N streams (their carries reset with ``state_reset=True`` provenance;
-every other stream is untouched).
+~K/N streams (a planned drain hands their carries over warm; every other
+stream is untouched).
 
-Re-route semantics (rebalance, failover, or a ring change): a moved
-stream RESTARTS on its new replica — sequence numbering from 0 and the
-zero reset carry, with its first window flagged ``state_reset=True``
-because the history was real.  This mirrors the ``StreamServer`` LRU
-eviction semantics exactly: a flagged reset, never a silently wrong
-continuation from a stale carry left on the old device.
+Re-route semantics (failover, a replica added or restored, or an
+abandoned drain): a moved stream RESTARTS on its new replica — sequence
+numbering from 0 and the zero reset carry, with its first window flagged
+``state_reset=True`` because the history was real.  This mirrors the
+``StreamServer`` LRU eviction semantics exactly: a flagged reset, never a
+silently wrong continuation from a stale carry left on the old device.
 """
 
 from __future__ import annotations
@@ -53,19 +53,29 @@ import threading
 import time
 from typing import Dict, Hashable, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.serving.metrics import MetricsSink
 from repro.serving.routing import HashRing
 from repro.serving.scheduler import ServerOverloaded
-from repro.serving.server import (ServingConfig, StreamResult, StreamServer,
-                                  _params_equal)
+from repro.serving.server import ServingConfig, StreamResult, StreamServer
 
 # faults keys summed across replicas by metrics_summary (deadline_miss_rate
 # is taken as the worst replica's instead; backend/degraded summarised).
 _FAULT_SUM_KEYS = ("retries", "timeouts", "wave_failures", "degradations",
                    "promotions", "probes", "sheds", "rejections",
                    "recoveries", "state_resets", "stream_errors")
+
+
+def _params_equal(a, b) -> bool:
+    """True when two param pytrees hold identical weights (replica check —
+    the model is tiny, so exact comparison at construction is cheap)."""
+    la = jax.tree_util.tree_leaves(a)
+    lb = jax.tree_util.tree_leaves(b)
+    return len(la) == len(lb) and all(
+        x is y or np.array_equal(np.asarray(x), np.asarray(y))
+        for x, y in zip(la, lb))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -103,9 +113,7 @@ class ClusterServer:
 
     Each replica runs its OWN scheduler threads — wave assembly and
     device compute proceed in parallel across replicas, which is where
-    the aggregate-throughput scaling comes from (the single
-    ``StreamServer`` multi-session mode only round-robins one compute
-    thread)."""
+    the aggregate-throughput scaling comes from."""
 
     def __init__(self, replicas: Sequence, config: Optional[ClusterConfig]
                  = None, *, names: Optional[Sequence[str]] = None,
@@ -329,7 +337,7 @@ class ClusterServer:
             if name in self._servers or name in self._unhealthy:
                 raise ValueError(f"replica name {name!r} already in use")
         if ref is not None:
-            sess0 = ref._sessions[0]
+            sess0 = ref._session
             if session.model != sess0.model \
                     or not _params_equal(session.params, sess0.params):
                 raise ValueError(
@@ -350,21 +358,16 @@ class ClusterServer:
         only ~K/N of the cluster's streams (the consistent-hash guarantee;
         everything else keeps replica, carry, and numbering).
 
-        What a moved stream keeps depends on where its carry lived.  With
-        HOST-resident state it restarts cold: ``state_reset=True``
-        provenance on its first window at the new replica.  With
-        DEVICE-resident state (``state_residency='device'``/``auto`` on a
-        pallas plan) a planned drain performs a WARM HANDOFF: after the
-        flush, each moved stream's carry is read back from the draining
-        replica's device table (the one sanctioned host/device state
-        transfer) and seeded into its new ring home, so its recurrence
-        continues bit-exactly — no reset, no flag (its per-replica seq
-        still restarts at 0).  ``abandon=True`` skips drain AND handoff
-        (replica died; pending windows and device-resident carries are
-        lost, and the moved streams restart cold with flagged resets).
-        Call with the moved streams quiescent — windows submitted for
-        them mid-drain race the handoff, exactly like they race the cold
-        path's re-route."""
+        A planned drain performs a WARM HANDOFF: after the flush, each
+        moved stream's carry is read back from the draining replica's
+        device table (the one sanctioned host/device state transfer) and
+        seeded into its new ring home, so its recurrence continues
+        bit-exactly — no reset, no flag (its per-replica seq still
+        restarts at 0).  ``abandon=True`` skips drain AND handoff (replica
+        died; pending windows and carries are lost, and the moved streams
+        restart cold with flagged resets).  Call with the moved streams
+        quiescent — windows submitted for them mid-drain race the
+        handoff."""
         with self._lock:
             if name not in self._servers:
                 raise KeyError(f"no replica named {name!r}")
@@ -384,7 +387,7 @@ class ClusterServer:
             moved = [sid for sid, rname in self._route.items()
                      if rname == name]
         handoff: Dict[Hashable, object] = {}
-        if not abandon and server.state_residency == "device":
+        if not abandon:
             for sid in moved:
                 st = server.read_stream_state(sid)
                 if st is not None:

@@ -1,21 +1,19 @@
 """Device-resident per-stream carry — the slot allocator and state table.
 
-The host-side :class:`~repro.serving.state.StateStore` ships every
-stream's carry codes to the device and back on EVERY wave.  This module
-is ROADMAP item 1's answer: the carries live in one persistent int32
-table ON the accelerator, ``max_slots + 2`` slots of the cell's
-``plan()['state_shape']`` ``(L, S, H)`` carry — ``S == 2`` (h, c) for
-the LSTM, ``S == 1`` for GRU/rGLRU — laid out by
+Every stateful ``StreamServer`` keeps its streams' carries here, in one
+persistent int32 table ON the accelerator: ``max_slots + 2`` slots of
+the cell's ``plan()['state_shape']`` ``(L, S, H)`` carry — ``S == 2``
+(h, c) for the LSTM, ``S == 1`` for GRU/rGLRU — laid out by
 ``kernels.qlstm_cell.state_table_shape``
-(``Accelerator.init_state_table``), and the host keeps only a
-:class:`SlotAllocator` — an LRU map ``stream_id -> table row`` with
-exactly the hit/miss/eviction accounting of the ``StateStore`` it
-replaces.  Per wave the scheduler ships two (B,) int32 slot-id vectors;
-the kernel (``kernels/qlstm_cell.qlstm_seq_slot_pallas``) gathers each
-row's carry at t == 0 and scatters the final state at t == T-1, so no
-carry array crosses the host/device boundary on the hot path — the
-paper's state-next-to-compute residency argument, and ELSA's throughput
-lever, applied to serving.
+(``Accelerator.init_state_table``).  The host keeps only a
+:class:`SlotAllocator`, a bounded LRU map ``stream_id -> table row``
+with hit/miss/eviction counters.  Per wave the scheduler ships two (B,)
+int32 slot-id vectors; the engine gathers each row's carry at t == 0 and
+scatters the final state at t == T-1 — inside the fused kernel
+(``kernels/qlstm_cell.qlstm_seq_slot_pallas``) on ``pallas``, through
+the XLA-level adapter on ``ref``/``xla`` — so no carry array crosses the
+host/device boundary on the hot path: the paper's state-next-to-compute
+residency argument, and ELSA's throughput lever, applied to serving.
 
 Table slot conventions (shared with the kernel and the XLA-level adapter
 ``backends.common.run_slots_via_state``):
@@ -29,9 +27,9 @@ Table slot conventions (shared with the kernel and the XLA-level adapter
     TRASH slot: the scatter target for padding rows, tombstoned windows,
     and same-wave eviction victims; never read.
 
-Eviction/reset semantics are IDENTICAL to the host store: an evicted or
-brand-new stream gathers the ZERO row and its first window back is
-flagged ``state_reset=True``.  The stale codes left in a freed slot are
+Eviction/reset semantics: an evicted or brand-new stream gathers the
+ZERO row, and a window of a stream with history computed from it comes
+back flagged ``state_reset=True``.  The stale codes left in a freed slot are
 unreachable — a returning stream misses in the allocator before it could
 ever gather them, and the slot's next owner overwrites them at its first
 scatter.
@@ -62,23 +60,26 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels.qlstm_cell import gather_carry, scatter_carry
-from repro.serving.state import StreamState
+
+# One stream's carry on the host: per layer, a tuple of the cell's
+# ``state_arity`` (hidden_size,) int32 code vectors (2 for the LSTM's
+# (h, c), 1 for GRU/rGLRU).
+StreamState = List[Tuple[np.ndarray, ...]]
 
 
 class SlotAllocator:
     """LRU map ``stream_id -> slot id`` over ``capacity`` device-table rows.
 
     The host half of the device-resident state store: it decides WHICH
-    table row each stream's carry occupies, with the exact semantics of
-    ``StateStore`` — :meth:`lookup` is ``get`` (recency refresh,
-    hit/miss counters), :meth:`assign` is ``put`` (insert or refresh,
-    LRU eviction when full), :meth:`release` is ``pop``.  Slot ids are
+    table row each stream's carry occupies — :meth:`lookup` (recency
+    refresh, hit/miss counters), :meth:`assign` (insert or refresh, LRU
+    eviction when full), :meth:`release`.  Slot ids are
     unique among live streams; released slots are reused (LIFO) before
     the high-water mark grows, so a bursty tenancy pattern touches the
     fewest distinct table rows.
 
     NOT thread-safe on its own — :class:`DeviceStateStore` serialises
-    access under its lock, exactly like ``StateStore`` does internally."""
+    access under its lock."""
 
     def __init__(self, capacity: int = 1024):
         """``capacity``: number of live stream slots (>= 1)."""
@@ -95,7 +96,7 @@ class SlotAllocator:
     def lookup(self, stream_id: Hashable) -> Optional[int]:
         """The stream's slot (refreshing its recency), or ``None`` when
         the stream is new or was evicted — the caller gathers the ZERO
-        row.  Mirrors ``StateStore.get``, counters included."""
+        row."""
         slot = self._slots.get(stream_id)
         if slot is None:
             self.misses += 1
@@ -106,8 +107,8 @@ class SlotAllocator:
 
     def assign(self, stream_id: Hashable) -> Tuple[int, List[Hashable]]:
         """The slot the stream's next scatter should target, allocating
-        one if needed; returns ``(slot, evicted_ids)``.  Mirrors
-        ``StateStore.put``: an existing stream keeps its slot (recency
+        one if needed; returns ``(slot, evicted_ids)``: an existing
+        stream keeps its slot (recency
         refreshed); a new stream takes a freed slot, a never-used slot,
         or — when all ``capacity`` slots are live — the LRU victim's
         (the victim is evicted and returned so the caller can release its
@@ -130,7 +131,7 @@ class SlotAllocator:
 
     def release(self, stream_id: Hashable) -> Optional[int]:
         """Free a stream's slot (end-of-stream / state loss); returns the
-        slot or ``None``.  Mirrors ``StateStore.pop``."""
+        slot or ``None``."""
         slot = self._slots.pop(stream_id, None)
         if slot is not None:
             self._free.append(slot)
@@ -159,16 +160,16 @@ class SlotAllocator:
 
 
 class DeviceStateStore:
-    """The device-resident replacement for ``StateStore``: a
-    :class:`SlotAllocator` plus the accelerator-resident state table.
+    """A stateful server's carry store: a :class:`SlotAllocator` plus the
+    accelerator-resident state table.
 
-    API-compatible with ``StateStore`` where the serving layer needs it
-    (``pop`` / ``stats`` / ``__len__`` / ``__contains__`` / ``capacity``),
-    plus the slot surface the device hot path runs on (:meth:`lookup` /
-    :meth:`assign` / :meth:`commit`) and the planned-movement surface
-    (:meth:`read_state` / :meth:`seed_state`).  All methods take the
-    internal lock; multi-op wave transactions are additionally serialised
-    by the server's own lock, like the host store's gather/scatter."""
+    The bookkeeping surface (``pop`` / ``ids`` / ``stats`` / ``__len__``
+    / ``__contains__`` / ``capacity``), the slot surface the hot path runs
+    on (:meth:`lookup` / :meth:`assign` / :meth:`take` / :meth:`commit` /
+    :meth:`restore`) and the planned-movement surface (:meth:`read_state`
+    / :meth:`seed_state`).  All methods take the internal lock; multi-op
+    wave transactions are additionally serialised by the server's own
+    lock."""
 
     def __init__(self, session, capacity: int = 1024):
         """``session``: the (quantised) ``Accelerator`` whose device owns
@@ -201,12 +202,14 @@ class DeviceStateStore:
     # -- wave surface (serialised by the server's lock) ----------------------
 
     def lookup(self, stream_id: Hashable) -> Optional[int]:
-        """GET-phase slot lookup — ``StateStore.get`` semantics."""
+        """The stream's slot, refreshing its recency
+        (:meth:`SlotAllocator.lookup`)."""
         with self._lock:
             return self._alloc.lookup(stream_id)
 
     def assign(self, stream_id: Hashable) -> Tuple[int, List[Hashable]]:
-        """PUT-phase slot assignment — ``StateStore.put`` semantics."""
+        """The slot the stream's scatter targets, and any evicted ids
+        (:meth:`SlotAllocator.assign`)."""
         with self._lock:
             return self._alloc.assign(stream_id)
 
@@ -222,8 +225,7 @@ class DeviceStateStore:
         """Adopt the kernel's updated table after a successful wave.
         ``rows`` lists the wave's real scatters as ``(batch_row,
         stream_id)`` — unused here, but the fault-injection wrapper draws
-        its per-put schedule from them (``faults.FaultyDeviceStateStore``),
-        keeping the injected schedule identical to the host store's."""
+        its per-row schedule from them (``faults.FaultyDeviceStateStore``)."""
         with self._lock:
             self._give_back(new_table)
 
@@ -307,10 +309,10 @@ class DeviceStateStore:
     # -- fault-injection surface ---------------------------------------------
 
     def corrupt_slot(self, stream_id: Hashable) -> bool:
-        """XOR the low bit of every code in the stream's table row — the
-        device form of the host store's put-corruption (same perturbation
-        as ``FaultInjector._mutate_put``).  Returns False for an unknown
-        stream (nothing to corrupt).  Waits while a wave holds the
+        """XOR the low bit of every code in the stream's table row — a
+        bitwise-plausible corruption that is guaranteed to change the
+        carry (the fault injector's ``corrupt``).  Returns False for an
+        unknown stream (nothing to corrupt).  Waits while a wave holds the
         table."""
         with self._lock:
             slot = self._alloc.slot_of(stream_id)
@@ -325,19 +327,18 @@ class DeviceStateStore:
                  for layer in state])
             return True
 
-    # -- StateStore-compatible reporting ------------------------------------
+    # -- reporting ---------------------------------------------------------
 
     def stats(self) -> Dict[str, int]:
-        """The ``StateStore`` counter block (live_streams, capacity,
-        hits, misses, evictions) plus ``residency``/``slot_high_water``
-        — the serving metrics report is schema-compatible either way."""
+        """Counters for the metrics report: live streams, capacity,
+        hits/misses (carry found vs reset), evictions, and the slot
+        high-water mark."""
         with self._lock:
             return {"live_streams": len(self._alloc),
                     "capacity": self.capacity,
                     "hits": self._alloc.hits,
                     "misses": self._alloc.misses,
                     "evictions": self._alloc.evictions,
-                    "residency": "device",
                     "slot_high_water": self._alloc.high_water}
 
     def __len__(self) -> int:
@@ -347,10 +348,3 @@ class DeviceStateStore:
     def __contains__(self, stream_id: Hashable) -> bool:
         with self._lock:
             return stream_id in self._alloc
-
-    def __getattr__(self, name):
-        raise AttributeError(
-            f"DeviceStateStore has no attribute {name!r}; host-store-only "
-            f"surfaces (get/put of carry arrays) do not exist on the "
-            f"device path — pin ServingConfig(state_residency='host') for "
-            f"host-store semantics")

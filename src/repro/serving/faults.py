@@ -8,9 +8,9 @@ wraps the two surfaces where production faults land —
   * the **backend execute path** (:meth:`FaultInjector.wrap_fn`): injected
     compute exceptions (a Pallas lowering hiccup, a device error) and
     latency spikes (a descheduled host thread, a contended device);
-  * the **state store** (:meth:`FaultInjector.wrap_state_store`): state
-    *loss* (a carry silently dropped, as a crashed replica would) and
-    state *corruption* (bit flips in the stored carry codes).
+  * the **carry table** (:meth:`FaultInjector.wrap_device_state_store`):
+    state *loss* (a carry silently dropped, as a crashed replica would)
+    and state *corruption* (bit flips in the stored carry codes).
 
 Everything is driven by one ``numpy`` PCG64 generator, so a given
 ``(seed, rates)`` pair injects the exact same schedule every run — chaos
@@ -30,11 +30,9 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Callable, Dict, Hashable, List, Optional, Set
+from typing import Callable, Dict, Hashable, Optional, Set
 
 import numpy as np
-
-from repro.serving.state import StateStore, StreamState
 
 
 class InjectedFault(RuntimeError):
@@ -52,11 +50,11 @@ class FaultConfig:
     :class:`InjectedFault` (retries draw independently, so a retried wave
     usually lands).  ``latency_spike_rate`` / ``latency_spike_s``: chance
     an attempt sleeps ``latency_spike_s`` before computing (drives the
-    guard's timeout path).  ``state_loss_rate``: chance a ``put`` into the
-    state store is silently dropped — the stream's next window starts from
-    the reset carry exactly like an LRU eviction.  ``state_corrupt_rate``:
-    chance a ``put`` stores bitwise-perturbed carry codes (the stream's
-    id is recorded so tests can exclude it from bit-exactness)."""
+    guard's timeout path).  ``state_loss_rate``: chance a carry a wave
+    stores is silently dropped — the stream's next window starts from the
+    reset carry exactly like an LRU eviction.  ``state_corrupt_rate``:
+    chance a stored carry's codes are bitwise-perturbed (the stream's id
+    is recorded so tests can exclude it from bit-exactness)."""
 
     wave_fault_rate: float = 0.0
     latency_spike_rate: float = 0.0
@@ -142,30 +140,19 @@ class FaultInjector:
 
         return chaotic
 
-    # -- state-store surface -------------------------------------------------
-
-    def wrap_state_store(self, store: StateStore) -> "FaultyStateStore":
-        """A delegating view of ``store`` whose ``put`` may drop or corrupt
-        carries according to the configured rates."""
-        return FaultyStateStore(store, self)
+    # -- carry-table surface -------------------------------------------------
 
     def wrap_device_state_store(self, store) -> "FaultyDeviceStateStore":
-        """The device-residency counterpart of :meth:`wrap_state_store`:
-        a delegating view of a ``DeviceStateStore`` whose per-wave
-        ``commit`` draws the same lose-then-corrupt schedule per stored
-        row (in batch-row order) that the host store draws per ``put`` —
-        so a given seed injects one identical schedule whichever side of
-        the host/device boundary the carry lives on."""
+        """A delegating view of a ``DeviceStateStore`` whose per-wave
+        ``commit`` draws a lose-then-corrupt fault per stored row, in
+        batch-row order."""
         return FaultyDeviceStateStore(store, self)
 
     def draw_put_fault(self, stream_id: Hashable) -> str:
-        """One put-side draw for ``stream_id``: ``"lose"`` (the carry is
+        """One store-side draw for ``stream_id``: ``"lose"`` (the carry is
         dropped — counted and recorded in :attr:`lost_streams`),
         ``"corrupt"`` (the stored codes must be bit-perturbed — counted
-        and recorded in :attr:`corrupted_streams`), or ``"none"``.  Both
-        store wrappers consume the RNG through this single method, in the
-        same lose-then-corrupt order, which is what keeps the host and
-        device schedules identical for a given seed."""
+        and recorded in :attr:`corrupted_streams`), or ``"none"``."""
         with self._lock:
             lose = self._draw(self.config.state_loss_rate)
             corrupt = (not lose) and self._draw(self.config.state_corrupt_rate)
@@ -179,21 +166,6 @@ class FaultInjector:
                 return "corrupt"
             return "none"
 
-    def _mutate_put(self, stream_id: Hashable,
-                    state: StreamState) -> Optional[StreamState]:
-        """The host-store put-side injection: ``None`` means drop the put
-        entirely (state loss); otherwise the possibly-corrupted state to
-        store."""
-        fault = self.draw_put_fault(stream_id)
-        if fault == "lose":
-            return None
-        if fault != "corrupt":
-            return state
-        # XOR a low bit of every code: bitwise-plausible corruption that
-        # is guaranteed to change the carry.
-        return [tuple(np.bitwise_xor(np.asarray(a), 1) for a in layer)
-                for layer in state]
-
     # -- reporting -----------------------------------------------------------
 
     def stats(self) -> Dict[str, int]:
@@ -204,83 +176,27 @@ class FaultInjector:
             return dict(self._counts)
 
 
-class FaultyStateStore:
-    """A :class:`~repro.serving.state.StateStore` view with injected
-    ``put`` faults; every other method delegates verbatim.
-
-    Kept API-compatible with ``StateStore`` (``get``/``put``/``pop``/
-    ``stats``/``__len__``/``__contains__``/``capacity``) so
-    ``StreamServer`` and its tests cannot tell the difference — which is
-    the point."""
-
-    def __init__(self, store: StateStore, injector: FaultInjector):
-        """Wrap ``store`` with the injector's put-side schedule."""
-        self._store = store
-        self._injector = injector
-
-    @property
-    def capacity(self) -> int:
-        """The wrapped store's capacity."""
-        return self._store.capacity
-
-    def get(self, stream_id: Hashable) -> Optional[StreamState]:
-        """Delegates to the wrapped store (reads are never faulted — a
-        lost carry is modelled at put time, like a crashed replica)."""
-        return self._store.get(stream_id)
-
-    def put(self, stream_id: Hashable,
-            state: StreamState) -> List[Hashable]:
-        """Store the carry — unless the schedule drops it (the stream's
-        existing carry is also popped, so the loss is observable) or
-        corrupts it first."""
-        mutated = self._injector._mutate_put(stream_id, state)
-        if mutated is None:
-            self._store.pop(stream_id)
-            return []
-        return self._store.put(stream_id, mutated)
-
-    def pop(self, stream_id: Hashable) -> Optional[StreamState]:
-        """Delegates to the wrapped store."""
-        return self._store.pop(stream_id)
-
-    def ids(self) -> List[Hashable]:
-        """Delegates to the wrapped store (``reset_streams`` support)."""
-        return self._store.ids()
-
-    def stats(self) -> Dict[str, int]:
-        """The wrapped store's counters."""
-        return self._store.stats()
-
-    def __len__(self) -> int:
-        return len(self._store)
-
-    def __contains__(self, stream_id: Hashable) -> bool:
-        return stream_id in self._store
-
-
 class FaultyDeviceStateStore:
     """A ``DeviceStateStore`` view with injected commit-time faults; every
     other method delegates verbatim (kept API-compatible so the serving
     layer cannot tell the difference — which is the point).
 
-    On the device path the kernel has already scattered every row's carry
-    into the table by the time the wave commits, so faults land AT COMMIT,
-    once per really-stored row in batch-row order — the exact points the
-    host store draws at (one ``put`` per row, same order).  A ``lose``
-    releases the row's slot (the scattered carry becomes unreachable — the
-    stream's next window restarts from the ZERO row, flagged
-    ``state_reset``, exactly like the host store popping the carry); a
+    The engine has already scattered every row's carry into the table by
+    the time the wave commits, so faults land AT COMMIT, once per
+    really-stored row in batch-row order.  A ``lose`` releases the row's
+    slot (the scattered carry becomes unreachable — the stream's next
+    window restarts from the ZERO row, flagged ``state_reset``); a
     ``corrupt`` XORs the low bit of every code in the row's table slot
-    (the same perturbation ``FaultyStateStore`` stores)."""
+    (``DeviceStateStore.corrupt_slot``)."""
 
     def __init__(self, store, injector: FaultInjector):
         """Wrap ``store`` (a ``DeviceStateStore``) with ``injector``'s
-        put-side schedule."""
+        store-side schedule."""
         self._store = store
         self._injector = injector
 
     def commit(self, new_table, rows) -> None:
-        """Adopt the wave's updated table, then apply one put-fault draw
+        """Adopt the wave's updated table, then apply one store-fault draw
         per stored row (``rows``: the wave's ``(batch_row, stream_id)``
         scatters, in batch-row order)."""
         self._store.commit(new_table, rows)

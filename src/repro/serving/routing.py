@@ -2,8 +2,9 @@
 
 The scaling story of ``repro.serving.cluster`` rests on ONE invariant:
 every named stream is served by exactly one replica, so its recurrent
-carry stays resident in that replica's :class:`~repro.serving.state.
-StateStore` and never migrates across devices on the hot path (ELSA's
+carry stays resident in that replica's device slot table
+(:class:`~repro.serving.device_state.DeviceStateStore`) and never
+migrates across devices on the hot path (ELSA's
 state-residency argument, applied at cluster scale).  This module is the
 routing function that provides the invariant.
 

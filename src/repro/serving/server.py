@@ -20,31 +20,20 @@ Deployment shape::
     server.metrics_summary()                  # samples/s, p50/p95/p99, GOP/s/W
     server.close()
 
-Multiple sessions (replicas of ONE configuration sharing one set of
-weights, e.g. one per device) may be passed; waves are dispatched
-round-robin across them by the single strictly-ordered compute thread
-(load spreading — not yet parallel execution; the ordering is what keeps
-per-stream carries consistent).  State lives either in a bounded host LRU
-:class:`~repro.serving.state.StateStore` or — when the fused pallas
-kernel heads the ladder (``ServingConfig.state_residency``, default
-``auto``) — in a device-resident slot table
-(:class:`~repro.serving.device_state.DeviceStateStore`): same LRU
-semantics, but the (h, c) codes never cross the host/device boundary on
-the hot path, only two (B,) slot-id vectors do.  An evicted or brand new
-stream starts from the all-zero reset carry either way.
+A stateful server keeps every stream's carry in its session's
+device-resident slot table
+(:class:`~repro.serving.device_state.DeviceStateStore`), whatever the
+engine: the fused pallas kernel gathers and scatters the rows inside the
+kernel, ``ref``/``xla`` through the XLA-level slot adapter.  The host
+keeps only the LRU ``stream_id -> slot`` map, and per wave only two (B,)
+slot-id vectors cross to the device.  An evicted or brand new stream
+starts from the all-zero reset carry.
 
-The round-robin is WAVE-level, not stream-level: with >= 2 sessions a
-stream's consecutive windows may execute on DIFFERENT sessions
-(``StreamResult.routed_replica`` records which, as the session index).
-That is correct only because a multi-session server's carry lives
-host-side in the shared ``StateStore`` — every session reads the same
-store, so which session computed window *k* does not matter for window
-*k+1*.  Device residency therefore requires a SINGLE session (one table
-on one device; ``auto`` falls back to host for replicas): to scale
-device-resident state across replicas use
+One server serves one session.  To spread streams across replicas use
 ``repro.serving.cluster.ClusterServer``, which pins every stream to
-exactly one replica by consistent hash so its carry stays replica-local
-(the routing invariant, pinned in ``tests/test_cluster.py``).
+exactly one replica by consistent hash so its carry stays in that
+replica's table (the routing invariant, pinned in
+``tests/test_cluster.py``).
 """
 
 from __future__ import annotations
@@ -56,27 +45,16 @@ import time
 from typing import (Dict, Hashable, Iterable, Iterator, List, Optional,
                     Tuple, Union)
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
 
+from repro.serving.device_state import DeviceStateStore
 from repro.serving.faults import FaultInjector
 from repro.serving.metrics import MetricsSink, WaveRecord
 from repro.serving.resilience import ExecutionGuard, ResiliencePolicy
 from repro.serving.scheduler import (OverloadPolicy, Slot, Wave,
                                      WaveScheduler)
-from repro.serving.state import StateStore
-
-
-def _params_equal(a, b) -> bool:
-    """True when two param pytrees hold identical weights (replica check —
-    the model is tiny, so exact comparison at construction is cheap)."""
-    la = jax.tree_util.tree_leaves(a)
-    lb = jax.tree_util.tree_leaves(b)
-    return len(la) == len(lb) and all(
-        x is y or np.array_equal(np.asarray(x), np.asarray(y))
-        for x, y in zip(la, lb))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,27 +79,18 @@ class ServingConfig:
     unbounded: required for the submit-everything-then-``drain()`` pattern
     (``drain`` flushes before polling, so a bound smaller than the
     outstanding windows would deadlock it); production servers with a
-    concurrent ``poll`` loop should set it.  ``max_streams``: LRU
-    state-store capacity.  ``stateful``: carry (h, c) across a stream's windows
-    (requires ``path="int"``); False gives the stateless
-    ``Accelerator.serve`` semantics.  ``backend``: engine override
+    concurrent ``poll`` loop should set it.  ``max_streams``: live
+    streams the carry table holds (LRU-evicted past it).  ``stateful``:
+    carry (h, c) across a stream's windows (requires ``path="int"``);
+    False gives the stateless ``Accelerator.serve`` semantics.
+    ``backend``: engine override
     (``ref`` | ``pallas`` | ``xla`` — all three carry state; the default
     follows the plan's ``stateful_backend``, docs/API.md §Backends).
 
     ``resilience``: the guarded-execution policy (retry/backoff/timeout +
     backend degradation, docs/SERVING.md §Reliability); every wave runs
     under it.  ``overload``: admission-control / load-shedding policy
-    (None = legacy block-on-backpressure, never shed).
-
-    ``state_residency``: where per-stream carries live on a stateful
-    server.  ``auto`` follows the plan — the device-resident slot table
-    when the fused pallas kernel heads the ladder (single-session
-    servers; ``plan()['state_residency']``), else the host-side LRU
-    ``StateStore``.  ``device`` forces the slot table (any stateful
-    engine — ``ref``/``xla`` run the XLA-level slot adapter); ``host``
-    forces the legacy host store.  Both sides are bit-identical; device
-    residency just stops shipping (h, c) arrays across the host/device
-    boundary every wave (docs/SERVING.md §State residency)."""
+    (None = legacy block-on-backpressure, never shed)."""
 
     batch: int = 256
     path: str = "int"
@@ -134,7 +103,6 @@ class ServingConfig:
     max_streams: int = 1024
     resilience: ResiliencePolicy = ResiliencePolicy()
     overload: Optional[OverloadPolicy] = None
-    state_residency: str = "auto"
 
     def __post_init__(self):
         """Reject contradictory settings at construction time."""
@@ -143,14 +111,6 @@ class ServingConfig:
                 f"stateful serving carries integer state codes, so it "
                 f"requires path='int' (got path={self.path!r}); set "
                 f"stateful=False for the float/qat paths")
-        if self.state_residency not in ("auto", "host", "device"):
-            raise ValueError(
-                f"state_residency must be auto|host|device, got "
-                f"{self.state_residency!r}")
-        if self.state_residency == "device" and not self.stateful:
-            raise ValueError(
-                "state_residency='device' is a stateful-serving knob; a "
-                "stateless server carries no per-stream state to place")
         if self.max_results is not None and self.max_results < 1:
             raise ValueError(
                 f"max_results must be >= 1, got {self.max_results}")
@@ -176,13 +136,10 @@ class StreamResult:
     just lost the history; silent before, now reported.  ``backend`` names
     the engine that computed the window (None for error rows).
 
-    ``routed_replica`` says WHERE the window ran: on a ``StreamServer``
-    it is the index of the session that executed the wave (None for shed
-    windows, which never executed anywhere) — with >= 2 sessions a
-    stream's windows may carry DIFFERENT indices, the wave-level
-    round-robin documented in the module docstring.  Through
-    ``ClusterServer`` it is the replica NAME, and the routing invariant
-    guarantees one stream always reports one replica.
+    ``routed_replica`` says WHERE the window ran: None on a
+    ``StreamServer``; through ``ClusterServer`` it is the replica NAME,
+    and the routing invariant guarantees one stream always reports one
+    replica.
 
     ``wave`` is the id of the wave that computed the window (None for
     shed windows): the ``wave=`` tag of that wave's ``serve.*`` spans on
@@ -234,43 +191,33 @@ class _StageClock:
 
 
 class StreamServer:
-    """Stateful streaming front-end over one or more ``Accelerator``
-    sessions (see the module docstring for the deployment shape).
+    """Stateful streaming front-end over one ``Accelerator`` session (see
+    the module docstring for the deployment shape).
 
     Results are delivered through :meth:`poll` / :meth:`drain` as
     :class:`StreamResult` rows; padded slots of partial waves are computed
-    and dropped — they are never emitted and never touch the state store."""
+    and dropped — they are never emitted and their carries go to the
+    table's TRASH row."""
 
-    def __init__(self, sessions, config: Optional[ServingConfig] = None,
+    def __init__(self, session, config: Optional[ServingConfig] = None,
                  fault_injector: Optional[FaultInjector] = None,
                  **overrides):
-        """``sessions``: one ``Accelerator`` or a list of replicas of the
-        same configuration (waves round-robin across them).  ``config`` or
-        keyword overrides (``batch=``, ``deadline_s=``, ...) set the
-        :class:`ServingConfig`.  ``fault_injector`` (tests/chaos drills
-        only) wraps the execute path and the state store with a seeded
-        fault schedule — see ``repro.serving.faults``."""
-        sessions = list(sessions) if isinstance(sessions, (list, tuple)) \
-            else [sessions]
-        if not sessions:
-            raise ValueError("need at least one Accelerator session")
-        for s in sessions[1:]:
-            if s.model != sessions[0].model:
-                raise ValueError(
-                    "all sessions must be replicas of one configuration; "
-                    f"got models {s.model} != {sessions[0].model}")
-            if not _params_equal(s.params, sessions[0].params):
-                # Same config but different weights would round-robin waves
-                # across bit-incompatible models (and cross-pollinate their
-                # carries through the shared state store).
-                raise ValueError(
-                    "all sessions must be replicas sharing one set of "
-                    "weights; the given sessions' params differ")
+        """``session``: the quantised ``Accelerator`` to serve (replicas go
+        behind a ``ClusterServer``).  ``config`` or keyword overrides
+        (``batch=``, ``deadline_s=``, ...) set the :class:`ServingConfig`.
+        ``fault_injector`` (tests/chaos drills only) wraps the execute path
+        and the carry table with a seeded fault schedule — see
+        ``repro.serving.faults``."""
+        if isinstance(session, (list, tuple)):
+            raise ValueError(
+                "StreamServer serves one Accelerator session; put replicas "
+                "behind a ClusterServer, which routes each stream to one "
+                "replica and its carry table")
         cfg = config or ServingConfig()
         if overrides:
             cfg = dataclasses.replace(cfg, **overrides)
         self.config = cfg
-        self._sessions = sessions
+        self._session = session
         self.fault_injector = fault_injector
         # Resolve the degradation ladder and validate NOW: a bad
         # path/backend or an unquantised session fails at construction,
@@ -279,64 +226,31 @@ class StreamServer:
         # (_compile_preferred); jit closures are lazy, so non-preferred
         # ladder levels cost nothing until a degradation runs them.
         from repro import backends as _backends
-        #: Resolved carry placement: "device" | "host" on a stateful
-        #: server, None on a stateless one (ServingConfig.state_residency
-        #: documents the knob; auto follows plan()["state_residency"]).
-        self.state_residency: Optional[str] = None
-        if cfg.stateful:
+        if cfg.path == "int":
             ladder = _backends.degradation_ladder(
-                sessions[0].model, sessions[0].accel, override=cfg.backend,
-                stateful=True)
-            residency = cfg.state_residency
-            if residency == "auto":
-                residency = ("device" if ladder[0] == "pallas"
-                             and len(sessions) == 1 else "host")
-            elif residency == "device" and len(sessions) > 1:
-                # One table lives on one device; replicas round-robining
-                # waves into private tables would shear a stream's carry
-                # across them.  Sharding streams across per-replica tables
-                # is ClusterServer's job (consistent routing).
-                raise ValueError(
-                    "state_residency='device' requires a single session; "
-                    "use ClusterServer to shard streams across replicas, "
-                    "each with its own device-resident table")
-            self.state_residency = residency
-            if residency == "device":
-                self._fns = [[(n, s.compiled_stateful_slots(n))
-                              for n in ladder] for s in sessions]
-            else:
-                self._fns = [[(n, s.compiled_stateful(n)) for n in ladder]
-                             for s in sessions]
-        elif cfg.path == "int":
-            ladder = _backends.degradation_ladder(
-                sessions[0].model, sessions[0].accel, override=cfg.backend,
-                stateful=False)
-            self._fns = [[(n, s.compiled(cfg.path, n)) for n in ladder]
-                         for s in sessions]
+                session.model, session.accel, override=cfg.backend,
+                stateful=cfg.stateful)
+            fns = [(n, session.compiled_stateful_slots(n) if cfg.stateful
+                    else session.compiled(cfg.path, n)) for n in ladder]
         else:
             # float/qat run one plan-resolved graph; the ladder is trivial
             # but the guard's retry/timeout protection still applies.
             ladder = (cfg.path,)
-            self._fns = [[(cfg.path, s.compiled(cfg.path, cfg.backend))]
-                         for s in sessions]
-        self._preferred = [per_session[0][1] for per_session in self._fns]
+            fns = [(cfg.path, session.compiled(cfg.path, cfg.backend))]
+        self._preferred = fns[0][1]
         if fault_injector is not None:
-            self._fns = [[(n, fault_injector.wrap_fn(fn, label=n))
-                          for n, fn in per_session]
-                         for per_session in self._fns]
+            fns = [(n, fault_injector.wrap_fn(fn, label=n)) for n, fn in fns]
+        # The ladder the guard runs, held as a list of one ladder: the
+        # benchmark's fault checks (benchmarks/chip/tests/test_faults.py)
+        # wrap its engines in that shape.
+        self._fns = [fns]
         self.guard = ExecutionGuard(ladder, cfg.resilience)
-        if not cfg.stateful:
-            self.states = None
-        elif self.state_residency == "device":
-            from repro.serving.device_state import DeviceStateStore
-            self.states = DeviceStateStore(sessions[0], cfg.max_streams)
+        self.states: Optional[DeviceStateStore] = None
+        if cfg.stateful:
+            self.states = DeviceStateStore(session, cfg.max_streams)
             if fault_injector is not None:
                 self.states = fault_injector.wrap_device_state_store(
                     self.states)
-        else:
-            self.states = StateStore(cfg.max_streams)
-            if fault_injector is not None:
-                self.states = fault_injector.wrap_state_store(self.states)
         self.metrics = MetricsSink()
         self._results: "queue.Queue" = queue.Queue(
             maxsize=cfg.max_results or 0)
@@ -350,7 +264,6 @@ class StreamServer:
         self._outstanding: Dict[Hashable, int] = {}
         self._seq_lock = threading.Lock()
         self._window_shape = None
-        self._rr = 0
         self._sched = WaveScheduler(
             cfg.batch, self._execute, one_per_stream=cfg.stateful,
             deadline_s=cfg.deadline_s, queue_depth=cfg.queue_depth,
@@ -382,7 +295,7 @@ class StreamServer:
         if w.ndim != 2:
             raise ValueError(
                 f"window must be a (T, M) array, got shape {w.shape}")
-        m = self._sessions[0].model.input_size
+        m = self._session.model.input_size
         if w.shape[0] < 1 or w.shape[1] != m:
             raise ValueError(
                 f"window shape {w.shape} does not match the model's "
@@ -417,7 +330,7 @@ class StreamServer:
         return self._sched.submit(stream_id, w, alloc_seq)
 
     def _compile_preferred(self, window_shape: Tuple[int, ...]) -> None:
-        """Compile every session's preferred engine for waves of
+        """Compile the preferred engine for waves of
         ``(batch, *window_shape)``, outside the ``ExecutionGuard``.  A
         kernel the compiler refuses raises here, to the first submitter:
         the guard degrades past runtime faults, and a program that cannot
@@ -425,21 +338,17 @@ class StreamServer:
         program."""
         b = self.config.batch
         x = jnp.zeros((b, *window_shape), jnp.float32)
-        for sess, fn in zip(self._sessions, self._preferred):
-            if self.state_residency == "device":
-                slots = jnp.zeros((b,), jnp.int32)
-                args = (x, self.states.table, slots, slots)
-            elif self.config.stateful:
-                args = (x, sess.init_state(b))
-            else:
-                args = (x,)
-            try:
-                fn.lower(*args).compile()
-            except Exception as e:
-                raise RuntimeError(
-                    f"the preferred engine {self.guard.ladder[0]!r} failed "
-                    f"to compile for waves of shape {x.shape}: "
-                    f"{type(e).__name__}: {e}") from e
+        args = (x,)
+        if self.states is not None:
+            slots = jnp.zeros((b,), jnp.int32)
+            args = (x, self.states.table, slots, slots)
+        try:
+            self._preferred.lower(*args).compile()
+        except Exception as e:
+            raise RuntimeError(
+                f"the preferred engine {self.guard.ladder[0]!r} failed "
+                f"to compile for waves of shape {x.shape}: "
+                f"{type(e).__name__}: {e}") from e
 
     def poll(self, timeout: float = 0.0) -> List[StreamResult]:
         """Completed predictions, in wave order (per-stream order is always
@@ -487,9 +396,10 @@ class StreamServer:
         client ids should call it.
 
         Safe against in-flight windows: carries of windows submitted
-        before this call are never re-stored (a tombstone watermark makes
-        the compute thread skip their scatter), so a window submitted
-        AFTER the call is guaranteed the zero reset carry."""
+        before this call are never re-stored (a tombstone watermark sends
+        their scatter to the TRASH row, and a wave already running writes
+        a released slot), so a window submitted AFTER the call is
+        guaranteed the zero reset carry."""
         if self.states is None:
             with self._seq_lock:
                 self._seq.pop(stream_id, None)
@@ -502,15 +412,16 @@ class StreamServer:
             if self._outstanding.get(stream_id, 0) > 0:
                 self._ended[stream_id] = max(watermark,
                                              self._ended.get(stream_id, 0))
-            # Inside the lock: _scatter holds it across its tombstone check
-            # AND its states.put, so the pop here cannot interleave with a
-            # put and erase a reborn stream's carry (or miss a stale one).
+            # Inside the lock: _gather_slots holds it across its tombstone
+            # checks AND its slot assignments, so the release here cannot
+            # interleave with an assignment and free a reborn stream's slot
+            # (or miss a stale one).
             self.states.pop(stream_id)
 
     def reset_streams(self) -> None:
         """Forget EVERY stream — carries and sequence numbering — without
-        tearing down the server: threads, compiled sessions, and (on the
-        device path) the resident slot table all survive, so the next
+        tearing down the server: threads, the compiled session and the
+        resident slot table all survive, so the next
         window is served by a warm datapath from a zero carry.
 
         This is the scenario harness's short-run reset
@@ -532,19 +443,14 @@ class StreamServer:
     def read_stream_state(self, stream_id: Hashable):
         """A host-side copy of a stream's carry (per layer, a tuple of the
         cell's ``state_arity`` int32 rows — ``[(h, c), ...]`` for the
-        LSTM), or ``None`` when the server holds none.  On a
-        device-resident server this is the one sanctioned state read-back,
-        meant for PLANNED stream movement (``ClusterServer`` drain) — not
-        for the hot path.  Call only with the stream quiescent (no windows
-        in flight), e.g. after ``flush()``."""
+        LSTM), or ``None`` when the server holds none.  This is the one
+        sanctioned read-back from the carry table, meant for PLANNED
+        stream movement (``ClusterServer`` drain) — not for the hot path.
+        Call only with the stream quiescent (no windows in flight), e.g.
+        after ``flush()``."""
         if self.states is None:
             return None
-        if self.state_residency == "device":
-            return self.states.read_state(stream_id)
-        st = self.states.get(stream_id)
-        if st is None:
-            return None
-        return [tuple(a.copy() for a in layer) for layer in st]
+        return self.states.read_state(stream_id)
 
     def seed_stream_state(self, stream_id: Hashable, state) -> None:
         """Plant a carry for ``stream_id`` (same per-layer carry-tuple
@@ -557,13 +463,7 @@ class StreamServer:
         if self.states is None:
             raise ValueError("cannot seed state on a stateless server")
         with self._seq_lock:
-            if self.state_residency == "device":
-                evicted = set(self.states.seed_state(stream_id, state))
-            else:
-                evicted = set(self.states.put(
-                    stream_id,
-                    [tuple(np.asarray(a).copy() for a in layer)
-                     for layer in state]))
+            evicted = set(self.states.seed_state(stream_id, state))
         self._reconcile_evictions(evicted)
 
     def close(self, abandon: bool = False,
@@ -594,15 +494,13 @@ class StreamServer:
 
     def metrics_summary(self) -> Dict:
         """The serving report: achieved samples/s, per-wave latency
-        p50/p95/p99, occupancy, deadline flushes, state-store counters, and
+        p50/p95/p99, occupancy, deadline flushes, carry-table counters, and
         the energy model's GOP/s/W at the MEASURED operating point (mean
         wave compute latency, mean occupancy) — the paper's Table-4 metric
         evaluated where the server actually runs."""
         s = self.metrics.summary()
         s["stateful"] = self.config.stateful
-        s["sessions"] = len(self._sessions)
         s["state"] = self.states.stats() if self.states is not None else None
-        s["state_residency"] = self.state_residency
         g = self.guard.stats()
         sched = self._sched.stats()
         counters = self.metrics.counters()
@@ -624,21 +522,18 @@ class StreamServer:
             "injected": (self.fault_injector.stats()
                          if self.fault_injector is not None else None),
         }
-        # Per-wave host<->device state traffic: the device-residency win is
-        # to_device/from_device pinned at 0 while only slot ids travel.
+        # Per-wave state traffic: only slot ids travel.  Then the waves
+        # whose new table is the old one's buffer, waves whose is not, and
+        # failed waves that lost it.
         s["state_transfer"] = {
-            "to_device_bytes": counters.get("state_bytes_to_device", 0),
-            "from_device_bytes": counters.get("state_bytes_from_device", 0),
             "slot_id_bytes": counters.get("slot_id_bytes", 0),
-            # Device residency: waves whose new table is the old one's
-            # buffer, waves whose is not, and failed waves that lost it.
             "table_in_place": counters.get("table_in_place", 0),
             "table_copied": counters.get("table_copied", 0),
             "table_losses": counters.get("table_losses", 0),
         }
         s["health"] = self.health()
         if s["waves"]:
-            sess = self._sessions[0]
+            sess = self._session
             occupancy = max(1, round(s["mean_occupancy"]))
             rep = sess.report(latency_s=s["compute_ms_mean"] / 1e3,
                               batch=occupancy)
@@ -678,16 +573,15 @@ class StreamServer:
             "deadline_miss_rate": sched["deadline_miss_rate"],
             "live_streams": (len(self.states)
                              if self.states is not None else None),
-            "state_residency": self.state_residency,
             "leaked_threads": list(self._sched.leaked_threads),
         }
 
     # -- compute thread -----------------------------------------------------
 
     def _execute(self, wave: Wave) -> None:
-        """Gather carries -> GUARDED device datapath -> scatter carries ->
+        """Assign slots -> GUARDED device datapath -> commit the table ->
         emit.  Runs on the scheduler's compute thread, waves strictly in
-        order — which is what makes the gather/scatter of consecutive
+        order — which is what makes the slot transactions of consecutive
         windows of one stream consistent.
 
         The guard absorbs engine failures (retry, backoff, degradation
@@ -699,34 +593,28 @@ class StreamServer:
         wave's :class:`WaveRecord` (``slots`` and ``commit`` only on a
         stateful server); ``compute_s`` spans every stage before
         ``emit``."""
-        sess_idx = self._rr % len(self._fns)
-        fns = self._fns[sess_idx]
-        self._rr += 1
+        fns = self._fns[0]
         clock = _StageClock(wave.id)
         t0 = time.perf_counter()
-        device_state = self.state_residency == "device"
-        if self.config.stateful:
+        stateful = self.config.stateful
+        if stateful:
             with clock("slots"):
-                if device_state:
-                    # Slot path: the carries never leave the table — only
-                    # two (B,) int32 slot-id vectors cross to the device.
-                    # The allocator transaction (lookup + assign +
-                    # tombstone checks) happens BEFORE compute, so faults
-                    # can only strand slots, never corrupt the
-                    # allocator<->table correspondence.
-                    g, s, reset, rows, evicted = self._gather_slots(wave)
-                    self.metrics.count("slot_id_bytes",
-                                       int(g.nbytes + s.nbytes))
-                else:
-                    gathered, reset = self._gather(wave)
+                # The carries never leave the table — only two (B,) int32
+                # slot-id vectors cross to the device.  The allocator
+                # transaction (lookup + assign + tombstone checks) happens
+                # BEFORE compute, so faults can only strand slots, never
+                # corrupt the allocator<->table correspondence.
+                g, s, reset, rows, evicted = self._gather_slots(wave)
+                self.metrics.count("slot_id_bytes",
+                                   int(g.nbytes + s.nbytes))
         else:
             reset = [False] * len(wave.slots)
         with clock("h2d"):
             x = jnp.asarray(wave.x)
-            if device_state:
+            if stateful:
                 slot_ids = (jnp.asarray(g), jnp.asarray(s))
         with clock("call"):
-            if device_state:
+            if stateful:
                 # The wave program donates the table: the store lends it
                 # here and gets a table back at commit, or at restore when
                 # the wave fails.  No attempt runs on a donated table.
@@ -735,37 +623,32 @@ class StreamServer:
                 outcome = self.guard.run(
                     fns, x, table, *slot_ids,
                     alive=lambda: not table.is_deleted())
-            elif self.config.stateful:
-                outcome = self.guard.run(fns, x, gathered)
             else:
                 outcome = self.guard.run(fns, x)
         if not outcome.ok:
-            if device_state:
+            if stateful:
                 self._return_table(table)
-            self._fail_wave(wave, outcome, t0, sess_idx)
-            if device_state:
+            self._fail_wave(wave, outcome, t0)
+            if stateful:
                 # Slot assignment (and any LRU evictions) happened before
                 # compute; the victims are still gone even though the
                 # wave's table update was discarded.
                 self._reconcile_evictions(evicted)
             return
         with clock("ready"):
-            y, new_state = (outcome.value if self.config.stateful
+            y, new_table = (outcome.value if stateful
                             else (outcome.value, None))
             try:
                 y = np.asarray(y)
             except BaseException:
                 # A device error surfaces here, after the dispatch: the
                 # donated table went with the failed program.
-                if device_state:
+                if stateful:
                     self._return_table(table)
                 raise
-        if self.config.stateful:
+        if stateful:
             with clock("commit"):
-                if device_state:
-                    self._commit_table(table, before, new_state, rows)
-                else:
-                    evicted = self._scatter(wave, new_state)
+                self._commit_table(table, before, new_table, rows)
                 self._retire(wave)
                 self._reconcile_evictions(evicted)
                 n_reset = sum(reset)
@@ -790,7 +673,6 @@ class StreamServer:
                 self._emit(StreamResult(slot.stream_id, slot.seq, y[i],
                                         state_reset=reset[i],
                                         backend=outcome.backend,
-                                        routed_replica=sess_idx,
                                         wave=wave.id))
 
     def _commit_table(self, table, before: int, new_table,
@@ -813,8 +695,7 @@ class StreamServer:
         if self.states.restore(table):
             self.metrics.count("table_losses")
 
-    def _fail_wave(self, wave: Wave, outcome, t0: float,
-                   sess_idx: int) -> None:
+    def _fail_wave(self, wave: Wave, outcome, t0: float) -> None:
         """Every ladder engine failed this wave: isolate the damage to the
         wave's own streams.  Their carries are dropped (a window was lost,
         so continuing from the pre-wave carry would be a silent gap — the
@@ -836,8 +717,7 @@ class StreamServer:
             t_built=wave.t_built, t_start=t0))
         for slot in wave.slots:
             self._emit(StreamResult(slot.stream_id, slot.seq, None,
-                                    error=err, routed_replica=sess_idx,
-                                    wave=wave.id))
+                                    error=err, wave=wave.id))
 
     def _shed(self, slot: Slot) -> None:
         """Scheduler shed callback (assembler thread): the window was
@@ -867,42 +747,18 @@ class StreamServer:
                 if self._sched.stopped:
                     return
 
-    def _gather(self, wave: Wave):
-        """Per-layer carry batch arrays for the wave (the cell's
-        ``state_arity`` arrays per layer — (h, c) for the LSTM): stored
-        carries for known streams, the zero reset state for new/evicted
-        streams and padding rows.  Also returns per-slot ``state_reset``
-        flags: True when a stream WITH HISTORY (seq > 0) found no carry —
-        it was evicted, lost, or dropped by a failed wave, and its result
-        must say so instead of silently continuing from zeros."""
-        nl, arity, hidden = self._sessions[0].plan["state_shape"]
-        bufs = [[np.zeros((self.config.batch, hidden), np.int32)
-                 for _ in range(arity)] for _ in range(nl)]
-        reset = [False] * len(wave.slots)
-        for i, slot in enumerate(wave.slots):
-            st = self.states.get(slot.stream_id)
-            if st is not None:
-                for li, layer_carry in enumerate(st):
-                    for s, arr in enumerate(layer_carry):
-                        bufs[li][s][i] = arr
-            elif slot.seq > 0:
-                reset[i] = True
-        state = tuple(tuple(jnp.asarray(a) for a in layer)
-                      for layer in bufs)
-        self.metrics.count("state_bytes_to_device",
-                           sum(int(a.nbytes) for layer in state
-                               for a in layer))
-        return state, reset
-
     def _gather_slots(self, wave: Wave):
-        """The device-residency counterpart of :meth:`_gather` +
-        :meth:`_scatter`'s bookkeeping, run BEFORE compute: one allocator
+        """The wave's carry bookkeeping, run BEFORE compute: one allocator
         transaction under ``_seq_lock`` producing the wave's slot-id
         vectors.  Returns ``(gather, scatter, reset, rows, evicted)``:
 
         * ``gather[i]``: table row whose carry seeds batch row ``i`` at
           t == 0 — the stream's slot, or ZERO for new/evicted streams and
-          padding (``reset[i]`` is flagged exactly like :meth:`_gather`);
+          padding;
+        * ``reset[i]``: True when a stream WITH HISTORY (seq > 0) found no
+          slot — it was evicted, lost, or dropped by a failed wave, and
+          its result must say so instead of silently continuing from
+          zeros;
         * ``scatter[i]``: row for the final carry at t == T-1 — the
           stream's (possibly new) slot, or TRASH for padding, tombstoned
           windows, and same-wave eviction victims;
@@ -910,10 +766,9 @@ class StreamServer:
           unit the fault injector draws per-put faults over;
         * ``evicted``: ids LRU-evicted by this wave's assignments.
 
-        The two phases replay the host path's store-op order — every
-        lookup (get), then every assignment (put) in batch-row order — so
-        hit/miss/eviction counters and any injected fault schedule match
-        the host store draw for draw."""
+        The two phases run every lookup, then every assignment in
+        batch-row order, so the hit/miss/eviction counters and any
+        injected fault schedule are a function of the waves alone."""
         store = self.states
         batch = self.config.batch
         g = np.full(batch, store.zero_slot, dtype=np.int32)
@@ -942,59 +797,27 @@ class StreamServer:
                 if j is not None:
                     # An earlier row of THIS wave was assigned this slot
                     # and its stream was just LRU-evicted (batch >
-                    # capacity): its carry would be dropped by the host
-                    # store too — redirect its dead scatter to TRASH.
+                    # capacity): its carry is dropped — redirect its dead
+                    # scatter to TRASH.
                     s[j] = store.trash_slot
                 row_of_slot[sl] = i
                 s[i] = sl
                 rows.append((i, sid))
         return g, s, reset, rows, evicted_all
 
-    def _scatter(self, wave: Wave, new_state) -> set:
-        """Store each real slot's updated carry; returns the ids evicted by
-        the wave's puts (reconciled by :meth:`_reconcile_evictions` after
-        :meth:`_retire`).  Padding rows are dropped (they never touch the
-        store); so are carries tombstoned by ``end_stream`` — windows
-        submitted before the end must not resurrect the stream's state."""
-        rows = [tuple(np.asarray(a) for a in layer) for layer in new_state]
-        self.metrics.count("state_bytes_from_device",
-                           sum(int(a.nbytes) for layer in rows
-                               for a in layer))
-        evicted_all = set()
-        for i, slot in enumerate(wave.slots):
-            sid = slot.stream_id
-            # One lock section spans the tombstone check AND the put: an
-            # end_stream between them could otherwise be silently undone
-            # by the put, resurrecting the ended stream's carry.  The
-            # store's own lock never takes _seq_lock, so no cycle.
-            with self._seq_lock:
-                watermark = self._ended.get(sid)
-                if watermark is not None:
-                    if slot.sub_idx < watermark:
-                        continue       # ended-generation carry: drop it
-                    del self._ended[sid]   # stream reborn after the end
-                # copy(): a view of row i would pin the whole
-                # (batch, hidden) wave array in the store for the stream's
-                # lifetime.
-                evicted_all.update(
-                    self.states.put(sid, [tuple(a[i].copy() for a in layer)
-                                          for layer in rows]))
-        return evicted_all
-
     def _reconcile_evictions(self, evicted: set) -> None:
         """An evicted stream is forgotten ENTIRELY — carry and sequence
         numbering — so a returning client looks like a new stream (and a
-        stateful server's _seq cannot grow without bound; state.py's
-        docstring scenario is millions of users).  Runs after
+        stateful server's _seq cannot grow without bound).  Runs after
         :meth:`_retire`, and only prunes a victim that is really gone:
 
-        * a victim that was a LATER slot of the evicting wave re-stored
-          its (correctly continued) carry — never really evicted, keeps
-          its numbering;
+        * a victim that was a LATER slot of the evicting wave was assigned
+          a slot again and stored its (correctly continued) carry — never
+          really evicted, keeps its numbering;
         * a victim with windows still in flight keeps its numbering too —
-          its pending window's scatter will re-store its carry before any
-          later wave gathers it (waves compute strictly in order), so
-          pruning here would hand out duplicate (stream_id, seq) keys."""
+          its pending window will be assigned a slot again (from the
+          flagged reset carry), so pruning here would hand out duplicate
+          (stream_id, seq) keys."""
         with self._seq_lock:
             for vid in evicted:
                 if vid not in self.states \

@@ -3,10 +3,10 @@
 Every registered cell — lstm, gru, rglru — must pass the SAME battery
 shape that locked in the LSTM: bit-exact ref<->xla int-path parity across
 fixed-point widths x HardSigmoid* methods x 1-3 layers, and
-windowed-vs-concatenated bit-exactness through ``StreamServer`` (host
-residency AND the device-resident slot table, which non-LSTM cells reach
-through the XLA-level slot adapter).  Plus the registry/plan surfaces the
-serving and explore layers dispatch on."""
+windowed-vs-concatenated bit-exactness through ``StreamServer`` on the
+device-resident slot table (which non-LSTM cells reach through the
+XLA-level slot adapter), on the ladder's head and on ``ref``.  Plus the
+registry/plan surfaces the serving and explore layers dispatch on."""
 
 import dataclasses
 
@@ -171,15 +171,13 @@ def test_plan_carries_cell_and_state_shape():
 
 
 def test_auto_backend_per_cell():
-    """LSTM keeps the fused kernel; cells without one resolve to xla (and
-    therefore to host state residency)."""
+    """LSTM keeps the fused kernel; cells without one resolve to xla."""
     p = plan(_model("lstm"), AcceleratorConfig())
-    assert p["backend"] == "pallas" and p["state_residency"] == "device"
+    assert p["backend"] == "pallas" and p["stateful_backend"] == "pallas"
     for cell in NON_FUSED_CELLS:
         p = plan(_model(cell), AcceleratorConfig())
         assert p["backend"] == "xla"
         assert p["stateful_backend"] == "xla"
-        assert p["state_residency"] == "host"
 
 
 def test_pallas_refuses_cells_without_fused_kernel():
@@ -210,37 +208,38 @@ def test_report_runs_per_cell():
 
 
 # ---------------------------------------------------------------------------
-# Serving: windowed-vs-concatenated through StreamServer, both residencies
+# Serving: windowed-vs-concatenated through StreamServer, on two rungs
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("residency", ["host", "device"])
+@pytest.mark.parametrize("backend", [None, "ref"], ids=["device", "ref"])
 @pytest.mark.parametrize("cell", CELLS)
-def test_stream_server_carry_equals_concatenated(cell, residency):
+def test_stream_server_carry_equals_concatenated(cell, backend):
     """The serving contract, per cell: feeding a stream window-by-window
     through StreamServer is bit-identical to one shot over the
-    concatenated sequence — on the host LRU store AND on the
-    device-resident slot table (which GRU/rGLRU reach through the
-    XLA-level slot adapter, their documented device path)."""
-    from repro.serving import StreamServer
+    concatenated sequence — from the device-resident slot table, on the
+    ladder's head (the fused kernel for the LSTM, the XLA-level slot
+    adapter for GRU/rGLRU) and on the ``ref`` rung."""
+    from repro.serving import DeviceStateStore, StreamServer
     m = _model(cell)
     sess = repro.build(m, seed=6).quantize()
     k, t = 3, m.seq_len
     streams = {f"s{i}": _x(t=k * t, seed=20 + i)[0] for i in range(3)}
     with StreamServer(sess, batch=2, deadline_s=0.005, max_streams=8,
-                      state_residency=residency) as srv:
-        assert srv.state_residency == residency
+                      backend=backend) as srv:
+        assert isinstance(srv.states, DeviceStateStore)
+        head = srv.guard.ladder[0]
         for w in range(k):
             for sid, xs in streams.items():
                 srv.submit(sid, xs[w * t:(w + 1) * t])
         results = srv.drain()
     by = {}
     for r in results:
-        assert r.error is None
+        assert r.error is None and r.backend == head
         by.setdefault(r.stream_id, {})[r.seq] = r.y
     for sid, xs in streams.items():
         full = np.asarray(sess.infer(jnp.asarray(xs[None]), path="int"))
         np.testing.assert_array_equal(by[sid][k - 1], full[0],
-                                      err_msg=f"{cell}@{residency}:{sid}")
+                                      err_msg=f"{cell}@{head}:{sid}")
 
 
 @pytest.mark.parametrize("cell", NON_FUSED_CELLS)
@@ -272,8 +271,8 @@ def test_stream_state_read_seed_roundtrip(cell):
 # ---------------------------------------------------------------------------
 
 def test_explore_cell_axis():
-    # cell sits between the Table-2 axes and the PR-10 serving axes
-    assert explore.AXES[-3:] == ("cell", "replicas", "state_residency")
+    # cell sits between the Table-2 axes and the serving axis
+    assert explore.AXES[-2:] == ("cell", "replicas")
     space = explore.SearchSpace(cell=("lstm", "gru"))
     assert space.size == 2
     labels = [p.label for p in space.grid()]

@@ -19,8 +19,9 @@ import pytest
 
 import repro
 from repro.core.qlstm import QLSTMConfig
-from repro.serving import (ClusterConfig, ClusterServer, HashRing,
-                           MetricsSink, OverloadPolicy, ServerOverloaded)
+from repro.serving import (ClusterConfig, ClusterServer, DeviceStateStore,
+                           HashRing, MetricsSink, OverloadPolicy,
+                           ServerOverloaded)
 from repro.serving.metrics import WaveRecord
 
 MODEL = QLSTMConfig(input_size=1, hidden_size=8, num_layers=2, seq_len=4)
@@ -330,16 +331,15 @@ def test_cluster_overload_propagates_replica_name(sess):
 
 
 def test_cluster_remove_replica_moves_only_its_streams(sess):
-    """Drain/rebalance with HOST-resident state (pinned — device residency
-    upgrades the drain to a warm handoff, covered separately below): the
-    ring shrink moves ONLY the removed replica's streams (~K/N); each
-    restarts at its new home with seq 0 and ``state_reset=True``
-    provenance, and its post-move prediction equals a fresh stream's (the
-    carry really did reset).  Unmoved streams keep replica, numbering,
-    and carry."""
+    """Drain/rebalance on the replicas' device tables: the ring shrink
+    moves ONLY the removed replica's streams (~K/N of K); each lands on
+    another replica with its numbering restarted.  Unmoved streams keep
+    replica, numbering, and carry: their next prediction continues the
+    concatenated sequence bit-exactly.  (What a moved stream's carry does
+    — the warm handoff — is pinned by the next test.)"""
     k = 2
-    streams = {f"d{i}": _windows(k + 1, seed=40 + i) for i in range(8)}
-    with _cluster(sess, 3, state_residency="host") as cluster:
+    streams = {f"d{i}": _windows(k + 1, seed=40 + i) for i in range(24)}
+    with _cluster(sess, 3) as cluster:
         for w in range(k):
             for sid, xs in streams.items():
                 cluster.submit(sid, xs[w])
@@ -349,6 +349,7 @@ def test_cluster_remove_replica_moves_only_its_streams(sess):
         moved = cluster.remove_replica(victim)
         assert sorted(moved) == sorted(
             s for s, r in before.items() if r == victim)
+        assert 0 < len(moved) < len(streams) * 2 / 3   # ~K/N, never all
         assert victim not in cluster.replicas
         for sid, xs in streams.items():
             cluster.submit(sid, xs[k])
@@ -357,12 +358,10 @@ def test_cluster_remove_replica_moves_only_its_streams(sess):
         t = MODEL.seq_len
         for sid, xs in streams.items():
             r = by[sid]
+            assert r.ok, sid
             if sid in moved:
-                assert r.seq == 0 and r.state_reset
-                assert r.routed_replica != victim
-                fresh = np.asarray(sess.infer(
-                    jnp.asarray(xs[k].reshape(1, t, 1)), path="int"))
-                np.testing.assert_array_equal(r.y, fresh[0])
+                assert r.seq == 0 and r.routed_replica != victim
+                assert r.routed_replica == cluster.replica_for(sid)
             else:
                 assert r.seq == k and not r.state_reset
                 assert r.routed_replica == before[sid]
@@ -374,16 +373,15 @@ def test_cluster_remove_replica_moves_only_its_streams(sess):
 
 
 def test_cluster_remove_replica_warm_handoff_device_residency(sess):
-    """Satellite acceptance: with DEVICE-resident state (the default —
-    ``auto`` resolves to the slot table on this pallas plan) a planned
-    drain upgrades to a WARM handoff.  The ring shrink still moves
+    """A planned drain is a WARM handoff between the replicas' device
+    tables.  The ring shrink moves
     exactly the victim's streams, but each moved carry is read back from
     the dying replica's slot table and seeded into the stream's new ring
     home — the destination's read-back rows must reproduce the ref
     oracle's threaded state — so the stream's next window continues the
     recurrence bit-exactly against the concatenated oracle: per-replica
     seq restarts at 0 with NO ``state_reset`` flag.  Unmoved streams
-    keep replica, numbering, and carry, exactly as on the cold path."""
+    keep replica, numbering, and carry."""
     k, t = 2, MODEL.seq_len
     streams = {f"w{i}": _windows(k + 1, seed=60 + i) for i in range(8)}
     ref = sess.compiled_stateful("ref")
@@ -395,7 +393,7 @@ def test_cluster_remove_replica_warm_handoff_device_residency(sess):
         return state
 
     with _cluster(sess, 3) as cluster:
-        assert all(s.state_residency == "device"
+        assert all(isinstance(s.states, DeviceStateStore)
                    for s in cluster._servers.values())
         for w in range(k):
             for sid, xs in streams.items():
@@ -436,9 +434,9 @@ def test_cluster_remove_replica_warm_handoff_device_residency(sess):
 
 
 def test_cluster_remove_replica_abandon_skips_handoff(sess):
-    """``abandon=True`` on a device-residency drain: the replica died, so
-    there is nothing to read back — moved streams restart COLD at their
-    new home with the flagged reset, the cold path's contract."""
+    """``abandon=True`` on a drain: the replica died, so there is nothing
+    to read back — moved streams restart COLD at their new home with the
+    flagged reset."""
     k = 1
     streams = {f"a{i}": _windows(k + 1, seed=80 + i) for i in range(8)}
     with _cluster(sess, 3) as cluster:

@@ -408,19 +408,20 @@ def test_property_constrained_front_respects_slo(pts, bound):
 # ---------------------------------------------------------------------------
 
 def test_space_gains_serving_axes_and_labels():
-    assert "replicas" in explore.AXES and "state_residency" in explore.AXES
+    assert explore.AXES[-1] == "replicas"
     sp = explore.SearchSpace(backend="xla", batch=4, replicas=(1, 2),
-                             state_residency=("auto", "host"))
+                             cell=("lstm", "gru"))
     labels = {p.label for p in sp.grid()}
     assert len(labels) == 4
-    assert any(lab.endswith("_r2_host") for lab in labels)
+    assert any(lab.endswith("_gru_r2") for lab in labels)
     base = next(iter(explore.SearchSpace(backend="xla", batch=4).grid()))
-    assert "_r" not in base.label and not base.label.endswith("_host")
+    assert "_r" not in base.label and base.label.endswith("_xla")
     from repro.explore.space import point_from_config
     for p in sp.grid():
         assert point_from_config(p.asdict()) == p
-    with pytest.raises(ValueError, match="state_residency"):
-        explore.SearchSpace(state_residency=("gpu",))
+    import dataclasses
+    assert tuple(f.name for f in dataclasses.fields(explore.SearchSpace)) \
+        == explore.AXES + ("constraints",)   # no axis beyond AXES
     with pytest.raises(ValueError, match="positive ints"):
         explore.SearchSpace(replicas=(0,))
 
@@ -438,7 +439,6 @@ def test_prune_and_serving_plan_agree_across_the_axes():
                              batch=4, hidden_size=8,
                              cell=("lstm", "gru", "rglru"),
                              replicas=(1, 3),
-                             state_residency=("auto", "host", "device"),
                              alu_mode=("pipelined", "per_step"))
     checked = 0
     for p in sp.grid():
@@ -451,18 +451,17 @@ def test_prune_and_serving_plan_agree_across_the_axes():
         if reason is None:
             assert planned is None, (p.label, planned)
             assert pl["replicas"] == p.replicas
-            assert pl["state_residency"] in ("host", "device")
+            assert pl["stateful_backend"] in ("ref", "xla", "pallas")
         else:
             assert planned is not None, (p.label, reason)
             # same rule fired, modulo the declarative/imperative prefix
             decl = reason.split(":", 1)[0]
             imp = planned.split(":", 1)[0]
             assert {("backend_supported", "backend"),
-                    ("device_residency", "state_residency"),
                     ("replicas_fit_devices", "replicas")} >= {(decl, imp)} \
                 or decl.startswith(imp) or imp in decl, (decl, imp)
         checked += 1
-    assert checked == sp.size == 4 * 3 * 2 * 3 * 2
+    assert checked == sp.size == 4 * 3 * 2 * 2
 
 
 def test_constraint_node_composition_operators():
@@ -481,15 +480,15 @@ def test_constraint_node_composition_operators():
 
 
 def test_sweep_all_infeasible_records_front_reason_no_builds():
-    # device residency on a cell with no fused kernel: every point pruned
-    # before measurement; the sweep reports WHY the front is empty.
-    space = explore.SearchSpace(backend="xla", batch=4, cell="gru",
-                                state_residency="device")
+    # the fused pallas engine on a cell with no fused kernel: every point
+    # pruned before measurement; the sweep reports WHY the front is empty.
+    space = explore.SearchSpace(backend="pallas", batch=4, cell="gru")
     payload = explore.sweep(space, scenario=explore.ServingScenario(
         streams=2, windows_per_stream=1), strategy="full")
     (row,) = payload["points"]
-    assert row["status"] == "infeasible"
-    assert "device" in row["reason"]
+    assert row["status"] == "unsupported"
+    assert row["reason"].startswith("backend_supported:")
+    assert "no fused kernel" in row["reason"]
     assert payload["front"] == []
     assert payload["front_reason"] is not None
     assert "0 of 1 points" in payload["front_reason"]

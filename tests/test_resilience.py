@@ -261,9 +261,10 @@ def test_degradation_and_promotion_round_trip_server(sess):
         srv.close()
 
 
-@pytest.mark.parametrize("residency", ["device", "host"])
+@pytest.mark.parametrize("stateful", [True, False],
+                         ids=["device", "stateless"])
 def test_preferred_engine_compile_refusal_raises_at_first_submit(
-        sess, monkeypatch, residency):
+        sess, monkeypatch, stateful):
     """A preferred engine the compiler refuses is an error, not a fault to
     degrade past: the first submit raises, no wave runs, and the guard
     never counts a retry, wave failure or degradation.  The stand-in
@@ -275,7 +276,7 @@ def test_preferred_engine_compile_refusal_raises_at_first_submit(
     fresh = repro.build(MODEL, params=sess.params, seed=0).quantize()
     monkeypatch.setattr(pallas_backend, "_interpret", lambda: False)
     srv = StreamServer(fresh, batch=2, deadline_s=0.005,
-                       state_residency=residency)
+                       stateful=stateful)
     try:
         assert srv.guard.ladder[0] == "pallas"
         with pytest.raises(RuntimeError, match="'pallas' failed to compile"):
@@ -659,24 +660,20 @@ def test_wave_timeout_exception_type():
 
 @pytest.mark.chaos
 def test_chaos_state_loss_on_device_store(sess):
-    """The host-store loss drill replayed against the DEVICE slot table
-    (backend=pallas resolves state_residency=device): a committed row
-    whose slot is dropped flags the stream's next window ``state_reset``,
-    survivors stay bit-exact with the oracle, and the per-wave (h, c)
-    transfer counters stay at zero throughout the whole chaotic run."""
+    """The loss drill on the fused kernel's slot table, with wave faults
+    on top: a committed row whose slot is dropped flags the stream's next
+    window ``state_reset``, survivors stay bit-exact with the oracle, and
+    only slot ids travel per wave."""
     xs, rows, summary, inj = _run_chaos(sess, "pallas", seed=3, k=4,
                                         state_loss_rate=0.15,
                                         wave_fault_rate=0.1)
-    assert summary["state_residency"] == "device"
-    assert summary["state"]["residency"] == "device"
+    assert summary["health"]["ladder"][0] == "pallas"
     assert inj.stats()["state_losses"] > 0      # seed 3 does inject
     _check_partition(sess, xs, rows, inj)
     for sid, by in rows.items():
         if any(r.state_reset for r in by.values()):
             assert sid in inj.lost_streams
-    t = summary["state_transfer"]
-    assert t["to_device_bytes"] == 0 and t["from_device_bytes"] == 0
-    assert t["slot_id_bytes"] > 0
+    assert summary["state_transfer"]["slot_id_bytes"] > 0
 
 
 @pytest.mark.chaos
@@ -686,7 +683,7 @@ def test_chaos_state_corruption_on_device_store(sess):
     against the oracle through the slot-gathered path."""
     xs, rows, summary, inj = _run_chaos(sess, "pallas", seed=5, k=3,
                                         state_corrupt_rate=0.5)
-    assert summary["state_residency"] == "device"
+    assert summary["health"]["ladder"][0] == "pallas"
     assert inj.stats()["state_corruptions"] > 0
     for sid, wins in xs.items():
         if sid in inj.corrupted_streams:
@@ -708,13 +705,11 @@ def test_concurrent_device_store_stress(sess):
     slot loss, a wave the whole ladder failed (its carries are popped),
     or — first generation only — end_stream outrunning the compute
     thread, which tombstones the dying generation's in-flight carries at
-    gather time (the documented host-path semantics, replayed by the
-    slot table's pre-compute tombstone check)."""
+    gather time (the slot table's pre-compute tombstone check)."""
     inj = FaultInjector(seed=29, wave_fault_rate=0.1, state_loss_rate=0.12)
     cfg = ServingConfig(batch=8, deadline_s=0.002, backend="pallas",
-                        state_residency="device", resilience=FAST)
+                        resilience=FAST)
     srv = StreamServer(sess, cfg, fault_injector=inj)
-    assert srv.state_residency == "device"
     n_threads, n_streams, k = 4, 3, 6
     windows = {}                                 # sid -> the k windows
     errors = []
@@ -746,8 +741,7 @@ def test_concurrent_device_store_stress(sess):
     summary = srv.metrics_summary()
     assert srv.close() == []
     assert len(rows) == n_threads * n_streams * k
-    assert summary["state_transfer"]["to_device_bytes"] == 0
-    assert summary["state_transfer"]["from_device_bytes"] == 0
+    assert summary["state_transfer"]["slot_id_bytes"] > 0
     per_stream = {}
     for r in rows:
         per_stream.setdefault(r.stream_id, []).append(r)

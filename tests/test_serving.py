@@ -2,13 +2,15 @@
 
 The load-bearing guarantee is STATEFUL CARRY: feeding a stream
 window-by-window through the server, with its (h, c) carried in the
-StateStore between windows, is bit-identical on the int path to running
+device slot table between windows, is bit-identical on the int path to running
 the stream's concatenated sequence through the accelerator in one call.
 Plus: deadline-bounded partial waves, LRU eviction semantics, padding
 drop, and compat-wrapper parity for ``Accelerator.serve`` /
 ``WaveBatcher.for_accelerator``."""
 
+import dataclasses
 import time
+from collections import OrderedDict
 
 import jax.numpy as jnp
 import numpy as np
@@ -19,8 +21,7 @@ from repro import backends
 from repro.core.accelerator import AcceleratorConfig
 from repro.core.qlstm import QLSTMConfig, init_int_state
 from repro.kernels.qlstm_cell import state_table_shape
-from repro.serving import (ServingConfig, StateStore, StreamServer,
-                           serve_windows)
+from repro.serving import ServingConfig, StreamServer, serve_windows
 
 MODEL = QLSTMConfig(input_size=1, hidden_size=8, num_layers=2, seq_len=4)
 
@@ -128,41 +129,35 @@ def test_end_stream_stateless_restarts_numbering(sess):
 
 
 def test_end_stream_during_scatter_is_not_undone(sess):
-    """end_stream racing the compute thread's scatter of the same stream's
-    in-flight carry: the scatter's tombstone-check + put and end_stream's
-    pop are serialised under one lock, so the ended carry can never be
-    re-stored afterwards (the TOCTOU this pins down resurrected it).
-    Host residency pinned: the race is host-scatter-specific (the device
-    path runs its whole allocator transaction before compute, under the
-    same lock end_stream takes, so there is no post-compute put to
-    race)."""
+    """end_stream while the stream's wave program runs: the program still
+    scatters the carry into the stream's slot, but end_stream released
+    that slot under the lock the wave's slot transaction took, so the
+    ended carry is unreachable — the stream's next window starts from the
+    reset carry, with its numbering restarted and no reset flag."""
     import threading
-    x = _windows(1, seed=18)
-    with StreamServer(sess, batch=2, deadline_s=0.01,
-                      state_residency="host") as srv:
-        orig_put = srv.states.put
-        in_put, release = threading.Event(), threading.Event()
+    x = _windows(2, seed=18)
+    fresh = np.asarray(sess.infer(jnp.asarray(x[1:2]), path="int"))
+    with StreamServer(sess, batch=2, deadline_s=0.01) as srv:
+        name, fn = srv._fns[0][0]
+        in_call, release = threading.Event(), threading.Event()
 
-        def slow_put(sid, state):
-            in_put.set()               # compute thread is inside _scatter
+        def slow(*args):
+            in_call.set()              # the wave program is running
             release.wait(5.0)
-            return orig_put(sid, state)
+            return fn(*args)
 
-        srv.states.put = slow_put
-
-        def ender():
-            in_put.wait(10.0)
-            srv.end_stream("s")        # blocks on the lock until put ends
-
-        t = threading.Thread(target=ender)
-        t.start()
+        srv._fns[0][0] = (name, slow)
         srv.submit("s", x[0])
-        in_put.wait(10.0)
-        time.sleep(0.05)               # let ender block on _seq_lock
+        assert in_call.wait(10.0)
+        srv.end_stream("s")
+        assert "s" not in srv.states
         release.set()
-        t.join(10.0)
         srv.flush(timeout=30)
         assert "s" not in srv.states   # the ended carry stayed dead
+        assert srv.submit("s", x[1]) == 0
+        results = srv.drain(timeout=30)
+    assert results[-1].seq == 0 and not results[-1].state_reset
+    np.testing.assert_array_equal(results[-1].y, fresh[0])
 
 
 def test_end_stream_with_window_in_flight(sess):
@@ -223,22 +218,8 @@ def test_serve_final_partial_wave_pads_and_drops(sess):
 
 
 # ---------------------------------------------------------------------------
-# StateStore LRU
+# LRU eviction
 # ---------------------------------------------------------------------------
-
-def test_state_store_lru_eviction_order():
-    store = StateStore(capacity=2)
-    st = [(np.ones(4, np.int32), np.ones(4, np.int32))]
-    store.put("a", st)
-    store.put("b", st)
-    assert store.get("a") is not None    # refresh: b is now LRU
-    store.put("c", st)                   # evicts b
-    assert "b" not in store and "a" in store and "c" in store
-    stats = store.stats()
-    assert stats["evictions"] == 1 and stats["live_streams"] == 2
-    assert store.get("b") is None        # miss counted
-    assert store.stats()["misses"] == 1
-
 
 def test_eviction_resets_stream_to_zero_state(sess):
     """An evicted stream's next window behaves like a brand new stream:
@@ -489,7 +470,7 @@ def test_metrics_summary_shape(sess):
     assert m["latency_ms"]["p99"] / 1e3 <= time.perf_counter() - t0 + 1.0
     assert m["gops_per_watt"] > 0 and m["ops_per_inference"] > 0
     assert m["state"]["live_streams"] == 2
-    assert m["stateful"] is True and m["sessions"] == 1
+    assert m["stateful"] is True
 
 
 def test_metrics_sink_is_bounded():
@@ -513,47 +494,15 @@ def test_metrics_sink_is_bounded():
     assert 92.0 < m["latency_ms"]["p50"] < 101.0
 
 
-def test_multi_session_round_robin(sess):
-    """Waves round-robin across replica sessions; results unchanged."""
+@pytest.mark.parametrize("stateful", [True, False],
+                         ids=["stateful", "stateless"])
+def test_stream_server_rejects_several_sessions(sess, stateful):
+    """One server serves one session; replicas go behind a ClusterServer,
+    and the error says so."""
     replica = repro.build(MODEL, params=sess.params, seed=0).quantize()
-    x = _windows(8, seed=9)
-    with StreamServer([sess, replica], batch=2, stateful=False) as srv:
-        for w in x:
-            srv.submit(None, w)
-        results = srv.drain()
-    want = np.asarray(sess.infer(jnp.asarray(x), path="int"))
-    got = np.stack([r.y for r in sorted(results, key=lambda r: r.seq)])
-    np.testing.assert_array_equal(got, want)
-    assert srv.metrics_summary()["sessions"] == 2
-
-
-def test_multi_session_round_robin_is_wave_level(sess):
-    """The round-robin assigns WAVES, not streams: with 2 sessions and
-    batch=1, one stateful stream's consecutive windows execute on both
-    sessions (``routed_replica`` = the session index) — correct only
-    because the carry is host-side in the shared StateStore (the module-
-    docstring caveat; ``ClusterServer`` is the pinned-routing answer)."""
-    replica = repro.build(MODEL, params=sess.params, seed=0).quantize()
-    k = 4
-    xs = _windows(k, seed=23)
-    with StreamServer([sess, replica], batch=1, deadline_s=0.005) as srv:
-        for w in xs:
-            srv.submit("one", w)
-        results = srv.drain()
-    assert {r.routed_replica for r in results} == {0, 1}
-    # ...and the shared host-side carry keeps it bit-exact anyway.
-    full = np.asarray(sess.infer(
-        jnp.asarray(xs.reshape(1, k * MODEL.seq_len, 1)), path="int"))
-    last = max(results, key=lambda r: r.seq)
-    np.testing.assert_array_equal(last.y, full[0])
-
-
-def test_non_replica_sessions_rejected(sess):
-    """Same config but different weights is NOT a replica set: round-robin
-    would silently interleave bit-incompatible models."""
-    other = repro.build(MODEL, seed=42).quantize()
-    with pytest.raises(ValueError, match="replicas"):
-        StreamServer([sess, other], batch=2)
+    for sessions in ([sess, replica], (sess,)):
+        with pytest.raises(ValueError, match="ClusterServer"):
+            StreamServer(sessions, batch=2, stateful=stateful)
 
 
 def test_invalid_scheduler_bounds_rejected(sess):
@@ -570,7 +519,35 @@ def test_invalid_scheduler_bounds_rejected(sess):
 from hypothesis_compat import given, settings, st  # noqa: E402
 from repro.serving import DeviceStateStore, SlotAllocator  # noqa: E402
 
-_DUMMY_STATE = [(np.zeros(4, np.int32), np.zeros(4, np.int32))]
+
+
+class _LruOracle:
+    """The LRU policy the allocator must follow, written plainly: an
+    ordered map with hit/miss/eviction counters."""
+
+    def __init__(self, capacity):
+        self.capacity, self.live = capacity, OrderedDict()
+        self.hits = self.misses = self.evictions = 0
+
+    def get(self, sid):
+        if sid not in self.live:
+            self.misses += 1
+            return False
+        self.live.move_to_end(sid)
+        self.hits += 1
+        return True
+
+    def put(self, sid):
+        self.live[sid] = True
+        self.live.move_to_end(sid)
+        evicted = []
+        while len(self.live) > self.capacity:
+            evicted.append(self.live.popitem(last=False)[0])
+            self.evictions += 1
+        return evicted
+
+    def pop(self, sid):
+        return self.live.pop(sid, None) is not None
 
 _ops_strategy = st.lists(
     st.tuples(st.sampled_from(["lookup", "assign", "release"]),
@@ -648,26 +625,23 @@ def test_slot_allocator_freed_slots_reused_before_growth(capacity, ops):
 @given(st.integers(1, 5), _ops_strategy)
 @settings(max_examples=120, deadline=None)
 def test_slot_allocator_lru_matches_statestore_oracle(capacity, ops):
-    """PROPERTY: the allocator IS the StateStore's LRU policy with rows
-    swapped for slot ids — identical op sequences produce identical live
-    sets, identical eviction victims in identical order, and identical
+    """PROPERTY: the allocator IS a plain LRU policy with entries mapped
+    to slot ids — identical op sequences produce identical live sets,
+    identical eviction victims in identical order, and identical
     hit/miss/eviction counters."""
     alloc = SlotAllocator(capacity)
-    store = StateStore(capacity)
+    store = _LruOracle(capacity)
     for op, sid in ops:
         if op == "lookup":
-            assert (alloc.lookup(sid) is not None) == \
-                (store.get(sid) is not None)
+            assert (alloc.lookup(sid) is not None) == store.get(sid)
         elif op == "assign":
             _, evicted = alloc.assign(sid)
-            assert evicted == store.put(sid, _DUMMY_STATE)
+            assert evicted == store.put(sid)
         else:
-            assert (alloc.release(sid) is not None) == \
-                (store.pop(sid) is not None)
-        assert set(alloc.live()) == set(store._states)
-        a, s = alloc, store.stats()
-        assert (a.hits, a.misses, a.evictions) == \
-            (s["hits"], s["misses"], s["evictions"])
+            assert (alloc.release(sid) is not None) == store.pop(sid)
+        assert set(alloc.live()) == set(store.live)
+        assert (alloc.hits, alloc.misses, alloc.evictions) == \
+            (store.hits, store.misses, store.evictions)
 
 
 def test_slot_allocator_matches_statestore_oracle_seeded():
@@ -676,34 +650,31 @@ def test_slot_allocator_matches_statestore_oracle_seeded():
     space against every small capacity."""
     rng = np.random.default_rng(123)
     for capacity in (1, 2, 3, 5):
-        alloc, store = SlotAllocator(capacity), StateStore(capacity)
+        alloc, store = SlotAllocator(capacity), _LruOracle(capacity)
         shadow_free = []
         for _ in range(2000):
             op = ("lookup", "assign", "release")[rng.integers(3)]
             sid = int(rng.integers(8))
             if op == "lookup":
-                assert (alloc.lookup(sid) is not None) == \
-                    (store.get(sid) is not None)
+                assert (alloc.lookup(sid) is not None) == store.get(sid)
             elif op == "assign":
                 fresh = sid not in alloc
                 hw = alloc.high_water
                 slot, evicted = alloc.assign(sid)
-                assert evicted == store.put(sid, _DUMMY_STATE)
+                assert evicted == store.put(sid)
                 if fresh and shadow_free:
                     assert slot == shadow_free.pop() and \
                         alloc.high_water == hw
             else:
                 slot = alloc.release(sid)
-                assert (slot is not None) == \
-                    (store.pop(sid) is not None)
+                assert (slot is not None) == store.pop(sid)
                 if slot is not None:
                     shadow_free.append(slot)
-            assert set(alloc.live()) == set(store._states)
+            assert set(alloc.live()) == set(store.live)
             live_slots = list(alloc.live().values())
             assert len(live_slots) == len(set(live_slots))
-            s = store.stats()
             assert (alloc.hits, alloc.misses, alloc.evictions) == \
-                (s["hits"], s["misses"], s["evictions"])
+                (store.hits, store.misses, store.evictions)
 
 
 def test_slot_allocator_validates_capacity():
@@ -711,73 +682,84 @@ def test_slot_allocator_validates_capacity():
         SlotAllocator(0)
 
 
-def test_state_residency_resolution(sess):
-    """plan()["state_residency"], the ServingConfig knob, and their
-    interaction: auto follows the plan for a single session, multi-session
-    auto falls back to host, explicit device + replicas is an error, and
-    the config validates its values."""
-    assert sess.plan["state_residency"] == "device"
-    srv = StreamServer(sess, batch=2)
-    assert srv.state_residency == "device"
-    assert isinstance(srv.states, DeviceStateStore)
-    assert srv.health()["state_residency"] == "device"
-    srv.close()
-    srv = StreamServer(sess, batch=2, state_residency="host")
-    assert srv.state_residency == "host"
-    assert isinstance(srv.states, StateStore)
-    srv.close()
-    replica = repro.build(MODEL, params=sess.params, seed=0).quantize()
-    srv = StreamServer([sess, replica], batch=2)       # auto, 2 sessions
-    assert srv.state_residency == "host"
-    srv.close()
-    with pytest.raises(ValueError, match="single session"):
-        StreamServer([sess, replica], batch=2, state_residency="device")
-    with pytest.raises(ValueError, match="host|device"):
-        ServingConfig(state_residency="gpu")
-    with pytest.raises(ValueError, match="stateful"):
-        ServingConfig(stateful=False, state_residency="device")
-    srv = StreamServer(sess, batch=2, stateful=False)  # stateless: None
-    assert srv.state_residency is None
-    srv.close()
+@pytest.mark.parametrize("backend", ["ref", "xla", "pallas"])
+def test_every_stateful_server_serves_from_the_slot_table(sess, backend):
+    """One carry store: whatever rung heads the ladder, a stateful server
+    keeps its carries in its session's device table and ships only slot
+    ids; a stateless server holds no store; and no option picks another
+    place."""
+    with StreamServer(sess, batch=2, backend=backend) as srv:
+        assert srv.guard.ladder[0] == backend
+        assert isinstance(srv.states, DeviceStateStore)
+        srv.submit("s", _windows(1)[0])
+        assert srv.drain(timeout=60)[0].backend == backend
+        summary = srv.metrics_summary()
+    assert summary["state"]["live_streams"] == 1
+    assert summary["state_transfer"]["slot_id_bytes"] == 2 * 2 * 4
+    assert len(dataclasses.fields(ServingConfig)) == 11
+    with StreamServer(sess, batch=2, stateful=False) as srv:
+        assert srv.states is None
 
 
-def test_device_store_rejects_host_only_surfaces(sess):
-    """The device store is not a drop-in for code reaching into the host
-    store's (h, c) surfaces — it says so instead of half-working."""
-    store = DeviceStateStore(sess, capacity=4)
-    with pytest.raises(AttributeError, match="state_residency='host'"):
-        store.put("s", _DUMMY_STATE)
-    assert store.zero_slot == 4 and store.trash_slot == 5
+def test_device_store_lru_eviction_order(sess):
+    """The store's LRU: a lookup refreshes recency, an assignment past
+    capacity evicts the least recently used stream, and the counters say
+    so.  The table holds the live slots plus the ZERO and TRASH rows."""
+    store = DeviceStateStore(sess, capacity=2)
+    assert store.zero_slot == 2 and store.trash_slot == 3
     assert store.table.shape == state_table_shape(
-        6, MODEL.num_layers, 2, MODEL.hidden_size)
+        4, MODEL.num_layers, 2, MODEL.hidden_size)
+    assert store.assign("a")[1] == [] and store.assign("b")[1] == []
+    assert store.lookup("a") is not None    # refresh: b is now LRU
+    assert store.assign("c")[1] == ["b"]    # evicts b
+    assert "b" not in store and "a" in store and "c" in store
+    stats = store.stats()
+    assert stats["evictions"] == 1 and stats["live_streams"] == 2
+    assert store.lookup("b") is None        # miss counted
+    assert store.stats()["misses"] == 1
 
 
 def test_device_vs_host_bit_exact_under_eviction_churn(sess):
     """The serving-level battery: more streams than slots (forced LRU
-    evictions, slot reuse, mid-stream resets) — the device path's
-    results, reset flags, and state counters all match the host path
-    wave for wave, and both match the fresh/continued oracle."""
+    evictions, slot reuse, mid-stream resets).  The slot table behind the
+    XLA adapter and behind the fused pallas kernel give the same results,
+    reset flags and state counters, and every window matches the chained
+    ``compiled_stateful`` reference, restarted from the zero carry where
+    the stream was reset or renumbered."""
     k, n_streams, cap = 3, 6, 4
     xs = {f"s{i}": _windows(k, seed=70 + i) for i in range(n_streams)}
 
-    def run(residency):
-        rows, srv_stats = {}, None
+    def run(backend):
+        rows = {sid: [] for sid in xs}
         with StreamServer(sess, batch=4, deadline_s=0.005, max_streams=cap,
-                          state_residency=residency) as srv:
+                          backend=backend) as srv:
             for w in range(k):
                 for sid in xs:
                     srv.submit(sid, xs[sid][w])
                 srv.flush(timeout=60)
             for r in srv.drain(timeout=60):
-                rows[(r.stream_id, r.seq, r.state_reset)] = np.asarray(r.y)
+                rows[r.stream_id].append((r.seq, r.state_reset,
+                                          np.asarray(r.y)))
             stats = srv.states.stats()
-            srv_stats = {q: stats[q] for q in ("hits", "misses",
-                                               "evictions", "live_streams")}
-        return rows, srv_stats
+        return rows, {q: stats[q] for q in ("hits", "misses", "evictions",
+                                            "live_streams")}
 
-    host_rows, host_stats = run("host")
-    dev_rows, dev_stats = run("device")
-    assert host_stats == dev_stats and host_stats["evictions"] > 0
-    assert host_rows.keys() == dev_rows.keys()     # same reset flags
-    for key in host_rows:
-        np.testing.assert_array_equal(host_rows[key], dev_rows[key])
+    xla_rows, xla_stats = run("xla")
+    pallas_rows, pallas_stats = run("pallas")
+    assert xla_stats == pallas_stats and xla_stats["evictions"] > 0
+    fn = sess.compiled_stateful("ref")
+    resets = 0
+    for sid, wins in xs.items():
+        assert len(xla_rows[sid]) == k
+        state = sess.init_state(1)
+        for w, ((seq, reset, y), (pseq, preset, py)) in enumerate(
+                zip(xla_rows[sid], pallas_rows[sid])):
+            assert (seq, reset) == (pseq, preset)  # same flags, same seqs
+            np.testing.assert_array_equal(y, py)
+            if reset or (w > 0 and seq == 0):
+                state = sess.init_state(1)
+                resets += 1
+            want, state = fn(wins[w][None], state)
+            np.testing.assert_array_equal(y, np.asarray(want)[0],
+                                          err_msg=f"{sid} window {w}")
+    assert resets > 0

@@ -17,13 +17,15 @@ MODEL = QLSTMConfig(input_size=1, hidden_size=8, num_layers=2, seq_len=4)
 BATCH = 4
 #: Streams per round: two full waves and one partial (flushed) wave.
 N_STREAMS = 10
+#: The device table behind the ladder's head (the fused kernel) and
+#: behind the XLA-level slot adapter, and a stateless server.
 KINDS = {
-    "device": dict(state_residency="device"),
-    "host": dict(state_residency="host"),
+    "device": dict(),
+    "xla": dict(backend="xla"),
     "stateless": dict(stateful=False),
 }
 #: Stages each kind of server runs (no carries on a stateless server).
-KIND_STAGES = {"device": STAGES, "host": STAGES,
+KIND_STAGES = {"device": STAGES, "xla": STAGES,
                "stateless": ("h2d", "call", "ready", "emit")}
 
 
@@ -121,7 +123,7 @@ def test_result_wave_matches_record(served):
 
 
 def test_reset_metrics_clears_stages(sess):
-    srv, _ = _serve(sess, "host", rounds=1)
+    srv, _ = _serve(sess, "device", rounds=1)
     try:
         assert srv.metrics_summary()["stages"]["waves"] == 3
         srv.reset_metrics()

@@ -58,8 +58,7 @@ def _device_server(sess, **kw):
     kw.setdefault("batch", 4)
     kw.setdefault("deadline_s", 0.005)
     kw.setdefault("max_streams", 16)
-    return StreamServer(sess, state_residency="device", resilience=FAST,
-                        **kw)
+    return StreamServer(sess, resilience=FAST, **kw)
 
 
 class _Engine:
@@ -252,8 +251,7 @@ def test_fault_before_dispatch_retries_on_the_live_table():
     inj = FaultInjector(seed=3, wave_fault_rate=0.4)
     xs = {f"s{i}": _windows(4, seed=30 + i) for i in range(6)}
     with StreamServer(sess, batch=4, deadline_s=0.005, max_streams=16,
-                      backend="xla", state_residency="device",
-                      resilience=FAST, fault_injector=inj) as srv:
+                      backend="xla", resilience=FAST, fault_injector=inj) as srv:
         for w in range(4):
             for sid in xs:
                 srv.submit(sid, xs[sid][w])
@@ -347,7 +345,7 @@ def test_cluster_warm_handoff_keeps_tables_in_place():
             cluster.submit(sid, xs[k])
         results = cluster.drain()
         for srv in cluster._servers.values():
-            assert srv.states.table.devices() == {srv._sessions[0].device}
+            assert srv.states.table.devices() == {srv._session.device}
         per = cluster.metrics_summary()["replicas"].values()
     for r in results:
         assert r.ok and not r.state_reset, r

@@ -75,7 +75,8 @@ def _assert_kernel(compiled):
 @pytest.mark.parametrize("compute_unit", ["mxu", "vpu"])
 def test_multilayer_kernel_compiles_for_v5e(one_chip, no_compile_cache,
                                             compute_unit):
-    """The host-residency serving kernel at the paper's width."""
+    """The carry-in/carry-out kernel (``run_stateful``) at the paper's
+    width."""
     cfg, t, m, h, n = (PEMS.fxp, PEMS.seq_len, PEMS.input_size,
                        PEMS.hidden_size, PEMS.num_layers)
     spec, w_xs, w_hs, bs = _weights(one_chip, n, m, h, cfg.storage_dtype)
@@ -89,8 +90,8 @@ def test_multilayer_kernel_compiles_for_v5e(one_chip, no_compile_cache,
 @pytest.mark.parametrize("compute_unit", ["mxu", "vpu"])
 def test_slot_kernel_compiles_for_v5e(one_chip, no_compile_cache,
                                       compute_unit):
-    """The device-residency serving kernel — what a ``StreamServer`` runs
-    by default — at the server's default wave and table sizes."""
+    """The slot-table kernel — what a stateful ``StreamServer`` runs on
+    pallas — at the server's default wave and table sizes."""
     cfg, t, m, h, n = (PEMS.fxp, PEMS.seq_len, PEMS.input_size,
                        PEMS.hidden_size, PEMS.num_layers)
     spec, w_xs, w_hs, bs = _weights(one_chip, n, m, h, cfg.storage_dtype)
