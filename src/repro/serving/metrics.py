@@ -13,9 +13,9 @@ Latency definitions (the metrics glossary in docs/SERVING.md):
 
   * ``compute_s``  — host wall time of the server's execute for the wave,
     from its first stage (slot gather) up to the emit loop: carries, the
-    batch's upload, the guarded device call, the wait for the results,
-    and the carry commit.  It includes the device time the host waits
-    out, but it is not a device clock.
+    guarded device call with the batch's upload, the wait for the
+    results, and the carry commit.  It includes the device time the host
+    waits out, but it is not a device clock.
   * ``latency_s``  — end-to-end for the wave's OLDEST window: submit ->
     results ready.  Queueing + assembly + compute; the quantity the
     deadline bounds, and what p50/p95/p99 are computed over.
@@ -25,15 +25,15 @@ where a window's time goes.  A window waits in the scheduler's pending
 list from submit to ``t_built`` (its wave assembled), then in the queue of
 assembled waves until ``t_start`` (the compute thread starts the wave's
 execute), then executes until ``t_done``.  The execute runs the stages of
-:data:`STAGES` in order — ``slots`` (carry lookup), ``h2d`` (the batch's
-and slot vectors' upload), ``call`` (the guarded jitted call), ``ready``
-(waiting for the results and reading them back), ``commit`` (carry
-commit and in-flight bookkeeping), ``emit`` (recording and the results'
-delivery) — each also a ``serve.<stage>`` span on the profiler's trace
-when one is recording.  ``stage_cpu_s`` holds the compute thread's CPU
-time in every stage but ``ready``: against those stages' wall time it says
-whether the thread was working or waiting (for the interpreter lock, a
-lock, or a transfer).
+:data:`STAGES` in order — ``slots`` (carry lookup), ``call`` (the
+guarded jitted call, which uploads the batch and slot vectors as it
+takes them), ``ready`` (waiting for the results and reading them back),
+``commit`` (carry commit and in-flight bookkeeping), ``emit`` (recording
+and the results' delivery) — each also a ``serve.<stage>`` span on the
+profiler's trace when one is recording.  ``stage_cpu_s`` holds the
+compute thread's CPU time in every stage but ``ready``: against those
+stages' wall time it says whether the thread was working or waiting (for
+the interpreter lock, a lock, or a transfer).
 """
 
 from __future__ import annotations
@@ -47,7 +47,7 @@ from typing import Deque, Dict, List, Optional
 import numpy as np
 
 #: The server's execute stages, in the order they run (module docstring).
-STAGES = ("slots", "h2d", "call", "ready", "commit", "emit")
+STAGES = ("slots", "call", "ready", "commit", "emit")
 
 
 @dataclasses.dataclass(frozen=True)

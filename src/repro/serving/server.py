@@ -162,7 +162,7 @@ class StreamResult:
 
 class _StageClock:
     """Times the execute stages of one wave (``metrics.STAGES``), one at a
-    time (``with clock("h2d"): ...``): the wall seconds of each into
+    time (``with clock("call"): ...``): the wall seconds of each into
     ``wall`` and the thread's CPU seconds of each but ``ready`` (the wait
     for the device) into ``cpu``.  Each stage is also a ``serve.<stage>``
     span tagged with the wave's id."""
@@ -337,10 +337,11 @@ class StreamServer:
         compile is not one.  Waves then hit jit's cache for the same
         program."""
         b = self.config.batch
-        x = jnp.zeros((b, *window_shape), jnp.float32)
+        # Host arrays, as a wave passes them (_execute).
+        x = np.zeros((b, *window_shape), np.float32)
         args = (x,)
         if self.states is not None:
-            slots = jnp.zeros((b,), jnp.int32)
+            slots = np.zeros((b,), np.int32)
             args = (x, self.states.table, slots, slots)
         try:
             self._preferred.lower(*args).compile()
@@ -609,11 +610,10 @@ class StreamServer:
                                    int(g.nbytes + s.nbytes))
         else:
             reset = [False] * len(wave.slots)
-        with clock("h2d"):
-            x = jnp.asarray(wave.x)
-            if stateful:
-                slot_ids = (jnp.asarray(g), jnp.asarray(s))
         with clock("call"):
+            # The wave's host arrays go to the jitted program as they are:
+            # its C++ argument path uploads them, to the committed table's
+            # device on a stateful server.
             if stateful:
                 # The wave program donates the table: the store lends it
                 # here and gets a table back at commit, or at restore when
@@ -621,10 +621,10 @@ class StreamServer:
                 table = self.states.take()
                 before = table.unsafe_buffer_pointer()
                 outcome = self.guard.run(
-                    fns, x, table, *slot_ids,
+                    fns, wave.x, table, g, s,
                     alive=lambda: not table.is_deleted())
             else:
-                outcome = self.guard.run(fns, x)
+                outcome = self.guard.run(fns, wave.x)
         if not outcome.ok:
             if stateful:
                 self._return_table(table)

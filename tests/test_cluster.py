@@ -9,7 +9,12 @@ MetricsSink.merge units, drain/rebalance with ``state_reset`` provenance,
 failover off a failed replica, and the device-pinning of
 ``Accelerator.replicate``."""
 
+import json
 import math
+import os
+import subprocess
+import sys
+import textwrap
 import time
 
 import jax
@@ -604,3 +609,76 @@ def test_build_cluster_front_door(sess):
         assert rows[0].ok and rows[0].routed_replica in ("r0", "r1")
     finally:
         cluster.close()
+
+
+#: A replica on the second of two host devices: its server hands each
+#: wave's host arrays to the wave program, which must run on the replica's
+#: device with the replica's table staying there.
+PINNED_REPLICA = textwrap.dedent("""
+    import json
+    import jax, jax.numpy as jnp
+    import numpy as np
+    import repro
+    from repro.core.qlstm import QLSTMConfig
+    from repro.serving import StreamServer
+
+    model = QLSTMConfig(input_size=1, hidden_size=8, num_layers=2,
+                        seq_len=4)
+    sess = repro.build(model, seed=0).quantize()
+    rep = sess.replicate(2)[1]
+    placed = []
+
+    def recording(fn):
+        def wave(x, table, gather, scatter):
+            y, new_table = fn(x, table, gather, scatter)
+            placed.append([type(x) is np.ndarray, type(gather) is np.ndarray,
+                           type(scatter) is np.ndarray,
+                           y.devices() == {rep.device},
+                           new_table.devices() == {rep.device}])
+            return y, new_table
+        return wave
+
+    x = np.random.default_rng(3).uniform(
+        0.0, 1.0, (6, model.seq_len, 1)).astype(np.float32)
+    srv = StreamServer(rep, batch=4, deadline_s=None, max_streams=16)
+    srv._fns = [[(n, recording(f)) for n, f in ladder]
+                for ladder in srv._fns]
+    got = {}
+    try:
+        for _ in range(2):
+            for i in range(6):
+                srv.submit(i, x[i])
+            for r in srv.drain(timeout=120):
+                got[(r.stream_id, r.seq)] = r.y
+        table_devices = srv.states.table.devices() == {rep.device}
+    finally:
+        srv.close()
+    once = np.asarray(sess.infer(jnp.asarray(x), path="int"))
+    twice = np.asarray(sess.infer(jnp.asarray(np.concatenate([x, x], 1)),
+                                  path="int"))
+    exact = all(np.array_equal(got[(i, 0)], once[i])
+                and np.array_equal(got[(i, 1)], twice[i]) for i in range(6))
+    print(json.dumps({"default": rep.device == jax.devices()[0],
+                      "waves": placed, "table": table_devices,
+                      "exact": exact, "answered": len(got)}))
+""")
+
+
+def test_pinned_replica_serves_host_arrays_on_its_device():
+    """A replica pinned to a non-default device (two host devices, in a
+    subprocess: the device count is fixed before jax starts) takes each
+    wave's batch and slot vectors as host arrays; the wave program runs
+    on the replica's device, its table stays there, and its results are
+    bit-exact with the unpinned session's."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", PINNED_REPLICA], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    assert res["default"] is False
+    assert len(res["waves"]) == 4          # two waves of 4 + 2 a round
+    assert all(all(w) for w in res["waves"]), res["waves"]
+    assert res["table"] and res["exact"] and res["answered"] == 12

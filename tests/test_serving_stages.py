@@ -5,6 +5,8 @@ takes, kept in the metrics sink and marked on the profiler's trace."""
 import glob
 import os
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -26,7 +28,7 @@ KINDS = {
 }
 #: Stages each kind of server runs (no carries on a stateless server).
 KIND_STAGES = {"device": STAGES, "xla": STAGES,
-               "stateless": ("h2d", "call", "ready", "emit")}
+               "stateless": ("call", "ready", "emit")}
 
 
 @pytest.fixture(scope="module")
@@ -34,13 +36,20 @@ def sess():
     return repro.build(MODEL, seed=0).quantize()
 
 
-def _serve(sess, kind, rounds=2):
-    """A server of ``kind`` after ``rounds`` rounds of one window per
-    stream, each round drained; returns (server, results)."""
-    x = np.random.default_rng(1).uniform(
+def _windows():
+    return np.random.default_rng(1).uniform(
         0.0, 1.0, (N_STREAMS, MODEL.seq_len, 1)).astype(np.float32)
+
+
+def _serve(sess, kind, rounds=2, wrap=None):
+    """A server of ``kind`` after ``rounds`` rounds of one window per
+    stream (``_windows``), each round drained; ``wrap(server)`` runs
+    before the first submit.  Returns (server, results)."""
+    x = _windows()
     srv = StreamServer(sess, ServingConfig(batch=BATCH, deadline_s=None,
                                            **KINDS[kind]))
+    if wrap is not None:
+        wrap(srv)
     results = []
     for _ in range(rounds):
         for i in range(N_STREAMS):
@@ -120,6 +129,51 @@ def test_result_wave_matches_record(served):
         by_wave.setdefault(r.wave, set()).add(r.stream_id)
     assert {w.wave: w.occupancy for w in waves} == \
         {k: len(v) for k, v in by_wave.items()}
+
+
+@pytest.mark.parametrize("kind", sorted(KINDS))
+def test_wave_program_takes_host_arrays(sess, kind):
+    """The execute hands the wave's host arrays to the wave program as they
+    are (jit uploads them as it takes them): the batch and slot vectors
+    arrive as NumPy arrays, the carry table as a device array, the
+    results are the accelerator's, and no stage of its own uploads."""
+    calls = []
+
+    def recording(fn):
+        def wave(*args):
+            calls.append(args)
+            return fn(*args)
+        return wave
+
+    def wrap(srv):
+        srv._fns = [[(name, recording(fn)) for name, fn in ladder]
+                    for ladder in srv._fns]
+
+    srv, results = _serve(sess, kind, wrap=wrap)
+    srv.close()
+    waves = srv.metrics.waves
+    assert len(calls) == len(waves) == 6
+    for args in calls:
+        if kind == "stateless":
+            (x,) = args
+        else:
+            x, table, gather, scatter = args
+            assert isinstance(table, jax.Array)
+            for v in (gather, scatter):
+                assert type(v) is np.ndarray and v.dtype == np.int32
+                assert v.shape == (BATCH,)
+        assert type(x) is np.ndarray and x.dtype == np.float32
+        assert x.shape == (BATCH, MODEL.seq_len, 1)
+    # A stateful server's second window continues the stream's carry: the
+    # stream's two windows in one call; a stateless server starts over.
+    x = _windows()
+    want = [np.asarray(sess.infer(jnp.asarray(x), path="int"))]
+    want.append(want[0] if kind == "stateless" else np.asarray(sess.infer(
+        jnp.asarray(np.concatenate([x, x], axis=1)), path="int")))
+    assert len(results) == 2 * N_STREAMS
+    for r in results:
+        np.testing.assert_array_equal(r.y, want[r.seq][r.stream_id])
+    assert all("h2d" not in w.stage_s for w in waves)
 
 
 def test_reset_metrics_clears_stages(sess):
